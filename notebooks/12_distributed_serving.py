@@ -79,6 +79,15 @@ from synapseml_tpu.io.serving_v2 import ProcessServingFleet
 # use the pid-echo stage shipped with the repo's tests
 from tests.serving_fault_stage import PidEchoReply
 
+# One process per chip: this process trained above, so on a TPU host it
+# holds the chip, and worker processes inherit JAX_PLATFORMS — asked for an
+# accelerator, each would try to open the chip at start-up and fail (loudly:
+# the fleet's error carries the worker's stderr). These workers only echo
+# their pid, so they are told to stay on the CPU. A fleet that serves a
+# MODEL from the chip is started with n_workers=1 by a process that never
+# initialises jax (see chip_smoke.py).
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 fleet = ProcessServingFleet(PidEchoReply(), n_workers=3,
                             import_modules=["tests.serving_fault_stage"],
                             reply_timeout=20.0)

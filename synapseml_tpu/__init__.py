@@ -29,3 +29,8 @@ from .core import (  # noqa: F401
     load_stage,
     save_stage,
 )
+from .runtime.compile_cache import place_compile_cache
+
+# before any compile, in every process that imports the package; worker
+# processes inherit the choice through the environment
+place_compile_cache()

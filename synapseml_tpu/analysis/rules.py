@@ -93,14 +93,14 @@ class DirectShardMap(Rule):
     jax moved ``shard_map`` between ``jax.experimental`` (0.4.x,
     ``check_rep=``) and top level (``check_vma=``); direct imports are
     exactly the drift that shipped 8 mesh-test ImportErrors in the seed.
-    Every mesh-distributed call site goes through the compat wrapper, which
-    picks the interpreter's spelling at call time.
+    Every mesh-distributed call site goes through the one wrapper, which
+    spells the installed jax's API (``jax.shard_map``) in one place.
     """
 
     code = "SMT002"
     name = "direct-shard-map"
     rationale = ("direct shard_map imports break across jax versions; "
-                 "runtime.topology.shard_map_compat absorbs the drift")
+                 "runtime.topology.shard_map_compat is the one call site")
 
     def check(self, module: Module) -> Iterable[Finding]:
         findings: List[Finding] = []

@@ -12,7 +12,7 @@ This pack **abstract-evals** the repo's ``profiled_jit``-registered hot
 entry points under canonical bench-lane-shaped signatures
 (``jax.make_jaxpr`` — tracing only, no device execution, runs on any
 backend) and walks the resulting jaxprs. Tracing happens under
-``jax.experimental.enable_x64`` so *latent* f64 leaks — dtype-less
+``jax.enable_x64`` so *latent* f64 leaks — dtype-less
 ``jnp.zeros(...)``/numpy-f64 constants that today only stay f32 by the
 grace of the global x64 flag — surface as findings instead of shipping.
 
@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import math
 import os
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
@@ -92,6 +93,19 @@ class TracedEntry:
 # jaxpr traversal helpers (duck-typed: no jax import needed at call time
 # beyond the objects already in hand)
 # ---------------------------------------------------------------------------
+
+def _const_nbytes(const) -> int:
+    """Footprint of one ``ClosedJaxpr.consts`` entry. jax 0.9 hands numpy
+    closure constants over wrapped (``TypedNdArray``): shape and dtype, no
+    ``nbytes``."""
+    nbytes = getattr(const, "nbytes", None)
+    if nbytes is None:
+        shape = getattr(const, "shape", None)
+        itemsize = getattr(getattr(const, "dtype", None), "itemsize", None)
+        nbytes = (math.prod(shape) * itemsize
+                  if shape is not None and itemsize else 0)
+    return int(nbytes)
+
 
 def _sub_jaxprs(value) -> Iterable[Any]:
     """Jaxpr objects hiding inside one eqn param value (pjit carries a
@@ -364,7 +378,7 @@ class HbmBloatConstant(DeviceRule):
         limit = traced.entry.const_bytes_limit
         findings: List[Finding] = []
         for i, const in enumerate(getattr(traced.closed, "consts", ())):
-            nbytes = getattr(const, "nbytes", 0) or 0
+            nbytes = _const_nbytes(const)
             if nbytes > limit:
                 findings.append(self.entry_finding(
                     traced,
@@ -650,9 +664,7 @@ def trace_entry(entry: DeviceEntry, root: Optional[str] = None
     kwargs = built.get("kwargs", {})
     x64_error = None
     try:
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64():
             closed = jax.make_jaxpr(fn)(*args, **kwargs)
     except Exception as e:
         x64_error = f"{type(e).__name__}: {e}"

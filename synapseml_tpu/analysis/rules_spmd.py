@@ -45,8 +45,8 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
                     Set, Tuple)
 
 from .engine import Finding, Module, Rule, register, walk_scoped
-from .rules_device import (_anchor_of, _gbdt_grow_inputs, _sub_jaxprs,
-                           iter_eqns)
+from .rules_device import (_anchor_of, _const_nbytes, _gbdt_grow_inputs,
+                           _sub_jaxprs, iter_eqns)
 
 __all__ = [
     "SpmdEntry",
@@ -317,7 +317,7 @@ class ReplicatedResidency(SpmdRule):
                     f"{row.get('reason', 'unrecorded')}"))
             return findings
         for i, const in enumerate(getattr(traced.closed, "consts", ())):
-            nbytes = int(getattr(const, "nbytes", 0) or 0)
+            nbytes = _const_nbytes(const)
             if nbytes <= limit:
                 continue
             sharding = getattr(const, "sharding", None)

@@ -1187,9 +1187,9 @@ def _build_step(grad_fn=None, fobj=None, *, cfg, C, lr, boosting, d, cat_idx,
 
     ``scan_iters=k``: instead of a single step, return the WHOLE k-iteration
     training loop as one ``lax.scan`` program — one dispatch per fit instead
-    of one per iteration (host dispatch latency dominates on tunneled/remote
-    backends; per-iteration host work only exists for dart/eval/callbacks,
-    which use the per-step form). RNG streams match the host loop exactly:
+    of one per iteration (per-iteration host work only exists for
+    dart/eval/callbacks, which use the per-step form). RNG streams match
+    the host loop exactly:
     carry key splits per iteration, bagging key folds by period."""
     import jax
     import jax.numpy as jnp
@@ -1728,6 +1728,16 @@ def train(params: Dict[str, Any], x: np.ndarray, y: Optional[np.ndarray] = None,
         binned_np = None
     else:
         binned_np = None if use_device_bin else mapper.transform(x)
+    # which ingest ran is decided from the data, so it is said: a fit whose
+    # rows are not f32-representable bins on the host without a word
+    # otherwise (``telemetry.recent_events()``, className="gbdt")
+    from ..core import telemetry
+
+    telemetry.log_event(
+        "binning", className="gbdt", uid="train", rows=int(n),
+        mesh=mesh is not None,
+        path=("dataset" if reuse_dataset else "sparse" if sparse_in
+              else "device" if use_device_bin else "host"))
 
     raw0_dev = None  # device-resident init margins (device-dataset continuation)
     if init_booster is not None:
@@ -2226,8 +2236,7 @@ def train(params: Dict[str, Any], x: np.ndarray, y: Optional[np.ndarray] = None,
 
     # Only dart bookkeeping, per-iteration eval, and user callbacks need the
     # tree on the HOST mid-loop. Without them the ENTIRE loop runs as one
-    # lax.scan program — a single dispatch instead of one per iteration (the
-    # host round-trip dominates wall time on tunneled/remote backends).
+    # lax.scan program — a single dispatch instead of one per iteration.
     sync_each_iter = bool(eval_binned) or boosting == "dart" or bool(callbacks)
 
     # Eval/early-stopping WITHOUT dart/callbacks: run chunked device scans —

@@ -1514,6 +1514,7 @@ class ProcessServingFleet:
         self._lists_lock = threading.Lock()
         self.procs = []
         self.addresses = []
+        self._stderr_paths: Dict[int, str] = {}  # worker pid -> its log
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env = dict(os.environ)
@@ -1616,12 +1617,49 @@ class ProcessServingFleet:
     def _launch_worker(self, port: int = 0):
         """Popen one worker process (no handshake yet). ``port`` pins the
         listen port — how ``restart_worker`` resurrects a kill victim at
-        its old address so the router's prober can re-admit it."""
+        its old address so the router's prober can re-admit it. The
+        worker's stderr goes to a file under the fleet's directory, so a
+        worker that dies can say why (``_startup_error``)."""
+        import os
         import subprocess
 
-        return subprocess.Popen(self._worker_cmd(port), stdout=subprocess.PIPE,
-                                stderr=subprocess.DEVNULL, text=True,
-                                env=self._env)
+        log_path = os.path.join(
+            self._tmp, f"worker-{len(self._stderr_paths) + 1}.stderr")
+        with open(log_path, "ab") as log:
+            p = subprocess.Popen(self._worker_cmd(port),
+                                 stdout=subprocess.PIPE, stderr=log,
+                                 text=True, env=self._env)
+        self._stderr_paths[p.pid] = log_path
+        return p
+
+    def _startup_error(self, p, what: str) -> str:
+        """The message for a worker that did not come up: what happened,
+        the end of the worker's own stderr, and — when the environment asks
+        for an accelerator — the one-process-per-chip rule, since a chip
+        that is already held is the usual cause."""
+        msg = f"serving worker {what}"
+        if p.poll() is not None:
+            msg += f" (exit code {p.returncode})"
+        try:
+            with open(self._stderr_paths[p.pid], "rb") as f:
+                tail = f.read()[-2000:].decode(errors="replace").strip()
+        except (KeyError, OSError):
+            tail = ""
+        msg += (f"; its stderr ends:\n{tail}" if tail
+                else "; it wrote nothing to stderr")
+        from ..runtime.topology import requested_platform
+
+        if requested_platform(self._env) not in (None, "cpu"):
+            msg += (
+                f"\nJAX_PLATFORMS={self._env['JAX_PLATFORMS']} asks every "
+                f"worker to open that device before it announces itself, "
+                f"and an accelerator chip belongs to one process at a "
+                f"time: a worker cannot open a chip that the launching "
+                f"process (once it has initialised jax) or another worker "
+                f"already holds. Use n_workers=1 per chip from a process "
+                f"that stays off jax, or set JAX_PLATFORMS=cpu for workers "
+                f"that do no device work.")
+        return msg
 
     def _handshake(self, p, deadline: float) -> str:
         """Read the worker's ``ADDRESS ...`` announcement (bounded by the
@@ -1634,22 +1672,24 @@ class ProcessServingFleet:
         while True:
             remaining = deadline - time.monotonic()
             if remaining <= 0:
-                raise TimeoutError(
-                    "serving worker did not announce its address "
-                    f"within {self.startup_timeout}s")
+                raise TimeoutError(self._startup_error(
+                    p, "did not announce its address within "
+                       f"{self.startup_timeout}s"))
             # select enforces the deadline even when the worker prints
             # NOTHING (a bare readline would block forever)
             ready, _, _ = select.select([p.stdout], [], [],
                                         min(remaining, 0.5))
             if not ready:
                 if p.poll() is not None:
-                    raise RuntimeError("serving worker died during startup")
+                    raise RuntimeError(
+                        self._startup_error(p, "died during startup"))
                 continue
             line = p.stdout.readline()
             if line.startswith("ADDRESS "):
                 break
             if not line and p.poll() is not None:
-                raise RuntimeError("serving worker died during startup")
+                raise RuntimeError(
+                    self._startup_error(p, "died during startup"))
         addr = line.split(None, 1)[1].strip()
         # drain further worker stdout forever: a pipeline stage that
         # print()s would otherwise fill the 64KB pipe and wedge the

@@ -94,6 +94,17 @@ def main(argv=None) -> int:
     import json as _json
     import time as _time
 
+    from ..runtime.topology import open_requested_platform
+
+    # a launcher that asked for an accelerator (JAX_PLATFORMS) gets a
+    # worker that holds it or jax's own error on stderr — before the
+    # address is announced, so the fleet never registers a worker that
+    # would answer from a CPU it fell back to
+    device = open_requested_platform()
+    if device is not None:
+        print(f"DEVICE {device.platform} {list(device.device_kinds)} "
+              f"x{device.num_devices}", file=sys.stderr, flush=True)
+
     t_load0 = _time.perf_counter()
     spec = _json.loads(args.models_json) if args.models_json else None
     if spec is not None:

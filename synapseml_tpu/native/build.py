@@ -19,13 +19,23 @@ def build(verbose: bool = True) -> str:
     sources = sorted(
         os.path.join(SRC_DIR, f) for f in os.listdir(SRC_DIR) if f.endswith(".cpp")
     )
+    # no -march=native: the binary may be loaded on another host than the
+    # one that built it (a copied checkout), and hashing gains nothing
+    # from it. Built beside the target and renamed into place, so a
+    # process loading the library never sees a half-written file.
+    tmp = f"{OUT}.{os.getpid()}.tmp"
     cmd = [
-        "g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-march=native",
-        *sources, "-o", OUT,
+        "g++", "-O3", "-fPIC", "-shared", "-std=c++17",
+        *sources, "-o", tmp,
     ]
     if verbose:
         print(" ".join(cmd), file=sys.stderr)
-    subprocess.run(cmd, check=True)
+    try:
+        subprocess.run(cmd, check=True)
+        os.replace(tmp, OUT)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return OUT
 
 

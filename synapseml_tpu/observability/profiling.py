@@ -610,21 +610,33 @@ class ProfiledJit:
                 # for this fn — stop retrying it (accounting is optional,
                 # the computation is not).
                 out = self._plain_jit()(*args, **kwargs)
-                self._aot_broken = True
+                self._leave_profiled_path("lower/compile failed")
                 return out
         try:
             out = entry.compiled(*args, **dyn_kwargs)
-        except (TypeError, ValueError):
+        except (TypeError, ValueError) as e:
             # calling-convention or placement mismatch the signature key
             # did not capture (donation, exotic shardings): permanent
             # plain fallback for this fn — plain jit handles these by
             # recompiling, and accounting is optional
-            self._aot_broken = True
+            self._leave_profiled_path(f"compiled call refused: {e}")
             return self._plain_jit()(*args, **kwargs)
         acc = _ACC
         acc.flops += entry.flops
         acc.bytes += entry.bytes
         return out
+
+    def _leave_profiled_path(self, why: str) -> None:
+        """This entry point runs through plain ``jax.jit`` from here on:
+        the computation is unaffected, its compiles and costs go
+        unrecorded. Said once, as a warning — every MFU and compile-time
+        figure for ``name`` is missing from then on."""
+        import logging
+
+        self._aot_broken = True
+        logging.getLogger("synapseml_tpu").warning(
+            "profiled jit %r left the profiled path (%s); compile and cost "
+            "accounting for it stop here", self.name, why)
 
     def _compile(self, sig, args, full_kwargs):
         # the lock is deliberately NOT held across lower/compile (lint
@@ -657,6 +669,10 @@ class ProfiledJit:
             ).lower(*args, **full_kwargs)
             compiled = lowered.compile()
         except Exception:
+            import logging
+
+            logging.getLogger("synapseml_tpu").debug(
+                "AOT lower/compile of %s failed", self.name, exc_info=True)
             return None  # caller re-runs through plain jit (see __call__)
         dt = _perf_counter() - t0
         flops, bytes_ = _cost_entry(compiled)

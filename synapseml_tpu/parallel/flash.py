@@ -56,6 +56,25 @@ def dense_attention(q, k, v, causal: bool = False, pv_dtype=None):
     return out.astype(q.dtype)
 
 
+@functools.lru_cache(maxsize=256)
+def note_dense_substitute(caller: str, shape: tuple, blocks: tuple) -> None:
+    """The Pallas kernel was asked for and dense attention runs instead
+    (the sequence lengths have no block that meets Mosaic's (8, 128) tile).
+    Said once per caller and shape — a warning on the ``synapseml_tpu.flash``
+    logger and a ``flash/dense_substitute`` telemetry event — so a run that
+    believes it exercised the kernel can see that it did not."""
+    import logging
+
+    from ..core import telemetry
+
+    logging.getLogger("synapseml_tpu.flash").warning(
+        "%s: auto-picked blocks %s for (b, s_q, s_k, h, h_kv, d)=%s are "
+        "below Mosaic's (8, 128) tile; running dense attention, not the "
+        "Pallas kernel", caller, blocks, shape)
+    telemetry.log_event("dense_substitute", className="flash", uid=caller,
+                        shape=list(shape), blocks=list(blocks))
+
+
 def _pow2_divisor(s: int, cap: int) -> int:
     """Largest power-of-2 divisor of ``s`` that is <= cap."""
     b = cap
@@ -65,16 +84,17 @@ def _pow2_divisor(s: int, cap: int) -> int:
 
 
 def _pick_blocks(bh: int, s_q: int, s_k: int):
-    """Block sizes tuned from the r5 TPU v5e sweep (bench.py harness,
-    single-dispatch timing):
+    """Block sizes: two classes, both of which Mosaic compiles on a
+    v5e under jax 0.9.0 / libtpu 0.0.34 (bf16, D=64, causal, with and
+    without GQA; ``chip_smoke.py`` checks both against dense attention).
 
-    - small grids (bh < 32) at long S are latency-bound per grid step —
-      wide (2048, 1024) q/k blocks win (S=32k, B=1, H=8: 31.5 ms / 0.354
-      MFU vs 41 ms at (2048, 512));
-    - bigger grids (serving batches, B*H >= 32) saturate with (1024, 1024)
-      AND must stay there: (2048, 512) at bh=64 exceeds the 16 MB scoped
-      VMEM limit (B=8, S=8k OOM'd in the sweep);
+    - small grids (bh < 32) at long S take wide (2048, 1024) q/k blocks:
+      fewer grid steps where each step's fixed cost dominates;
+    - bigger grids (serving batches, B*H >= 32) take (1024, 1024);
     - everything clamps to power-of-2 divisors of the sequence lengths.
+
+    Which class is faster where has not been measured on this toolchain
+    (see PERF.md).
     """
     bq_target = 2048 if (bh < 32 and s_q >= 16384) else 1024
     return (_pow2_divisor(s_q, bq_target), _pow2_divisor(s_k, 1024))
@@ -92,13 +112,11 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
 
     ``causal`` aligns the diagonal to the END of the key sequence (queries
     are the LAST S_q positions), matching decode/ring conventions. Block
-    sizes default to the r5 sweep's auto-pick (:func:`_pick_blocks`);
-    explicit values must divide the sequence lengths.
+    sizes default to :func:`_pick_blocks`; explicit values must divide the
+    sequence lengths.
 
-    ``bench.py``'s ``flash_attention_32k`` config records throughput on the
-    round's TPU; at short S the kernel is dispatch-bound and roughly ties
-    XLA's dense attention, so it is the long-sequence path (dense attention
-    at S=32k would need ~34 GB for the score tensor alone).
+    This is the long-sequence path: dense attention at S=32k would need
+    ~34 GB for the score tensor alone.
     """
     import jax.numpy as jnp
 
@@ -154,6 +172,8 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
                 f"({block_q}, {block_k}) are below Mosaic's (8, 128) tile "
                 f"minimum, and the lengths are too large for the dense "
                 f"fallback. Pad the sequences to a multiple of 128.")
+        note_dense_substitute("flash_attention", (b, s_q, s_k, h, h_kv, d),
+                              (block_q, block_k))
         if rep != 1:  # dense needs matching head counts: expand GQA K/V
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
@@ -205,7 +225,10 @@ def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret):
 
     # bf16 inputs run the two dots at the MXU's native rate with f32
     # accumulation (p is cast to the value dtype for the PV dot — the
-    # standard flash-kernel precision tradeoff); f32 inputs stay exact
+    # standard flash-kernel precision tradeoff). f32 inputs are exact in
+    # the interpreter; compiled by Mosaic the dots run at its default
+    # precision (1.4e-2 max abs error against a highest-precision dense
+    # reference on a v5e, S=512, D=64)
     in_dt = q.dtype
 
     def kernel(q_ref, k_ref, v_ref, o_ref, ml_s, acc_s):
@@ -280,8 +303,8 @@ def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret):
         out_specs=pl.BlockSpec((1, block_q, d),
                                lambda bhi, i, j: (bhi, i, 0)),
         # output in the INPUT dtype: the caller casts to q.dtype anyway, and
-        # the f32 out block was what pushed (2048, 1024) past the 16 MB
-        # scoped-VMEM limit when operands arrive as arguments (r5)
+        # an f32 out block doubles what the (2048, 1024) class keeps in
+        # scoped VMEM
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
         scratch_shapes=[
             # running max (lane 0) + denominator (lane 1)

@@ -178,7 +178,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     if rep > 1 and local != "flash":
         # dense local path: expand grouped K/V after the collective; the
         # flash kernel instead resolves GQA in-kernel via its BlockSpec
-        # index map, so the expanded K/V never materialize in HBM (r5)
+        # index map, so the expanded K/V never materialize in HBM
         kh = jnp.repeat(kh, rep, axis=2)
         vh = jnp.repeat(vh, rep, axis=2)
     if local == "flash":
@@ -192,7 +192,11 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
             # rejected or crawl at sub-tile grids; dense local attention is
             # both correct and faster at these sizes. Explicit blocks are
             # honored (interpret-mode tests and expert tuning).
-            pass  # falls through to the dense path below
+            from .flash import note_dense_substitute
+
+            note_dense_substitute("ulysses_attention",
+                                  (b, S, S, qh.shape[2], kh.shape[2], d),
+                                  (bq, bk))  # then the dense path below
         else:
             from .flash import flash_attention
 

@@ -30,37 +30,25 @@ __all__ = [
     "initialize_distributed",
     "device_kind",
     "is_tpu",
+    "open_requested_platform",
+    "requested_platform",
+    "require_backend",
     "shard_map_compat",
 ]
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across jax version drift.
+    """``jax.shard_map`` with jax imported at call time, so the modules
+    that build mesh-distributed programs stay jax-free at import.
 
-    Newer jax exposes ``jax.shard_map`` with a ``check_vma`` kwarg; 0.4.x
-    has ``jax.experimental.shard_map.shard_map`` with ``check_rep``. Every
-    mesh-distributed code path in this repo goes through this wrapper —
-    never ``from jax import shard_map`` directly — so an interpreter's jax
-    picks the right spelling at call time (jax stays lazily imported).
-
-    ``check`` defaults to True, matching jax's own replication checking
-    default; the trainers pass ``check=False`` explicitly where the body's
-    collectives are known-good and the check costs tracing time."""
-    import inspect
-
+    ``check`` is jax's ``check_vma`` (on by default, as in jax); the
+    trainers pass ``check=False`` where the body's collectives are
+    known-good and the check costs tracing time."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    if "check_vma" in params:
-        kw = {"check_vma": check}
-    elif "check_rep" in params:
-        kw = {"check_rep": check}
-    else:
-        kw = {}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
+
 
 _logger = logging.getLogger("synapseml_tpu.topology")
 
@@ -142,6 +130,29 @@ def require_backend(want: Optional[str] = None, *,
             f"`python tools/check_device.py`; pass allow_cpu=True (bench: "
             f"--allow-cpu) only to deliberately measure the host.")
     return info
+
+
+def requested_platform(env=None) -> Optional[str]:
+    """The platform ``JAX_PLATFORMS`` (in ``env``, default this process's
+    environment) asks jax for first, lower-cased; None when it asks for
+    none (unset or empty)."""
+    value = (os.environ if env is None else env).get("JAX_PLATFORMS", "")
+    return value.split(",")[0].strip().lower() or None
+
+
+def open_requested_platform() -> Optional["ClusterInfo"]:
+    """Open the accelerator ``JAX_PLATFORMS`` asks for — now, not at the
+    first jit. Returns its :class:`ClusterInfo`, or None when the variable
+    asks for no accelerator (unset, empty or ``cpu``) and jax is left alone.
+
+    With ``JAX_PLATFORMS`` unset jax treats a TPU it cannot open (held by
+    another process) as a log line and computes on the CPU. A worker
+    process calls this before its start-up handshake: a launcher that asked
+    for the chip then gets either a worker that holds it or jax's own
+    error, never a worker that answers from a CPU it fell back to."""
+    if requested_platform() in (None, "cpu"):
+        return None
+    return require_backend()
 
 
 def best_mesh_shape(n_devices: int, n_axes: int) -> Tuple[int, ...]:

@@ -278,7 +278,8 @@ class _WorkerHandle:
             os.path.abspath(__file__))))
         wenv["PYTHONPATH"] = pkg_root + os.pathsep + wenv.get("PYTHONPATH", "")
         wenv.update(env or {})
-        self._log = open(os.path.join(study_dir, f"worker-{slot}.log"), "ab")
+        self._log_path = os.path.join(study_dir, f"worker-{slot}.log")
+        self._log = open(self._log_path, "ab")
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "synapseml_tpu.tuning.trial_worker",
              "--study-dir", study_dir],
@@ -288,7 +289,18 @@ class _WorkerHandle:
         line = self._read(timeout=self.task_timeout_s)
         if line is None or not line.startswith("READY"):
             self.kill()
-            raise WorkerCrash(f"trial worker failed to start: {line!r}")
+            # the worker's own words: with JAX_PLATFORMS asking for an
+            # accelerator this is where "the chip is held by another
+            # process" surfaces (one worker per chip)
+            raise WorkerCrash(f"trial worker failed to start: {line!r}; "
+                              f"its log ends:\n{self._log_tail()}")
+
+    def _log_tail(self) -> str:
+        try:
+            with open(self._log_path, "rb") as f:
+                return f.read()[-2000:].decode(errors="replace").strip()
+        except OSError:
+            return ""
 
     def alive(self) -> bool:
         return self.proc.poll() is None

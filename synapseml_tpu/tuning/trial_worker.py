@@ -115,6 +115,11 @@ def main(argv=None) -> int:
                     help="study directory written by tuning.study")
     args = ap.parse_args(argv)
 
+    from synapseml_tpu.runtime.topology import open_requested_platform
+
+    # same rule as io/serving_worker: open the accelerator the launcher
+    # asked for before READY, or die with jax's error in the worker log
+    open_requested_platform()
     ctx = build_context(args.study_dir)
 
     from .executor import TrialError, TrialTask, run_trial_segment

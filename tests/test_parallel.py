@@ -133,7 +133,8 @@ def test_ulysses_flash_auto_block(mesh):
 def test_ulysses_flash_odd_length_falls_back_to_dense(mesh):
     """A gathered length with only tiny power-of-2 factors (s_local=12 ->
     S=96... use 8*13=104 -> auto block 8) must not reach the flash kernel
-    at sub-tile block sizes — it silently runs dense and stays correct."""
+    at sub-tile block sizes — it runs dense (and says so, see
+    test_dense_substitute_leaves_a_visible_trace) and stays correct."""
     q, k, v = _qkv(s=104)  # S=104 = 8 * 13: auto block degrades to 8
     out = np.asarray(sequence_sharded_attention(
         q, k, v, mesh, strategy="ulysses", local="flash", causal=True,
@@ -308,6 +309,54 @@ def test_flash_subtile_auto_falls_back_to_dense():
     refg = dense_attention(q4, jnp.repeat(q, 2, axis=2),
                            jnp.repeat(q, 2, axis=2), causal=True)
     np.testing.assert_allclose(np.asarray(outg), np.asarray(refg), atol=1e-5)
+
+
+def test_dense_substitute_leaves_a_visible_trace(mesh, caplog):
+    """A caller that asked for the Pallas kernel and got dense attention
+    can see it: one warning on the ``synapseml_tpu.flash`` logger and one
+    ``flash/dense_substitute`` telemetry event per caller and shape — from
+    ``flash_attention`` itself and from the Ulysses wrapper — and nothing
+    more when the same shape comes again."""
+    import logging
+
+    from synapseml_tpu.core import telemetry
+    from synapseml_tpu.parallel import flash_attention
+    from synapseml_tpu.parallel.flash import note_dense_substitute
+
+    def events():
+        return [(e["uid"], tuple(e["shape"]), tuple(e["blocks"]))
+                for e in telemetry.recent_events()
+                if e.get("className") == "flash"
+                and e.get("method") == "dense_substitute"]
+
+    note_dense_substitute.cache_clear()
+    telemetry.clear_events()
+    rng = np.random.default_rng(12)
+    q = jnp.asarray(rng.normal(size=(1, 300, 2, 64)).astype(np.float32))
+    with caplog.at_level(logging.WARNING, logger="synapseml_tpu.flash"):
+        flash_attention(q, q, q, causal=True)  # S=300 -> block 4: no tile
+        assert events() == [("flash_attention", (1, 300, 300, 2, 2, 64),
+                             (4, 4))]
+        flash_attention(q, q, q, causal=True)
+        assert len(events()) == 1  # once per shape
+        # the Ulysses wrapper decides before the kernel is ever called, so
+        # it says so itself (gathered S=88 auto-blocks to 8; a length no
+        # other test traces — the event fires when the program is traced)
+        qs, ks, vs = _qkv(s=88)
+        sequence_sharded_attention(qs, ks, vs, mesh, strategy="ulysses",
+                                   local="flash", causal=True,
+                                   interpret=True)
+        assert [e[0] for e in events()] == ["flash_attention",
+                                            "ulysses_attention"]
+        assert events()[1][2] == (8, 8)
+    said = [r.getMessage() for r in caplog.records
+            if r.name == "synapseml_tpu.flash"]
+    assert len(said) == 2
+    assert all("dense attention, not the Pallas kernel" in m for m in said)
+    # a shape that does reach the kernel says nothing
+    q2 = jnp.asarray(rng.normal(size=(1, 256, 2, 64)).astype(np.float32))
+    flash_attention(q2, q2, q2, causal=True, interpret=True)
+    assert len(events()) == 2
 
 
 def test_flash_explicit_subtile_blocks_raise_but_clamped_ok():

@@ -7,8 +7,8 @@ timed and cause-attributed; stage spans report achieved FLOPs/MFU; peak
 gauges merge as max and live gauges as sum across workers; the timeline
 export is schema-valid Chrome trace JSON and a ``ProcessServingFleet``
 stitches into one timeline with >= 2 process tracks; and
-``tools/perf_diff.py BENCH_r04.json BENCH_r05.json`` reproduces a written
-diagnosis of the r5 flash regression.
+``tools/perf_diff.py`` reproduces a written diagnosis of a flash regression
+of the r4->r5 shape from two small artefacts written under ``tmp_path``.
 """
 
 import json
@@ -314,9 +314,39 @@ def test_perf_timeline_cli_renders_saved_payload(tmp_path):
 # perf_diff: the bisection toolkit reproduces the r5 flash diagnosis
 # ---------------------------------------------------------------------------
 
-def test_perf_diff_flags_r5_flash_regression_with_attribution(capsys):
-    rc = perf_diff.main([os.path.join(_REPO, "BENCH_r04.json"),
-                         os.path.join(_REPO, "BENCH_r05.json")])
+# two round artefacts in the driver's format, cut down to the flash lane:
+# the older one damaged the way r4 was (``parsed: null``, the JSON line's
+# front truncated out of the recorded tail), the newer one intact and 20%
+# slower at every curve point, with neither blocks nor operand mode stamped
+_FLASH_OLD = {
+    "seq_len": 32768, "ms_per_fwd": 30.34, "tflops_nominal": 72.5,
+    "curve": {
+        "s8192": {"flash_ms": 14.5, "xla_ms": 21.76},
+        "s16384": {"flash_ms": 19.49, "xla_ms": 51.47},
+        "s32768": {"flash_ms": 30.34, "xla_ms": None}}}
+_FLASH_NEW = {
+    "seq_len": 32768, "ms_per_fwd": 37.76, "tflops_nominal": 58.2,
+    "curve": {
+        "s8192": {"flash_ms": 20.96, "xla_ms": 29.51},
+        "s16384": {"flash_ms": 24.38, "xla_ms": 49.48},
+        "s32768": {"flash_ms": 37.76, "xla_ms": None}}}
+
+
+def _write_flash_rounds(tmp_path):
+    old, new = tmp_path / "BENCH_r04.json", tmp_path / "BENCH_r05.json"
+    tail = ('0444.0, "rows": 500000, "ingest_s": 14.48}, '
+            '"flash_attention_32k": ' + json.dumps(_FLASH_OLD) + ', '
+            '"serving_latency": {"continuous_p50_ms": 0.303}}}\n')
+    old.write_text(json.dumps({"n": 4, "rc": 0, "tail": tail,
+                               "parsed": None}))
+    new.write_text(json.dumps({"n": 5, "rc": 0, "tail": "", "parsed": {
+        "value": 11411.28,
+        "extra": {"flash_attention_32k": _FLASH_NEW}}}))
+    return str(old), str(new)
+
+
+def test_perf_diff_flags_flash_regression_with_attribution(tmp_path, capsys):
+    rc = perf_diff.main(list(_write_flash_rounds(tmp_path)))
     out = capsys.readouterr().out
     assert rc == 1  # a regressed lane fails the exit code (CI-friendly)
     assert "flash_attention_32k" in out and "x0.803" in out
@@ -371,8 +401,8 @@ def test_perf_diff_json_mode_and_clean_exit(tmp_path, capsys):
     assert payload["lanes"][0]["ratio"] == 1.0
 
 
-def test_perf_diff_recovers_damaged_artifact_tail():
-    extra = perf_diff.load_artifact(os.path.join(_REPO, "BENCH_r04.json"))
+def test_perf_diff_recovers_damaged_artifact_tail(tmp_path):
+    extra = perf_diff.load_artifact(_write_flash_rounds(tmp_path)[0])
     assert extra.get("_tail_recovered") is True
     assert extra["flash_attention_32k"]["tflops_nominal"] == 72.5
 
@@ -471,13 +501,13 @@ def test_perf_timeline_cli_jax_free_on_artifacts(tmp_path):
     satellite) — asserted in a SUBPROCESS immune to this session."""
     src = tmp_path / "traces.json"
     src.write_text(json.dumps(_FIXTURE_TRACES))
+    old, new = _write_flash_rounds(tmp_path)
     code = (
         "import sys\n"
         f"sys.path.insert(0, {_TOOLS!r})\n"
         "import perf_timeline, perf_diff\n"
         f"perf_timeline.main([{str(src)!r}])\n"
-        f"perf_diff.main([{os.path.join(_REPO, 'BENCH_r04.json')!r}, "
-        f"{os.path.join(_REPO, 'BENCH_r05.json')!r}])\n"
+        f"perf_diff.main([{old!r}, {new!r}])\n"
         "bad = [m for m in sys.modules if m == 'jax' "
         "or m.startswith('jax.')]\n"
         "assert not bad, bad\n"

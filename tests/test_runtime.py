@@ -388,15 +388,28 @@ def test_spec_layout_3d_degrades_to_single_chip_and_serves(monkeypatch,
                                np.asarray(x @ jnp.arange(12.0).reshape(4, 3)))
 
 
-def test_graft_entry_dryrun_multichip_in_process():
+def test_graft_entry_dryrun_multichip_in_process(dryrun_multichip_8_stdout):
     """The driver's multi-chip gate: with 8 visible devices the impl runs
-    in-process; with fewer it must self-provision a virtual CPU mesh (the
-    subprocess path is exercised by the driver itself)."""
+    in-process, every assert inside it holding (the virtual-mesh
+    subprocess, taken only when the CPU was asked for, is exercised by the
+    driver itself)."""
+    assert "DEVICES platform=cpu kind='cpu' count=8" in \
+        dryrun_multichip_8_stdout
+    assert "ULYSSES_FLASH seq=1024 interpret=True" in \
+        dryrun_multichip_8_stdout
+
+
+def test_graft_entry_dryrun_does_not_slide_onto_a_cpu_mesh(monkeypatch):
+    """Too few devices is an error unless the caller asked for the CPU: a
+    run on a one-chip host must not re-run itself on virtual CPU devices
+    and report success."""
     import sys
     sys.path.insert(0, "/root/repo")
     try:
         import __graft_entry__ as g
-        g.dryrun_multichip(8)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")  # the chip host's
+        with pytest.raises(RuntimeError, match="ask for it: JAX_PLATFORMS=cpu"):
+            g.dryrun_multichip(64)
     finally:
         sys.path.remove("/root/repo")
 
@@ -431,6 +444,23 @@ def test_require_backend_want_pins_platform():
         require_backend(want="tpu")
 
 
+def test_open_requested_platform_leaves_jax_alone_unless_asked(monkeypatch):
+    """Worker processes call this before their handshake: no accelerator
+    asked for (unset, empty, cpu) -> None and no device work; asked for ->
+    the accelerator or an error (here: the conftest CPU is not one)."""
+    from synapseml_tpu.runtime.topology import open_requested_platform
+
+    for value in (None, "", "cpu", "cpu,tpu"):
+        if value is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", value)
+        assert open_requested_platform() is None
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="resolved jax backend is 'cpu'"):
+        open_requested_platform()
+
+
 def _check_device_main(monkeypatch, probe_code, args):
     import importlib
     import os
@@ -461,6 +491,22 @@ def test_check_device_exit_codes(monkeypatch, capsys):
                               ["--want", "gpu"]) == 1
     out = capsys.readouterr()
     assert '"platform": "tpu"' in out.out  # probe JSON relayed
+
+
+def test_check_device_want_sets_the_platform_for_the_probe_child(
+        monkeypatch, capsys):
+    """``--want tpu`` makes jax in the probe child open that platform or
+    fail (exit 2 with the runtime's own message) — never resolve to
+    another backend quietly."""
+    code = ('import json, os; print(json.dumps({"platform": '
+            'os.environ["JAX_PLATFORMS"], "device_kinds": [], '
+            '"num_devices": 1, "num_hosts": 1}))')
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert _check_device_main(monkeypatch, code, ["--want", "tpu"]) == 0
+    assert '"platform": "tpu"' in capsys.readouterr().out
+    # without --want the child keeps the ambient value
+    assert _check_device_main(monkeypatch, code, ["--allow-cpu"]) == 0
+    assert '"platform": "cpu"' in capsys.readouterr().out
 
 
 def test_check_device_probe_crash_is_exit_2(monkeypatch, capsys):

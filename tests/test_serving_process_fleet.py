@@ -93,6 +93,27 @@ def test_front_door_metrics_aggregate_worker_processes(fleet):
     assert p50 is not None and p50 > 0
 
 
+def test_worker_that_cannot_open_the_requested_platform_fails_the_start(
+        monkeypatch):
+    """A launcher whose environment asks for an accelerator gets workers
+    that hold it, or a start-up error carrying the worker's own stderr —
+    never a worker that fell back to the CPU. Here ``JAX_PLATFORMS=tpu``
+    on a host without one: jax's refusal, written by the worker process,
+    is in the message, with the one-process-per-chip rule beside it."""
+    sys.path.insert(0, _REPO)
+    from tests.serving_fault_stage import PidEchoReply
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # inherited by the worker
+    with pytest.raises(RuntimeError) as ei:
+        ProcessServingFleet(PidEchoReply(), n_workers=1,
+                            import_modules=["tests.serving_fault_stage"])
+    msg = str(ei.value)
+    assert "serving worker died during startup (exit code 1)" in msg
+    assert "its stderr ends:" in msg
+    assert "Unable to initialize backend 'tpu'" in msg  # the worker's words
+    assert "belongs to one process at a time" in msg
+
+
 def test_kill_then_restart_worker_is_readmitted():
     """The full fault ROUND TRIP (not just failover): kill a worker, the
     router evicts it; restart a replacement at the same address, the

@@ -2,18 +2,21 @@
 """Accelerator probe with a bounded timeout — ``python tools/check_device.py``.
 
 ``jax.devices()`` on a mis-provisioned TPU VM has two failure modes and
-both are worse than an error: it silently falls back to CPU (every
-downstream number measures the wrong machine), or it HANGS waiting for a
-libtpu that is claimed by another process. This probe runs the device
-query in a SUBPROCESS with a hard timeout so both modes become loud,
-scriptable exit codes — the preflight for bench runs and fleet bring-up
-(ROADMAP item 1's environment half).
+both are worse than an error: with ``JAX_PLATFORMS`` unset it silently
+falls back to CPU when the chip is held by another process (every
+downstream number measures the wrong machine), or it HANGS inside a
+wedged runtime. This probe runs the device query in a SUBPROCESS with a
+hard timeout, and ``--want tpu`` sets ``JAX_PLATFORMS`` for that child so
+jax must open the platform or fail: a busy chip is then exit 2 carrying
+libtpu's own message, not exit 1 "backend is cpu". The child exits before
+the result is printed, so the probe never keeps the chip.
 
 Exit codes: 0 accelerator present (platform/kinds printed as one JSON
 line), 1 resolved backend is CPU (or not the ``--want`` platform), 2 the
-probe subprocess crashed (import error, runtime error — stderr relayed),
-3 the probe TIMED OUT (the hang made loud). ``--allow-cpu`` downgrades
-the CPU case to exit 0 for deliberately host-only environments.
+probe subprocess crashed (the wanted platform could not be opened, import
+error — stderr relayed), 3 the probe TIMED OUT (the hang made loud).
+``--allow-cpu`` downgrades the CPU case to exit 0 for deliberately
+host-only environments.
 
 Import discipline: this tool never imports jax in-process — only the
 child does — so a hung TPU runtime cannot hang the probe itself.
@@ -44,16 +47,21 @@ print(json.dumps({{"platform": info.platform,
 """
 
 
-def probe(timeout: float = 60.0) -> dict:
+def probe(timeout: float = 60.0, want: Optional[str] = None) -> dict:
     """Run the device query in a subprocess; returns the probe dict.
 
-    Raises ``subprocess.TimeoutExpired`` on hang and ``RuntimeError``
-    (with the child's stderr) on crash.
+    ``want`` becomes the child's ``JAX_PLATFORMS``: jax then opens that
+    platform or raises, instead of quietly choosing another. Raises
+    ``subprocess.TimeoutExpired`` on hang and ``RuntimeError`` (with the
+    child's stderr) on crash.
     """
     code = os.environ.get("SMT_DEVICE_PROBE_CODE",
                           _PROBE_CODE.format(root=_REPO_ROOT))
+    env = dict(os.environ)
+    if want:
+        env["JAX_PLATFORMS"] = want
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=timeout)
+                       text=True, timeout=timeout, env=env)
     if r.returncode != 0:
         raise RuntimeError(f"device probe subprocess failed "
                            f"(exit {r.returncode}):\n{r.stderr.strip()}")
@@ -69,13 +77,14 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="seconds before a hanging device query is "
                          "declared dead (default 60)")
     ap.add_argument("--want", default=None,
-                    help="require this platform specifically (tpu/gpu)")
+                    help="require this platform specifically (tpu/gpu): "
+                         "the probe child runs with JAX_PLATFORMS set to it")
     ap.add_argument("--allow-cpu", action="store_true",
                     help="exit 0 even when the backend is cpu")
     args = ap.parse_args(argv)
 
     try:
-        info = probe(timeout=args.timeout)
+        info = probe(timeout=args.timeout, want=args.want)
     except subprocess.TimeoutExpired:
         print(f"error: device query still hung after {args.timeout:.0f}s — "
               f"likely a libtpu claimed by another process or a wedged "

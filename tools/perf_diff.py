@@ -24,8 +24,8 @@ Artifacts damaged by the driver's tail-window truncation (r4's
 ``parsed: null``) recover per-lane objects by brace matching, same as
 ``bench.py``'s armored loader.
 
-    python tools/perf_diff.py BENCH_r04.json BENCH_r05.json
-    python tools/perf_diff.py BENCH_r04.json BENCH_r05.json --json
+    python tools/perf_diff.py old.json new.json
+    python tools/perf_diff.py old.json new.json --json
     python tools/perf_diff.py old.json new.json --threshold 0.9 --all
 
 Exit code 1 when any lane regressed below the threshold (CI-friendly).
