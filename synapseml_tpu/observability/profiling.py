@@ -1,7 +1,7 @@
-"""Device-side performance observability: compile / HBM / MFU accounting.
+"""Device-side performance observability: compile / HBM / FLOPs accounting.
 
 The spans half of this subsystem answers *which stage* was slow; this
-module answers *why the hardware was slow*. Three accountings, all merged
+module answers *why the hardware was slow*. Its accountings are all merged
 fleet-wide through the ordinary snapshot path and exposed at ``/metrics``:
 
 - **Compile accounting** (:func:`profiled_jit`): every XLA compilation a
@@ -12,14 +12,19 @@ fleet-wide through the ordinary snapshot path and exposed at ``/metrics``:
   ``weak_type`` / ``placement``). The compiled executable's ``cost_analysis()`` FLOPs and
   bytes are cached per signature, so every subsequent call is attributed
   at zero cost.
-- **Achieved MFU / roofline per stage**: calls through profiled entry
-  points accumulate their executable's FLOPs/bytes into a thread-local;
-  the stage-span hook (installed into ``observability.spans``) reads the
+- **FLOPs / bytes per stage**: calls through profiled entry points
+  accumulate their executable's FLOPs/bytes into a thread-local; the
+  stage-span hook (installed into ``observability.spans``) reads the
   delta at span exit and records ``smt_stage_flops_total`` /
-  ``smt_stage_bytes_total{stage,method}`` plus an ``smt_stage_mfu``
-  histogram sample (achieved FLOPs / wall time / device peak) — MFU and
-  roofline position (FLOPs÷bytes = arithmetic intensity) per *stage*, not
-  just per bench lane.
+  ``smt_stage_bytes_total{stage,method}`` — XLA's own count of the work a
+  stage ran, and its arithmetic intensity (FLOPs÷bytes). Utilisation is
+  the benchmark's to compute (``step_mfu``: model FLOPs from shapes over
+  device time from a trace), not a host-clock ratio taken here.
+- **Spans in the profiler's trace**: the same hook mirrors every enabled
+  span (stage and phase) as a ``jax.profiler.TraceAnnotation`` named
+  ``smt.<stage>.<method>``, and :class:`ProfiledJit` records the
+  runtime's part of a dispatch as the ``ProfiledJit.execute`` span, so a
+  profile names the host's phases on the device trace's clock.
 - **Memory accounting**: per-stage ``smt_stage_hbm_live_bytes`` /
   ``smt_stage_hbm_peak_bytes`` gauges from ``device.memory_stats()``
   (graceful no-op on backends without allocator stats — CPU returns
@@ -77,9 +82,9 @@ __all__ = [
     "update_memory_gauges",
 ]
 
-# bf16 peak FLOPs by TPU generation (public figures); the MFU denominator.
-# ``bench.py`` consumes this table too — one source of truth for what a
-# device's ceiling is. None (unknown device kind) -> MFU not reported.
+# bf16 peak FLOPs by TPU generation (public figures). Nothing in the
+# package reads it any more; ``bench.py`` and ``chip_smoke.py`` import it
+# and it goes with ``bench.py`` (ROADMAP Design 3).
 PEAK_BF16_FLOPS: Dict[str, float] = {
     "v5litepod": 197e12, "v5lite": 197e12, "v5e": 197e12,
     "v5p": 459e12, "v5": 459e12,
@@ -91,8 +96,8 @@ PEAK_BF16_FLOPS: Dict[str, float] = {
 def peak_flops(device_kind: str) -> Optional[float]:
     """Peak bf16 FLOPs for a device kind string (substring match, most
     specific first), or the ``SMT_PEAK_FLOPS`` env override (how unknown
-    hardware — or a test — supplies the MFU denominator). None when
-    unknown: MFU is then simply not recorded, never guessed."""
+    hardware — or a test — supplies one). None when unknown, never
+    guessed."""
     env = os.environ.get("SMT_PEAK_FLOPS")
     if env:
         try:
@@ -111,7 +116,7 @@ _enabled = True
 
 def enable() -> None:
     """Turn device profiling on (the default) and re-install the span
-    hook so stage spans resume recording FLOPs/MFU/memory."""
+    hook so spans resume their annotations and FLOPs/memory records."""
     global _enabled
     _enabled = True
     _spans.set_profiler(_PROFILER)
@@ -175,13 +180,12 @@ def _jax_if_loaded():
 
 class _DeviceState:
     """Lazily probed, cached view of the local devices: (device objects,
-    peak bf16 FLOPs, whether memory_stats() yields anything). Re-probed
-    only while jax is absent; once devices exist the answer is final."""
+    whether memory_stats() yields anything). Re-probed only while jax is
+    absent; once devices exist the answer is final."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.devices: Optional[List[Any]] = None
-        self.peak: Optional[float] = None
         self.has_memory_stats = False
 
     def probe(self):
@@ -197,8 +201,6 @@ class _DeviceState:
             devices = list(jax.local_devices())
         except Exception:
             return self
-        peak = peak_flops(
-            getattr(devices[0], "device_kind", "") if devices else "")
         has_stats = False
         for d in devices:
             try:
@@ -208,7 +210,6 @@ class _DeviceState:
             break
         with self._lock:
             if self.devices is None:
-                self.peak = peak
                 self.has_memory_stats = has_stats
                 self.devices = devices
         return self
@@ -294,13 +295,30 @@ def install_memory_collector(registry: Optional[MetricsRegistry] = None
 
 
 # ---------------------------------------------------------------------------
-# span hook: FLOPs/MFU/memory per stage span
+# span hook: profiler annotation per span; FLOPs/memory per stage span
 # ---------------------------------------------------------------------------
 
 class _SpanProfiler:
-    """Installed into ``observability.spans``: ``enter()`` snapshots the
-    thread-local FLOPs/bytes counters, ``exit()`` attributes the delta —
-    the profiled-jit calls that ran inside the span — to the stage."""
+    """Installed into ``observability.spans``. For every span,
+    ``annotate()`` enters the span's mirror in the profiler's trace. For
+    stage spans, ``enter()`` snapshots the thread-local FLOPs/bytes
+    counters and ``exit()`` attributes the delta — the profiled-jit calls
+    that ran inside the span — to the stage."""
+
+    def annotate(self, label, rows):
+        """An entered ``TraceAnnotation(label)`` (the caller exits it), or
+        None while jax is not loaded. No switch: the annotation is inert
+        unless a profile is being captured, and then it puts the span on
+        the caller's line of that capture."""
+        jax = _jax_if_loaded()
+        if jax is None:
+            return None
+        if rows is None:
+            ann = jax.profiler.TraceAnnotation(label)
+        else:
+            ann = jax.profiler.TraceAnnotation(label, rows=rows)
+        ann.__enter__()
+        return ann
 
     def enter(self):
         acc = _ACC
@@ -332,19 +350,12 @@ class _SpanProfiler:
                     "cost_analysis bytes accessed inside stage spans "
                     "(FLOPs/bytes = roofline arithmetic intensity)",
                     ("stage", "method"))
-                mfu_h = reg.histogram(
-                    "smt_stage_mfu",
-                    "achieved MFU per span (FLOPs / wall time / device peak)",
-                    ("stage", "method"))
                 got = cache[key] = (flops_c.labels(*name),
-                                    bytes_c.labels(*name),
-                                    mfu_h.labels(*name))
-            flops_s, bytes_s, mfu_s = got
+                                    bytes_c.labels(*name))
+            flops_s, bytes_s = got
             flops_s.inc(dflops)
             if dbytes > 0.0:
                 bytes_s.inc(dbytes)
-            if st.peak and elapsed_s > 0.0:
-                mfu_s.observe(dflops / elapsed_s / st.peak)
         if st.has_memory_stats:
             stats = memory_stats()
             if stats:
@@ -613,7 +624,11 @@ class ProfiledJit:
                 self._leave_profiled_path("lower/compile failed")
                 return out
         try:
-            out = entry.compiled(*args, **dyn_kwargs)
+            # the runtime's part of a dispatch: enqueue, and as much of
+            # the upload as is synchronous. What the caller's own span
+            # round this call holds beyond it is the keying above.
+            with _spans.span("ProfiledJit", "execute"):
+                out = entry.compiled(*args, **dyn_kwargs)
         except (TypeError, ValueError) as e:
             # calling-convention or placement mismatch the signature key
             # did not capture (donation, exotic shardings): permanent
@@ -629,7 +644,7 @@ class ProfiledJit:
     def _leave_profiled_path(self, why: str) -> None:
         """This entry point runs through plain ``jax.jit`` from here on:
         the computation is unaffected, its compiles and costs go
-        unrecorded. Said once, as a warning — every MFU and compile-time
+        unrecorded. Said once, as a warning — every FLOPs and compile-time
         figure for ``name`` is missing from then on."""
         import logging
 

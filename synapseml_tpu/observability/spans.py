@@ -17,6 +17,24 @@ span into the process-default :class:`~.metrics.MetricsRegistry`:
 - ``smt_stage_errors_total{stage,method}`` — spans that raised (the
   duration is still observed, under the same labels).
 
+Two kinds of span share the machinery. A **stage span** (``stage_span``:
+one ``transform``/``fit`` of a stage instance) also runs the
+device-profiling hook, which attributes FLOPs and samples device memory. A
+**phase span** (``span``: a step inside a stage, such as
+``ONNXModel.dispatch``) is plain: duration, rows, errors, nothing else.
+
+Every enabled span is also mirrored, for its duration, in two other views:
+
+- the **profiler's trace**: the hook that ``observability.profiling``
+  installs enters a ``jax.profiler.TraceAnnotation("smt.<stage>.<method>")``
+  (when jax is already imported), so a capture taken by any means
+  (``profile_trace``, ``Timer(profile_dir=)``, ``jax.profiler.trace``)
+  holds the span on the caller's line, on the device trace's clock. The
+  annotation is inert while no profile is being captured.
+- the **request trace**: while a trace span is current in this thread the
+  span begins a child ``TraceSpan`` and is current itself until it exits,
+  so nested stage and phase spans form a tree in ``/traces``.
+
 ``disable()`` turns spans into no-ops (the bench microbench compares
 on-vs-off; contract: < 5% per-transform overhead when ON — series lookups
 are cached per (registry, stage, method), so the hot path is two monotonic
@@ -41,11 +59,12 @@ __all__ = ["span", "stage_span", "enable", "disable", "is_enabled", "Span",
 
 _enabled = True
 
-# Device-profiling hook (installed by ``observability.profiling``): an
-# object with ``enter() -> token`` and ``exit(token, name, elapsed_s)``.
-# When set, every span attributes the FLOPs/bytes of profiled jit calls
-# that ran inside it (achieved MFU per stage) and samples device memory.
-# Kept as a hook so this module stays stdlib-pure on its own.
+# Profiling hook (installed by ``observability.profiling``): an object with
+# ``annotate(label, rows) -> entered context or None`` (every span: the
+# profiler-trace mirror) and ``enter() -> token`` / ``exit(token, name,
+# elapsed_s)`` (stage spans only: FLOPs/bytes of the profiled jit calls
+# that ran inside, and a device-memory sample). Kept as a hook so this
+# module stays stdlib-pure on its own.
 _profiler = None
 
 
@@ -75,10 +94,11 @@ _cache_lock = threading.Lock()
 
 
 def _series_for(reg: MetricsRegistry, stage: str, method: str):
-    """(duration_cold, duration_warm, rows, errors) series, cached ON the
-    registry — family/label resolution off the per-call path, and the cache
-    dies with the registry (a module-global cache would keep every
-    swapped-out registry alive through the series backrefs)."""
+    """(duration_cold, duration_warm, rows, errors) series, then the
+    span's name pair and its profiler label, cached ON the registry —
+    family/label resolution off the per-call path, and the cache dies with
+    the registry (a module-global cache would keep every swapped-out
+    registry alive through the series backrefs)."""
     cache = reg.__dict__.get("_span_series_cache")
     if cache is None:
         with _cache_lock:
@@ -98,7 +118,8 @@ def _series_for(reg: MetricsRegistry, stage: str, method: str):
                          "stage method calls that raised",
                          ("stage", "method"))
     got = (dur.labels(stage, method, "1"), dur.labels(stage, method, "0"),
-           rows.labels(stage, method), errors.labels(stage, method))
+           rows.labels(stage, method), errors.labels(stage, method),
+           key, f"smt.{stage}.{method}")
     with _cache_lock:
         cache[key] = got
     return got
@@ -110,15 +131,15 @@ class Span:
     keep the hot path at two clock reads + one histogram observe."""
 
     __slots__ = ("_dur", "_rows_c", "_errors", "_t0", "rows", "_name",
-                 "_trace_parent", "_prof0")
+                 "_label", "_device", "_trace_span", "_prof0", "_annotation")
 
-    def __init__(self, series, cold: bool, name=("span", "call")):
-        dur_cold, dur_warm, rows_c, errors = series
+    def __init__(self, series, cold: bool, rows: Optional[int] = None,
+                 device: bool = False):
+        (dur_cold, dur_warm, self._rows_c, self._errors, self._name,
+         self._label) = series
         self._dur = dur_cold if cold else dur_warm
-        self._rows_c = rows_c
-        self._errors = errors
-        self._name = name
-        self.rows: Optional[int] = None
+        self._device = device  # stage spans run the device-profiling hook
+        self.rows = rows
 
     def set_rows(self, n: Optional[int]) -> None:
         self.rows = n
@@ -126,28 +147,42 @@ class Span:
     def __enter__(self) -> "Span":
         # trace-context attachment: when a trace is active in this thread
         # (a serving engine activated the batch's pipeline span), this
-        # stage span also lands in the trace as a child. Cost with no
-        # active trace: one module-bool check + one contextvar read.
-        self._trace_parent = (_tracing.current_span()
-                              if _tracing.is_enabled() else None)
-        # device-profiling snapshot (FLOPs/bytes thread-local counters);
-        # cost with no profiler installed: one module-global check
-        self._prof0 = _profiler.enter() if _profiler is not None else None
+        # span begins a child of the current span and is current itself
+        # until it exits, so spans nested in it become ITS children. Cost
+        # with no active trace: one module-bool check + one contextvar read.
+        parent = (_tracing.current_span()
+                  if _tracing.is_enabled() else None)
+        self._trace_span = None if parent is None else \
+            parent.tracer.begin_span(
+                f"{self._name[0]}.{self._name[1]}", parent).__enter__()
+        # cost with no hook installed: one module-global check
+        prof = _profiler
+        if prof is None:
+            self._prof0 = self._annotation = None
+        else:
+            self._prof0 = prof.enter() if self._device else None
+            self._annotation = prof.annotate(self._label, self.rows)
         self._t0 = _now_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         elapsed_s = (_now_ns() - self._t0) * 1e-9
-        self._dur.observe(elapsed_s)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        ts = self._trace_span
+        # the exemplar is the trace this span is part of, as the ambient
+        # lookup would find; handed over, the lookup is not made again
+        self._dur.observe(elapsed_s, None if ts is None else ts.trace_id,
+                          ambient=False)
         cost = None
         if self._prof0 is not None and _profiler is not None:
             try:
                 cost = _profiler.exit(self._prof0, self._name, elapsed_s)
             except Exception:
                 pass  # accounting must never break the instrumented call
-        tp = self._trace_parent
-        if tp is not None:
-            attrs = {"stage": self._name[0], "method": self._name[1]}
+        if ts is not None:
+            attrs = ts.attributes
+            attrs["stage"], attrs["method"] = self._name
             if self.rows is not None:
                 attrs["rows"] = self.rows
             if cost is not None:
@@ -156,9 +191,7 @@ class Span:
                 attrs["flops"] = cost[0]
                 if cost[1] > 0:
                     attrs["hbm_bytes"] = cost[1]
-            tp.tracer.record(f"{self._name[0]}.{self._name[1]}", parent=tp,
-                             duration_s=elapsed_s, attributes=attrs,
-                             error=exc if exc_type is not None else None)
+            ts.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             # rows only count on SUCCESS (a failed fit trained nothing;
             # counting its input would inflate throughput on every retry)
@@ -185,9 +218,11 @@ _NOOP = _NoopSpan()
 
 
 def span(stage: str, method: str = "call", cold: bool = False,
-         registry: Optional[MetricsRegistry] = None):
-    """Record a span named (``stage``, ``method``) into ``registry`` (the
-    process default when omitted).
+         registry: Optional[MetricsRegistry] = None,
+         rows: Optional[int] = None):
+    """Record a phase span named (``stage``, ``method``) into ``registry``
+    (the process default when omitted). ``rows`` known at entry also label
+    the span's annotation in a profiler trace.
 
     >>> with span("ingest", "decode") as sp:
     ...     sp.set_rows(128)
@@ -195,7 +230,7 @@ def span(stage: str, method: str = "call", cold: bool = False,
     if not _enabled:
         return _NOOP
     return Span(_series_for(registry or get_registry(), stage, method), cold,
-                name=(stage, method))
+                rows)
 
 
 def stage_span(stage_obj: Any, method: str):
@@ -222,7 +257,7 @@ def stage_span(stage_obj: Any, method: str):
             return _NOOP
         return Span(_series_for(get_registry(),
                                 type(stage_obj).__name__, method), False,
-                    name=(type(stage_obj).__name__, method))
+                    device=True)
     warm_set = marker[1]
     cold = method not in warm_set
     if cold:
@@ -238,4 +273,4 @@ def stage_span(stage_obj: Any, method: str):
         marker[2][method] = (reg, series)
     else:
         series = cached[1]
-    return Span(series, cold, name=(type(stage_obj).__name__, method))
+    return Span(series, cold, device=True)

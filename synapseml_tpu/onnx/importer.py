@@ -606,6 +606,7 @@ class OnnxFunction:
 
     def _run_graph(self, graph: GraphProto, env: Dict[str, Any],
                    opset: "int | None" = None) -> None:
+        import jax
         import jax.numpy as jnp
 
         opset = self.opset if opset is None else opset
@@ -629,19 +630,27 @@ class OnnxFunction:
             return run
 
         for node in graph.node:
+            # every op a node stages carries ``<op_type>.<node name>`` in
+            # its scope path (trace time only): a profile of the compiled
+            # program names device ops by the graph's own nodes
+            scope = (f"{node.op_type}."
+                     f"{node.name or next(filter(None, node.output), '')}")
             fdef = self.functions.get((node.domain, node.op_type))
             # builtins win only in the standard domains; a custom-domain
             # function whose name collides with a builtin must still expand
             if fdef is not None and (node.domain not in ("", "ai.onnx")
                                      or node.op_type not in OPS):
-                self._run_function(fdef, node, env, to_std)
+                with jax.named_scope(scope):
+                    self._run_function(fdef, node, env, to_std)
                 continue
             try:
                 fn = OPS[node.op_type]
             except KeyError:
                 raise NotImplementedError(f"unsupported ONNX op {node.op_type}") from None
-            if self.channels_last and self._try_nhwc(node, env, nhwc):
-                continue
+            if self.channels_last:
+                with jax.named_scope(scope):
+                    if self._try_nhwc(node, env, nhwc):
+                        continue
             for i in node.input:  # fallback consumers get standard layout
                 to_std(i)
             inputs = [env[i] if i else None for i in node.input]
@@ -661,12 +670,11 @@ class OnnxFunction:
                         and node.op_type != "Dropout")
             try:
                 if const_in:
-                    import jax
-
                     with jax.ensure_compile_time_eval():
                         out = fn(inputs, node.attrs(), ctx)
                 else:
-                    out = fn(inputs, node.attrs(), ctx)
+                    with jax.named_scope(scope):
+                        out = fn(inputs, node.attrs(), ctx)
             except Exception as e:
                 raise type(e)(
                     f"while executing node {node.name or '?'} ({node.op_type}) "

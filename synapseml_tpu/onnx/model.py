@@ -21,9 +21,25 @@ import numpy as np
 from ..core import (ColumnSpec, ComplexParam, Param, Table, TableSchema,
                     Transformer)
 from ..core.params import ParamValidators
+from ..observability import spans as _spans
+from ..observability.metrics import get_registry
 from .importer import OnnxFunction, model_io_specs
 
 __all__ = ["ONNXModel"]
+
+# what crossed the host/device boundary, counted where it crosses (the
+# phase spans of ``_run_buckets``) and added to the registry once a call;
+# docs/observability.md has the use of each
+_COUNTERS = (
+    ("smt_onnx_padded_rows_total",
+     "rows repeated to fill a short last bucket up to batch_size"),
+    ("smt_onnx_upload_bytes_total",
+     "bytes of the host arrays handed to the program"),
+    ("smt_onnx_download_bytes_total",
+     "bytes of the fetched outputs read back to the host"),
+    ("smt_onnx_unfetched_output_bytes_total",
+     "bytes of program outputs that no fetch_dict entry read"),
+)
 
 
 class ONNXModel(Transformer):
@@ -160,6 +176,16 @@ class ONNXModel(Transformer):
 
     def transform_arrays(self, feeds: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         """Batched execution with pad-to-bucket; returns full-length outputs."""
+        parts = self._run_buckets(feeds)
+        with _spans.span("ONNXModel", "assemble"):
+            return {k: np.concatenate(v, axis=0) for k, v in parts.items()}
+
+    def _run_buckets(self, feeds: Dict[str, np.ndarray]) -> Dict[str, List[np.ndarray]]:
+        """Every bucket's fetched outputs, in order, one list an output
+        column. A bucket passes through three phase spans: ``pad`` (slice
+        it out, fill a short one), ``dispatch`` (hand it to the program;
+        returns when the runtime has taken the call, not when the device
+        is done) and ``fetch`` (the host blocked on the reply)."""
         fn = self.fn
         n = len(next(iter(feeds.values())))
         if n == 0:  # empty partitions are normal in a partitioned pipeline
@@ -179,52 +205,71 @@ class ONNXModel(Transformer):
                         f"ONNXModel({self.uid}): graph has no output {name!r}; "
                         f"outputs: {list(result)}"
                     )
-                out0[col] = np.asarray(result[name])[:0]
+                out0[col] = [np.asarray(result[name])[:0]]
             return out0
         b = min(self.batch_size, max(1, n))
+        span = _spans.span
+        fetched = set(self.fetch_dict.values())
+        padded = uploaded = downloaded = unfetched = 0  # in _COUNTERS' order
         out_parts: Dict[str, List[np.ndarray]] = {k: [] for k in self.fetch_dict}
         for lo in range(0, n, b):
             hi = min(lo + b, n)
-            batch = {k: v[lo:hi] for k, v in feeds.items()}
             pad = b - (hi - lo)
-            if pad:
-                batch = {
-                    k: np.concatenate([v, np.repeat(v[-1:], pad, axis=0)]) for k, v in batch.items()
-                }
-            result = fn(batch)
-            for out_col, onnx_name in self.fetch_dict.items():
-                if onnx_name not in result:
-                    raise ValueError(
-                        f"ONNXModel({self.uid}): graph has no output {onnx_name!r}; "
-                        f"outputs: {list(result)}"
-                    )
-                r = np.asarray(result[onnx_name])
-                out_parts[out_col].append(r[: hi - lo] if pad else r)
-        return {k: np.concatenate(v, axis=0) for k, v in out_parts.items()}
+            with span("ONNXModel", "pad", rows=hi - lo):
+                batch = {k: v[lo:hi] for k, v in feeds.items()}
+                if pad:
+                    batch = {
+                        k: np.concatenate([v, np.repeat(v[-1:], pad, axis=0)]) for k, v in batch.items()
+                    }
+                    padded += pad
+            with span("ONNXModel", "dispatch", rows=b):
+                result = fn(batch)
+                uploaded += sum(v.nbytes for v in batch.values()
+                                if isinstance(v, np.ndarray))
+            with span("ONNXModel", "fetch", rows=hi - lo):
+                for out_col, onnx_name in self.fetch_dict.items():
+                    if onnx_name not in result:
+                        raise ValueError(
+                            f"ONNXModel({self.uid}): graph has no output {onnx_name!r}; "
+                            f"outputs: {list(result)}"
+                        )
+                    r = np.asarray(result[onnx_name])
+                    out_parts[out_col].append(r[: hi - lo] if pad else r)
+                    downloaded += r.nbytes
+                unfetched += sum(v.nbytes for name, v in result.items()
+                                 if name not in fetched)
+        if _spans.is_enabled():  # the counters go off with the spans
+            reg = get_registry()
+            for (name, help_), v in zip(_COUNTERS, (padded, uploaded,
+                                                    downloaded, unfetched)):
+                reg.counter(name, help_).inc(v)
+        return out_parts
 
     # -- transform -----------------------------------------------------------------
 
     def _transform(self, table: Table) -> Table:
-        if not self.feed_dict or not self.fetch_dict:
-            raise ValueError(f"ONNXModel({self.uid}): feed_dict and fetch_dict must be set")
-        unknown = [k for k in self.feed_dict if k not in self.fn.input_names]
-        if unknown:
-            raise ValueError(
-                f"ONNXModel({self.uid}): feed_dict keys {unknown} are not graph inputs; "
-                f"graph expects {self.fn.input_names}"
-            )
-        for onnx_in, col in self.feed_dict.items():
-            self._validate_input(table, col)
-        feeds = {onnx_in: self._gather_feed(table, col) for onnx_in, col in self.feed_dict.items()}
-        outputs = self.transform_arrays(feeds)
-        out = table
-        for col, arr in outputs.items():
-            out = out.with_column(col, arr)
-        for src, dst in self.softmax_dict.items():
-            x = np.asarray(out[src], dtype=np.float64)
-            x = x - x.max(axis=-1, keepdims=True)
-            e = np.exp(x)
-            out = out.with_column(dst, (e / e.sum(axis=-1, keepdims=True)).astype(np.float32))
-        for src, dst in self.argmax_dict.items():
-            out = out.with_column(dst, np.argmax(np.asarray(out[src]), axis=-1).astype(np.int64))
+        with _spans.span("ONNXModel", "gather", rows=len(table)):
+            if not self.feed_dict or not self.fetch_dict:
+                raise ValueError(f"ONNXModel({self.uid}): feed_dict and fetch_dict must be set")
+            unknown = [k for k in self.feed_dict if k not in self.fn.input_names]
+            if unknown:
+                raise ValueError(
+                    f"ONNXModel({self.uid}): feed_dict keys {unknown} are not graph inputs; "
+                    f"graph expects {self.fn.input_names}"
+                )
+            for onnx_in, col in self.feed_dict.items():
+                self._validate_input(table, col)
+            feeds = {onnx_in: self._gather_feed(table, col) for onnx_in, col in self.feed_dict.items()}
+        parts = self._run_buckets(feeds)
+        with _spans.span("ONNXModel", "assemble", rows=len(table)):
+            out = table
+            for col, v in parts.items():
+                out = out.with_column(col, np.concatenate(v, axis=0))
+            for src, dst in self.softmax_dict.items():
+                x = np.asarray(out[src], dtype=np.float64)
+                x = x - x.max(axis=-1, keepdims=True)
+                e = np.exp(x)
+                out = out.with_column(dst, (e / e.sum(axis=-1, keepdims=True)).astype(np.float32))
+            for src, dst in self.argmax_dict.items():
+                out = out.with_column(dst, np.argmax(np.asarray(out[src]), axis=-1).astype(np.int64))
         return out

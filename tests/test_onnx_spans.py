@@ -1,0 +1,197 @@
+"""What ``ONNXModel.transform`` records about itself (ISSUE 25): phase spans
+in the registry and in a profiler capture, byte and row counters at the
+host/device boundary, ONNX node names on the ops it stages.
+
+One table of two and a half buckets (20 rows at ``batch_size`` 8) of the
+zoo's BERTTiny at S=16, fetching ``logits`` [N,2] and ``pooled`` [N,128] and
+leaving ``sequence`` [N,16,128] on the device, so every count below follows
+from the shapes.
+"""
+
+import glob
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from synapseml_tpu.core import Table
+from synapseml_tpu.models.zoo import build_model_bytes
+from synapseml_tpu.observability import profiling, spans
+from synapseml_tpu.observability.metrics import MetricsRegistry, set_registry
+from synapseml_tpu.onnx import ONNXModel
+from synapseml_tpu.onnx.importer import OnnxFunction
+
+ROWS, BUCKET, S = 20, 8, 16
+BUCKETS = 3  # 8 + 8 + 4 padded by 4
+PHASES = ("gather", "pad", "dispatch", "fetch", "assemble")
+
+
+@pytest.fixture(scope="module")
+def model_bytes():
+    return build_model_bytes("BERTTiny", seed=0)
+
+
+@pytest.fixture(scope="module")
+def table():
+    ids = np.random.default_rng(0).integers(0, 1000, (ROWS, S))
+    return Table({"input_ids": ids.astype(np.int64)})
+
+
+@pytest.fixture(scope="module")
+def model(model_bytes, table):
+    m = ONNXModel(model_bytes=model_bytes,
+                  feed_dict={"input_ids": "input_ids"},
+                  fetch_dict={"logits": "logits", "pooled": "pooled"},
+                  batch_size=BUCKET, dtype_policy="bfloat16")
+    m.transform(table)  # the compile
+    return m
+
+
+@pytest.fixture
+def fresh_registry():
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        yield reg
+    finally:
+        set_registry(prev)
+
+
+@pytest.fixture(scope="module")
+def one_call(model, table):
+    """The registry after one warm ``transform`` of the table."""
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        out = model.transform(table)
+        assert out["logits"].shape == (ROWS, 2)
+        return reg.snapshot()["families"]
+    finally:
+        set_registry(prev)
+
+
+def _samples(families, stage, method):
+    fam = families.get("smt_stage_duration_seconds", {"series": []})
+    return sum(s["count"] for s in fam["series"]
+               if s["labels"][:2] == [stage, method])
+
+
+@pytest.mark.parametrize("stage,method,expected", [
+    ("ONNXModel", "transform", 1),
+    ("ONNXModel", "gather", 1),
+    ("ONNXModel", "pad", BUCKETS),
+    ("ONNXModel", "dispatch", BUCKETS),
+    ("ProfiledJit", "execute", BUCKETS),
+    ("ONNXModel", "fetch", BUCKETS),
+    ("ONNXModel", "assemble", 1),
+])
+def test_each_span_samples_once_a_bucket_or_once_a_call(one_call, stage,
+                                                        method, expected):
+    assert _samples(one_call, stage, method) == expected
+
+
+@pytest.mark.parametrize("family,expected", [
+    ("smt_onnx_padded_rows_total", BUCKETS * BUCKET - ROWS),
+    # int64 ids as the host holds them, padding included
+    ("smt_onnx_upload_bytes_total", BUCKETS * BUCKET * S * 8),
+    ("smt_onnx_download_bytes_total", BUCKETS * BUCKET * (2 + 128) * 4),
+    ("smt_onnx_unfetched_output_bytes_total", BUCKETS * BUCKET * S * 128 * 4),
+])
+def test_counters_equal_what_the_shapes_give(one_call, family, expected):
+    assert [s["value"] for s in one_call[family]["series"]] == [expected]
+
+
+def test_phase_spans_count_the_rows_they_handled(one_call):
+    rows = {tuple(s["labels"]): s["value"]
+            for s in one_call["smt_stage_rows_total"]["series"]}
+    assert rows[("ONNXModel", "gather")] == ROWS
+    assert rows[("ONNXModel", "pad")] == ROWS              # rows sliced
+    assert rows[("ONNXModel", "dispatch")] == BUCKETS * BUCKET  # rows sent
+    assert rows[("ONNXModel", "fetch")] == ROWS
+    assert rows[("ONNXModel", "transform")] == ROWS
+
+
+def _caller_line(trace_dir):
+    """[(name, start, end)] of the host line that holds the annotations."""
+    from jax.profiler import ProfileData
+
+    found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert found, "the capture wrote no trace"
+    for plane in ProfileData.from_file(found[0]).planes:
+        for line in plane.lines:
+            events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                      for e in line.events]
+            if any(name.startswith("smt.") for name, _, _ in events):
+                yield events
+
+
+def test_a_profile_holds_the_phases_inside_transform_on_one_line(
+        model, table, tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        model.transform(table)
+    lines = list(_caller_line(str(tmp_path)))
+    assert len(lines) == 1, "all of one call's spans are on the caller's line"
+    smt = [e for e in lines[0] if e[0].startswith("smt.")]
+    (_, lo, hi), = [e for e in smt if e[0] == "smt.ONNXModel.transform"]
+    inside = [name for name, a, b in smt if lo <= a and b <= hi]
+    for phase in PHASES:
+        want = 1 if phase in ("gather", "assemble") else BUCKETS
+        assert inside.count(f"smt.ONNXModel.{phase}") == want, phase
+    # the runtime's part of a dispatch lies inside the dispatch it is part of
+    dispatches = [(a, b) for n, a, b in smt if n == "smt.ONNXModel.dispatch"]
+    executes = [(a, b) for n, a, b in smt if n == "smt.ProfiledJit.execute"]
+    assert len(executes) == BUCKETS
+    for (a, b), (lo_d, hi_d) in zip(executes, dispatches):
+        assert lo_d <= a and b <= hi_d
+
+
+def test_spans_disable_leaves_no_annotation_and_no_sample(
+        model, table, fresh_registry, tmp_path):
+    spans.disable()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            model.transform(table)
+    finally:
+        spans.enable()
+    assert list(_caller_line(str(tmp_path))) == []
+    families = fresh_registry.snapshot()["families"]
+    assert "smt_stage_duration_seconds" not in families
+    assert not [name for name in families if name.startswith("smt_onnx_")]
+
+
+def test_lowered_program_names_ops_by_onnx_node(model_bytes):
+    fn = OnnxFunction(model_bytes, dtype_policy="bfloat16")
+    text = jax.jit(fn._run_positional).lower(
+        np.zeros((BUCKET, S), np.int64)).as_text(debug_info=True)
+    # <op_type>.<node name>, under the program's own name (which the
+    # benchmark finds its module by)
+    assert "jit(_run_positional)/MatMul.MatMul_l1_f0/dot_general" in text
+    assert "/Softmax.Softmax_l0_att_" in text
+    assert "/Gather." in text and "/LayerNormalization." in text
+
+
+def test_phase_spans_leave_device_memory_alone(model, table, fresh_registry,
+                                               monkeypatch):
+    """On a backend with allocator statistics (here: faked) only the stage
+    span sweeps them; the six spans inside it make no sweep and no
+    ``smt_stage_hbm_*`` series."""
+    sweeps = []
+
+    def fake_memory_stats():
+        sweeps.append(1)
+        return [("tpu:0", {"bytes_in_use": 10, "peak_bytes_in_use": 20})]
+
+    state = profiling._DeviceState()
+    state.devices, state.has_memory_stats = [object()], True
+    monkeypatch.setattr(profiling, "_DEV", state)
+    monkeypatch.setattr(profiling, "memory_stats", fake_memory_stats)
+    model.transform(table)
+    assert len(sweeps) == 1
+    families = fresh_registry.snapshot()["families"]
+    for name in ("smt_stage_hbm_live_bytes", "smt_stage_hbm_peak_bytes"):
+        assert [s["labels"] for s in families[name]["series"]] == \
+            [["ONNXModel", "transform"]]
+    assert [s["labels"] for s in families["smt_stage_flops_total"]["series"]] \
+        == [["ONNXModel", "transform"]]

@@ -127,6 +127,46 @@ def test_stage_spans_attach_to_active_trace(fresh_tracer):
     assert stage_span["attributes"]["rows"] == 3
 
 
+def test_nested_spans_form_a_tree_with_true_start_times(fresh_tracer):
+    """A span entered while a trace span is current is that span's child and
+    is current itself until it exits: ``ONNXModel.dispatch`` hangs under
+    ``ONNXModel.transform``, not beside it under the pipeline span, and each
+    span's start is when it began (not its end less its duration)."""
+    from synapseml_tpu.models.zoo import build_model_bytes
+    from synapseml_tpu.onnx import ONNXModel
+
+    model = ONNXModel(model_bytes=build_model_bytes("BERTTiny", seed=0),
+                      feed_dict={"input_ids": "input_ids"},
+                      fetch_dict={"logits": "logits"}, batch_size=4)
+    t = Table({"input_ids": np.zeros((6, 8), np.int64)})
+    with tracing.start_span("pipeline", parent=None):
+        model.transform(t)
+        assert tracing.current_span().name == "pipeline"  # restored
+    (trace,) = fresh_tracer.snapshot()["traces"]
+    by_name = {}
+    for s in trace["spans"]:
+        by_name.setdefault(s["name"], []).append(s)
+    (pipe,), (transform,) = by_name["pipeline"], by_name["ONNXModel.transform"]
+    assert transform["parent_id"] == pipe["span_id"]
+    for phase, n in (("gather", 1), ("pad", 2), ("dispatch", 2), ("fetch", 2),
+                     ("assemble", 1)):
+        children = by_name[f"ONNXModel.{phase}"]
+        assert len(children) == n
+        for child in children:
+            assert child["parent_id"] == transform["span_id"], phase
+            assert child["attributes"]["method"] == phase
+            assert transform["start_ts"] <= child["start_ts"]
+            assert child["start_ts"] + child["duration_s"] <= \
+                transform["start_ts"] + transform["duration_s"] + 1e-3
+    dispatches = by_name["ONNXModel.dispatch"]
+    for execute, dispatch in zip(by_name["ProfiledJit.execute"], dispatches):
+        assert execute["parent_id"] == dispatch["span_id"]
+    # two buckets in order: the second dispatch began after the first ended
+    assert dispatches[0]["start_ts"] + dispatches[0]["duration_s"] <= \
+        dispatches[1]["start_ts"] + 1e-3
+    assert transform["attributes"]["rows"] == 6
+
+
 def test_disable_makes_serving_untraced(fresh_tracer):
     """tracing.disable() gates the CREATION sites: a served request opens
     no spans, records no trace, and tags no exemplars."""
