@@ -14,9 +14,12 @@ distinct input shape triggers one retrace — callers batch with fixed bucket si
 (``ONNXModel`` pads minibatches for exactly this reason; the reference instead pins
 shape(0)=batch at ``ONNXModel.scala:357-362``).
 
-``dtype_policy='bfloat16'`` runs floating-point compute in bf16 (inputs/weights cast,
-matmul/conv accumulate in f32 via ``preferred_element_type``, outputs returned f32) —
-the MXU-native mode.
+``dtype_policy='bfloat16'`` is the MXU-native mode: every floating tensor one node hands
+to the next is bfloat16 (inputs and weights are cast on the way in), float32 lives only
+inside an op (the accumulator of MatMul/Gemm/Conv, the reductions of Softmax and
+LayerNormalization), and graph outputs are returned float32. A graph's own
+``Cast(to=FLOAT)`` is honoured; ``smt_onnx_float32_handoff_bytes{fn}`` says how many
+bytes of float32 a traced program still passes between nodes.
 """
 
 from __future__ import annotations
@@ -411,7 +414,18 @@ class OnnxFunction:
             env[name] = v
         for name, arr in zip(self.input_names, arrays):
             env[name] = self._cast_policy_in(arr)
-        self._run_graph(self.graph, env)
+        # float32 bytes handed from node to node under the bfloat16 policy,
+        # summed while the graph is traced (so once a compiled program)
+        handoff = [0] if self.dtype_policy == "bfloat16" else None
+        self._run_graph(self.graph, env, handoff=handoff)
+        if handoff is not None:
+            from ..observability.metrics import get_registry
+
+            get_registry().gauge(
+                "smt_onnx_float32_handoff_bytes",
+                "bytes of float32 tensors one node hands another in the "
+                "newest program traced under dtype_policy='bfloat16'",
+                ("fn",), merge="max").labels(self._jit.name).set(handoff[0])
         outs = []
         for name in self.output_names:
             v = env[name]
@@ -546,7 +560,8 @@ class OnnxFunction:
 
         return False
 
-    def _run_function(self, fdef, call, env: Dict[str, Any], to_std) -> None:
+    def _run_function(self, fdef, call, env: Dict[str, Any], to_std,
+                      handoff: "List[int] | None" = None) -> None:
         """Inline-expand a model-local function call: bind formal inputs,
         substitute ``ref_attr_name`` attributes from the call site (falling
         back to ``attribute_proto`` defaults, recursing into subgraph
@@ -599,19 +614,24 @@ class OnnxFunction:
         # the body executes under ITS opset (pre-13 bodies keep e.g.
         # attribute-form Unsqueeze even inside an opset-13+ model)
         self._run_graph(body, fenv,
-                        opset=fdef.opset_imports.get("") or None)
+                        opset=fdef.opset_imports.get("") or None,
+                        handoff=handoff)
         for formal, actual in zip(fdef.output, call.output):
             if actual:
                 env[actual] = fenv[formal]
 
     def _run_graph(self, graph: GraphProto, env: Dict[str, Any],
-                   opset: "int | None" = None) -> None:
+                   opset: "int | None" = None,
+                   handoff: "List[int] | None" = None) -> None:
         import jax
         import jax.numpy as jnp
 
         opset = self.opset if opset is None else opset
         accum = jnp.float32 if self.dtype_policy == "bfloat16" else None
         nhwc: set = set()  # value names currently stored channels-last
+        # names this graph's nodes make that no node has been seen to read yet
+        unread = ({o for n in graph.node for o in n.output}
+                  if handoff is not None else set())
 
         def to_std(name: str) -> None:
             if name in nhwc:
@@ -623,7 +643,7 @@ class OnnxFunction:
                 for name in list(nhwc):  # subgraphs see standard layout
                     to_std(name)
                 sub_env = dict(env)
-                self._run_graph(sub, sub_env, opset=opset)
+                self._run_graph(sub, sub_env, opset=opset, handoff=handoff)
                 vals = [sub_env[o.name] for o in sub.output]
                 return vals[0] if len(vals) == 1 else tuple(vals)
 
@@ -635,13 +655,18 @@ class OnnxFunction:
             # program names device ops by the graph's own nodes
             scope = (f"{node.op_type}."
                      f"{node.name or next(filter(None, node.output), '')}")
+            for i in unread.intersection(node.input):
+                unread.discard(i)  # a tensor counts once, at its first reader
+                v = env[i]
+                if not _is_const(v) and getattr(v, "dtype", None) == jnp.float32:
+                    handoff[0] += v.size * 4
             fdef = self.functions.get((node.domain, node.op_type))
             # builtins win only in the standard domains; a custom-domain
             # function whose name collides with a builtin must still expand
             if fdef is not None and (node.domain not in ("", "ai.onnx")
                                      or node.op_type not in OPS):
                 with jax.named_scope(scope):
-                    self._run_function(fdef, node, env, to_std)
+                    self._run_function(fdef, node, env, to_std, handoff)
                 continue
             try:
                 fn = OPS[node.op_type]

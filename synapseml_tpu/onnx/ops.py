@@ -16,7 +16,9 @@ inputs, Slice attrs (<10) vs inputs.
 
 TPU notes: convs/matmuls go through ``lax.conv_general_dilated``/``jnp.matmul`` and land
 on the MXU; XLA picks layouts (NCHW semantics preserved from ONNX). bf16 execution is
-applied at the executor level by dtype policy, not per-op.
+applied at the executor level by dtype policy (it casts inputs and weights); an op's
+part is to hand on the dtype of its operands: products return their float32 accumulator
+rounded to it, Softmax/LogSoftmax/LayerNormalization reduce in float32 inside.
 """
 
 from __future__ import annotations
@@ -204,7 +206,26 @@ def _gelu(inputs, attrs, ctx):
     return jax.nn.gelu(inputs[0], approximate=approx)
 
 
+def _float32_inside(fn):
+    """A bfloat16 first input is computed on as its float32 upcast and the
+    result rounded once to bfloat16: reductions (softmax's sum, a norm's mean
+    and variance) keep float32 inside the op while the tensor handed on stays
+    narrow. On the TPU the upcast fuses into the op; any other dtype passes
+    through untouched."""
+
+    @functools.wraps(fn)
+    def wrapped(inputs, attrs, ctx):
+        x = inputs[0]
+        if getattr(x, "dtype", None) != jnp.bfloat16:
+            return fn(inputs, attrs, ctx)
+        out = fn([x.astype(jnp.float32), *inputs[1:]], attrs, ctx)
+        return out.astype(jnp.bfloat16)
+
+    return wrapped
+
+
 @op("Softmax")
+@_float32_inside
 def _softmax(inputs, attrs, ctx):
     axis = attrs.get("axis", -1 if ctx["opset"] >= 13 else 1)
     if ctx["opset"] >= 13:
@@ -219,6 +240,7 @@ def _softmax(inputs, attrs, ctx):
 
 
 @op("LogSoftmax")
+@_float32_inside
 def _log_softmax(inputs, attrs, ctx):
     axis = attrs.get("axis", -1 if ctx["opset"] >= 13 else 1)
     return jax.nn.log_softmax(inputs[0], axis=axis)
@@ -252,7 +274,12 @@ def _cumsum(inputs, attrs, ctx):
 
 @op("MatMul")
 def _matmul(inputs, attrs, ctx):
-    return jnp.matmul(inputs[0], inputs[1], preferred_element_type=ctx.get("accum_dtype"))
+    a, b = inputs[0], inputs[1]
+    out = jnp.matmul(a, b, preferred_element_type=ctx.get("accum_dtype"))
+    # the accumulator's dtype stays inside the op, as in Gemm and Conv: two
+    # bfloat16 operands hand on bfloat16, a float32 operand keeps float32
+    dtype = jnp.promote_types(a.dtype, b.dtype)
+    return out.astype(dtype) if out.dtype != dtype else out
 
 
 @op("Gemm")
@@ -458,6 +485,7 @@ def _instancenorm(inputs, attrs, ctx):
 
 
 @op("LayerNormalization")
+@_float32_inside
 def _layernorm(inputs, attrs, ctx):
     x = inputs[0]
     scale = inputs[1] if len(inputs) > 1 else None

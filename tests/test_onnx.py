@@ -911,3 +911,168 @@ def OPS_LSTM_REF(x, w, r):
     y, _, _ = OPS["LSTM"]([jnp.asarray(x), w, r], {"hidden_size": r.shape[-1]},
                           {"op_type": "LSTM", "opset": 17})
     return y
+
+
+# -- dtype_policy="bfloat16": bfloat16 between nodes, float32 inside an op ----
+
+def _parent_matmul(inputs, attrs, ctx):
+    """``MatMul`` as it stood before it cast back: the accumulator leaves."""
+    return jnp.matmul(inputs[0], inputs[1],
+                      preferred_element_type=ctx.get("accum_dtype"))
+
+
+def _handoff_bytes(mb, feed, **kw):
+    """``smt_onnx_float32_handoff_bytes`` after tracing ``mb`` once (no
+    compile), and the traced outputs' avals."""
+    import jax
+
+    from synapseml_tpu.observability.metrics import (MetricsRegistry,
+                                                     set_registry)
+
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        fn = OnnxFunction(mb, **kw)
+        outs = jax.eval_shape(fn._run_positional, feed)
+    finally:
+        set_registry(prev)
+    fam = reg.snapshot()["families"].get("smt_onnx_float32_handoff_bytes")
+    if fam is None:
+        return None, outs
+    (series,) = fam["series"]
+    assert series["labels"] == [fn._jit.name]
+    return series["value"], outs
+
+
+def _policy_matmul_vs_gemm(policy, monkeypatch):
+    from synapseml_tpu.onnx.ops import OPS
+
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(8, 16)).astype(np.float32)
+    w = (rng.normal(size=(16, 6)) / 4).astype(np.float32)
+    b = rng.normal(size=6).astype(np.float32)
+    # the ops themselves: what the next node is handed
+    dt = jnp.bfloat16 if policy == "bfloat16" else jnp.float32
+    ctx = {"opset": 17, "accum_dtype": jnp.float32 if policy == "bfloat16" else None}
+    xs, ws, bs = (jnp.asarray(v, dt) for v in (x, w, b))
+    mm = OPS["Add"]([OPS["MatMul"]([xs, ws], {}, ctx), bs], {}, ctx)
+    gemm = OPS["Gemm"]([xs, ws, bs], {}, ctx)
+    assert mm.dtype == gemm.dtype == dt
+    # and through a graph: float32 outputs either way
+    fn = build_fn(
+        [node("MatMul", ["x", "w"], ["y0"]), node("Add", ["y0", "b"], ["y"]),
+         node("Gemm", ["x", "w", "b"], ["z"])],
+        [value_info("x", np.float32, [None, 16])],
+        [value_info("y", np.float32, [None, 6]), value_info("z", np.float32, [None, 6])],
+        {"w": w, "b": b}, dtype_policy=policy)
+    out = fn({"x": x})
+    y, z = np.asarray(out["y"]), np.asarray(out["z"])
+    assert y.dtype == z.dtype == np.float32
+    if policy == "float32":
+        np.testing.assert_array_equal(y, z)
+        np.testing.assert_allclose(y, x @ w + b, rtol=1e-5, atol=1e-5)
+    else:
+        # MatMul rounds the product and then the sum, Gemm the sum alone:
+        # one bfloat16 ulp (2**-8 relative) apart at most, plus the operands'
+        np.testing.assert_allclose(y, z, rtol=2 ** -7, atol=2 ** -7)
+        np.testing.assert_allclose(y, x @ w + b, rtol=3e-2, atol=3e-2)
+
+
+def _float32_inside(op_type, monkeypatch):
+    from synapseml_tpu.onnx.ops import OPS
+
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(3, 5, 32)) * 3, jnp.bfloat16)
+    rest, attrs = [], {"axis": -1}
+    if op_type == "LayerNormalization":
+        rest = [jnp.asarray(rng.normal(size=32), jnp.bfloat16),
+                jnp.asarray(rng.normal(size=32), jnp.bfloat16)]
+        attrs["epsilon"] = 1e-12
+    ctx = {"opset": 17}
+    got = OPS[op_type]([x, *rest], attrs, ctx)
+    want = OPS[op_type]([x.astype(jnp.float32), *rest], attrs, ctx)
+    assert got.dtype == jnp.bfloat16 and want.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint16),
+        np.asarray(want.astype(jnp.bfloat16)).view(np.uint16))
+    if op_type == "Softmax":  # against jax's own, not the op under test
+        import jax
+
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.uint16),
+            np.asarray(jax.nn.softmax(x.astype(jnp.float32), axis=-1)
+                       .astype(jnp.bfloat16)).view(np.uint16))
+
+
+def _float32_policy_program_is_the_parents(_, monkeypatch):
+    import jax
+
+    from synapseml_tpu.models.zoo import build_model_bytes
+    from synapseml_tpu.onnx.ops import OPS
+
+    mb = build_model_bytes("BERTTiny", seed=0)
+    ids = np.random.default_rng(0).integers(0, 1000, (4, 16)).astype(np.int64)
+    fn = OnnxFunction(mb)
+    jaxpr = str(jax.make_jaxpr(fn._run_positional)(ids))
+    out = {k: np.asarray(v) for k, v in fn({"input_ids": ids}).items()}
+    monkeypatch.setitem(OPS, "MatMul", _parent_matmul)
+    for k in ("Softmax", "LogSoftmax", "LayerNormalization"):
+        monkeypatch.setitem(OPS, k, OPS[k].__wrapped__)
+    parent = OnnxFunction(mb)
+    parent_jaxpr = str(jax.make_jaxpr(parent._run_positional)(ids))
+    parent_out = parent({"input_ids": ids})
+    assert jaxpr == parent_jaxpr  # so no convert_element_type the parent lacked
+    for k, v in out.items():
+        np.testing.assert_array_equal(v, np.asarray(parent_out[k]))
+    assert _handoff_bytes(mb, ids)[0] is None  # the gauge is the bfloat16 policy's
+
+
+def _bfloat16_handoff(which, monkeypatch):
+    from synapseml_tpu.models.zoo import build_model_bytes, vit
+    from synapseml_tpu.onnx.ops import OPS
+
+    if which == "bert_tiny":
+        mb = build_model_bytes("BERTTiny", seed=0)
+        feed = np.zeros((4, 16), np.int64)
+    elif which == "vit_two_layers":
+        mb = serialize_model(vit(image_size=32, layers=2, hidden=64, heads=2,
+                                 num_classes=10))
+        feed = np.zeros((2, 3, 32, 32), np.float32)
+    else:  # a graph that asks for float32 itself keeps it, and the gauge says so
+        w = np.ones((16, 6), np.float32)
+        g = make_graph(
+            [node("MatMul", ["x", "w"], ["y"]),
+             node("Cast", ["y"], ["y32"], to=1),
+             node("Softmax", ["y32"], ["p"], axis=-1)],
+            "cast_before_softmax",
+            [value_info("x", np.float32, [None, 16])],
+            [value_info("p", np.float32, [None, 6])], {"w": w})
+        mb = serialize_model(make_model(g, opset=17))
+        feed = np.zeros((4, 16), np.float32)
+    handed, outs = _handoff_bytes(mb, feed, dtype_policy="bfloat16")
+    assert all(o.dtype == np.float32 for o in outs)
+    if which == "explicit_cast":
+        assert handed == 4 * 6 * 4  # y32 alone: p is an output, read by no node
+        return
+    assert handed == 0
+    # the counter counts: with the parent's MatMul every product hands float32 on
+    monkeypatch.setitem(OPS, "MatMul", _parent_matmul)
+    assert _handoff_bytes(mb, feed, dtype_policy="bfloat16")[0] > 0
+
+
+@pytest.mark.parametrize("case,arg", [
+    (_policy_matmul_vs_gemm, "float32"),
+    (_policy_matmul_vs_gemm, "bfloat16"),
+    (_float32_inside, "Softmax"),
+    (_float32_inside, "LogSoftmax"),
+    (_float32_inside, "LayerNormalization"),
+    (_float32_policy_program_is_the_parents, None),
+    (_bfloat16_handoff, "bert_tiny"),
+    (_bfloat16_handoff, "vit_two_layers"),
+    (_bfloat16_handoff, "explicit_cast"),
+], ids=lambda v: getattr(v, "__name__", v))
+def test_bfloat16_policy_hands_on_bfloat16(case, arg, monkeypatch):
+    """ISSUE 26: under ``dtype_policy="bfloat16"`` a tensor one node hands the
+    next is bfloat16 and float32 lives inside an op; the float32 policy's
+    program is what it was."""
+    case(arg, monkeypatch)
