@@ -8,7 +8,9 @@ models — sufficient for throughput benchmarking, integration tests, and archit
 validation (weights are obviously not the pretrained ones; load real weights via
 ``weights`` overrides when available).
 
-Models: ResNet-18/50 (v1.5 bottleneck), a BERT-base-style encoder, ViT-B/16.
+Models: ResNet-18/50 (v1.5 bottleneck), a BERT-base-style encoder, ViT-B/16, and one
+chip's share of the hybrid Mamba-2 / sparse-expert / grouped-query decoder
+``nemotron_h`` (``models/nemotron_h.py``: weights as BFLOAT16 initializers).
 All emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
 can "cut" the head exactly like the reference's ``cutOutputLayers``
 (``ImageFeaturizer.scala:40-197``).
@@ -285,7 +287,22 @@ MODEL_BUILDERS = {
     "BERTBase": lambda **kw: bert_encoder(**kw),
     "BERTTiny": lambda **kw: bert_encoder(layers=2, hidden=128, heads=2, vocab=1000, **kw),
     "ViTB16": lambda **kw: vit(**kw),
+    "NemotronH": lambda **kw: _nemotron_h(**kw),
+    "NemotronHTiny": lambda **kw: _nemotron_h(**{**NEMOTRON_H_TINY, **kw}),
 }
+
+# widths of the CPU tests' nemotron_h: every mechanism of the full graph
+# (two key-value groups, a router wider than its top-k, several chunks)
+NEMOTRON_H_TINY = dict(
+    pattern="ME*E", hidden=64, vocab=128, mamba_heads=4, mamba_head_dim=16,
+    groups=2, state=16, chunk=8, heads=4, kv_heads=2, head_dim=16, experts=8,
+    top_k=2, expert_width=32, shared_width=64, experts_held=8)
+
+
+def _nemotron_h(**kw) -> ModelProto:
+    from .nemotron_h import nemotron_h
+
+    return nemotron_h(**kw)
 
 
 def build_model_bytes(name: str, **kw) -> bytes:

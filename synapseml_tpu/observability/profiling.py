@@ -50,6 +50,7 @@ the fleet-stitched timeline).
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import pickle
@@ -519,6 +520,9 @@ def prewarm_aot_cache() -> Dict[str, int]:
     return out
 
 
+_BOUND = "__bound_arguments__"  # marks ``ProfiledJit.bind``'s signature entry
+
+
 class ProfiledJit:
     """``jax.jit`` with compile/cost accounting.
 
@@ -555,6 +559,9 @@ class ProfiledJit:
         self._last_sig = None
         self._plain = None
         self._aot_broken = False
+        # (avals, placements) of arguments bound by ``bind`` <-> the small
+        # integer that stands for them in a signature
+        self._bound_keys: List[Any] = []
         _INSTANCES.add(self)
 
     def _plain_jit(self):
@@ -578,22 +585,43 @@ class ProfiledJit:
                        if k in kwargs)
         return dyn, static
 
+    def bind(self, *arrays):
+        """``call(*args)`` == ``self(*args, *arrays)`` for trailing arguments
+        that are the same arrays on every call (a model's weights, placed
+        once). Their shapes, types and placements join the signature HERE,
+        once, as one small integer: a call keys on what changes between
+        calls, not on hundreds of weights it has seen before."""
+        from jax.api_util import shaped_abstractify
+
+        key = (tuple(shaped_abstractify(x) for x in arrays),
+               tuple(getattr(x, "sharding", None) for x in arrays))
+        with self._lock:
+            if key not in self._bound_keys:
+                self._bound_keys.append(key)
+            token = self._bound_keys.index(key)
+        return functools.partial(self._call, (_BOUND, token), arrays)
+
     def __call__(self, *args, **kwargs):
+        return self._call(None, (), *args, **kwargs)
+
+    def _call(self, bound_key, bound, *args, **kwargs):
         import jax
 
         if not _enabled or self._aot_broken:
-            return self._plain_jit()(*args, **kwargs)
+            return self._plain_jit()(*args, *bound, **kwargs)
         dyn_kwargs, static = self._split(kwargs)
+        if bound_key is not None:
+            static += (bound_key,)
         try:
             leaves, treedef = jax.tree_util.tree_flatten((args, dyn_kwargs))
         except Exception:
-            return self._plain_jit()(*args, **kwargs)
+            return self._plain_jit()(*args, *bound, **kwargs)
         tracer = jax.core.Tracer
         for leaf in leaves:
             if isinstance(leaf, tracer):
                 # under an outer trace the compilation (and its cost) is
                 # the OUTER program's; inline like plain jit would
-                return self._plain_jit()(*args, **kwargs)
+                return self._plain_jit()(*args, *bound, **kwargs)
         try:
             from jax.api_util import shaped_abstractify
 
@@ -605,7 +633,7 @@ class ProfiledJit:
             placements = tuple(getattr(x, "sharding", None) for x in leaves)
             sig = (treedef, avals, placements, static)
         except Exception:
-            return self._plain_jit()(*args, **kwargs)
+            return self._plain_jit()(*args, *bound, **kwargs)
         entry = self._cache.get(sig)
         if entry is not None:
             # track the last USED signature so a later recompile's cause
@@ -613,14 +641,14 @@ class ProfiledJit:
             # relative to whichever compile happened to come last
             self._last_sig = sig
         else:
-            entry = self._compile(sig, args, kwargs)
+            entry = self._compile(sig, args + bound, kwargs)
             if entry is None:
                 # AOT lower/compile failed. The plain path re-traces: a
                 # genuine user error re-raises with its natural traceback;
                 # success means the AOT machinery specifically is broken
                 # for this fn — stop retrying it (accounting is optional,
                 # the computation is not).
-                out = self._plain_jit()(*args, **kwargs)
+                out = self._plain_jit()(*args, *bound, **kwargs)
                 self._leave_profiled_path("lower/compile failed")
                 return out
         try:
@@ -628,14 +656,14 @@ class ProfiledJit:
             # the upload as is synchronous. What the caller's own span
             # round this call holds beyond it is the keying above.
             with _spans.span("ProfiledJit", "execute"):
-                out = entry.compiled(*args, **dyn_kwargs)
+                out = entry.compiled(*args, *bound, **dyn_kwargs)
         except (TypeError, ValueError) as e:
             # calling-convention or placement mismatch the signature key
             # did not capture (donation, exotic shardings): permanent
             # plain fallback for this fn — plain jit handles these by
             # recompiling, and accounting is optional
             self._leave_profiled_path(f"compiled call refused: {e}")
-            return self._plain_jit()(*args, **kwargs)
+            return self._plain_jit()(*args, *bound, **kwargs)
         acc = _ACC
         acc.flops += entry.flops
         acc.bytes += entry.bytes
@@ -717,6 +745,9 @@ class ProfiledJit:
         key because serialized executables are exactly that fragile — a
         mismatch must read as a miss (silent recompile), never a load."""
         treedef, avals, placements, static = sig
+        # a bound-arguments token is this process's; what it stands for is not
+        static = tuple(self._bound_keys[e[1]] if e[0] is _BOUND else e
+                       for e in static)
         parts = [
             _AOT_MAGIC, self.name, str(treedef),
             "|".join(repr(a) for a in avals),

@@ -59,9 +59,10 @@ def _attr(name: str, v: Any) -> AttributeProto:
 
 
 def node(op_type: str, inputs: Sequence[str], outputs: Sequence[str],
-         name: str = "", **attrs) -> NodeProto:
+         name: str = "", domain: str = "", **attrs) -> NodeProto:
     return NodeProto(
         op_type=op_type,
+        domain=domain,
         name=name or f"{op_type}_{outputs[0] if outputs else ''}",
         input=list(inputs),
         output=list(outputs),
@@ -90,9 +91,12 @@ def make_graph(nodes: Sequence[NodeProto], name: str,
     )
 
 
-def make_model(graph: GraphProto, opset: int = 17, producer: str = "synapseml_tpu") -> ModelProto:
+def make_model(graph: GraphProto, opset: int = 17, producer: str = "synapseml_tpu",
+               domains: Optional[Dict[str, int]] = None) -> ModelProto:
+    """``domains``: version by custom operator domain the graph's nodes use
+    (``{"synapseml_tpu": 1}`` for ``ExpertFFN``)."""
     return ModelProto(ir_version=8, producer_name=producer, graph=graph,
-                      opset_imports={"": opset})
+                      opset_imports={"": opset, **(domains or {})})
 
 
 def save_model(model: ModelProto, path: str) -> None:

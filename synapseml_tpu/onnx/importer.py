@@ -20,11 +20,22 @@ inside an op (the accumulator of MatMul/Gemm/Conv, the reductions of Softmax and
 LayerNormalization), and graph outputs are returned float32. A graph's own
 ``Cast(to=FLOAT)`` is honoured; ``smt_onnx_float32_handoff_bytes{fn}`` says how many
 bytes of float32 a traced program still passes between nodes.
+
+Weights are ARGUMENTS of the compiled program, not literals in it: every floating
+initializer of ``WEIGHT_ARGUMENT_MIN_SIZE`` elements or more is cast to the policy's
+type and placed on the device once, at construction, and handed to the program after
+the feeds on every call. The program is then keyed by the weights' shapes and types,
+never their values (two checkpoints of one graph share one executable), and its size
+does not grow with the model's. Smaller floating initializers, and every integer or
+shape operand, stay constants of the trace, so ``Shape`` folding is untouched.
 """
 
 from __future__ import annotations
 
+import hashlib
 import logging
+import threading
+import weakref
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -37,9 +48,20 @@ __all__ = ["OnnxFunction", "load_model", "model_io_specs"]
 
 _logger = logging.getLogger("synapseml_tpu.onnx")
 
+# floating initializers with at least this many elements are arguments of the
+# program (module docstring); below it sit the scalars and the short vectors
+# that ops read as static values (Resize's scales and roi, Range's bounds)
+WEIGHT_ARGUMENT_MIN_SIZE = 16
+
 
 def _is_const(v) -> bool:
     return isinstance(v, np.ndarray) or np.isscalar(v)
+
+
+def _is_floating(dtype) -> bool:
+    """numpy's floating types and ml_dtypes' bfloat16, which numpy does not
+    count among them."""
+    return np.issubdtype(dtype, np.floating) or dtype.name == "bfloat16"
 
 
 def _value_info_spec(vi: ValueInfo):
@@ -85,6 +107,34 @@ def model_io_specs(model: "ModelProto | bytes"):
     return inputs, outputs
 
 
+class _SharedProgram:
+    """The jitted entry point of every live ``OnnxFunction`` whose trace is
+    the same (``OnnxFunction._program_digest``): compiled once, whichever
+    instance's weights it is then called with. It holds its instances
+    weakly, so a dropped model's weights leave the device."""
+
+    def __init__(self, name: str, closure_key: str):
+        from ..observability.profiling import profiled_jit
+
+        self.instances: "weakref.WeakSet[OnnxFunction]" = weakref.WeakSet()
+        # profiled jit entry point: every XLA compile of this program is
+        # timed into smt_compile_seconds{fn=...}, its cost_analysis FLOPs
+        # cached, and warm calls attribute achieved MFU to the enclosing
+        # stage span (observability/profiling.py)
+        self.jit = profiled_jit(self._run_positional, name=name,
+                                closure_key=closure_key)
+
+    def _run_positional(self, *arrays):  # the XLA module is named after it
+        with _PROGRAMS_LOCK:  # a WeakSet must not change size under the look
+            instance = next(iter(self.instances))
+        return instance._run_positional(*arrays)
+
+
+_PROGRAMS: "weakref.WeakValueDictionary[str, _SharedProgram]" = \
+    weakref.WeakValueDictionary()
+_PROGRAMS_LOCK = threading.Lock()
+
+
 class OnnxFunction:
     """Callable wrapper: ``fn(feeds: dict[str, array]) -> dict[str, array]``.
 
@@ -124,6 +174,12 @@ class OnnxFunction:
             t.name: tensor_to_numpy(t, external_dir=external_data_dir)
             for t in self.graph.initializer
         }
+        if dtype_policy == "float32":
+            # a bfloat16 checkpoint computes in float32 under this policy
+            # (exact: every bfloat16 is a float32)
+            for name, const in self.constants.items():
+                if const.dtype.name == "bfloat16":
+                    self.constants[name] = const.astype(np.float32)
         init_names = set(self.constants)
         # Graph inputs that are not initializers are the real feeds.
         self.input_infos: List[ValueInfo] = [
@@ -147,21 +203,8 @@ class OnnxFunction:
             self._plan_const_specs() if layout is not None
             and (getattr(layout, "model_size", 1) > 1
                  or getattr(layout, "fsdp_size", 1) > 1) else {})
-        for name, spec in self._const_specs.items():
-            const = self.constants[name]
-            if self.dtype_policy == "bfloat16":
-                # cast BEFORE placement: the executable only ever consumes
-                # the bf16 view, and the whole point of tp-sharding is HBM
-                # headroom — a resident f32 master copy would triple it
-                const = const.astype(np.dtype("bfloat16"))
-            self.constants[name] = layout.put(const, spec)
-        # profiled jit entry point: every XLA compile of this model is
-        # timed into smt_compile_seconds{fn=...}, its cost_analysis FLOPs
-        # cached, and warm calls attribute achieved MFU to the enclosing
-        # stage span (observability/profiling.py)
-        from ..observability.profiling import profiled_jit
-
-        graph_name = getattr(self.graph, "name", "") or "graph"
+        self._fn_name = "onnx." + (getattr(self.graph, "name", "") or "graph")
+        self._place_weights()
         # the persisted-AOT digest must see the weight placement: the
         # same graph under a replicated, (1,2)-tp or (2,2,2)-fsdp layout
         # compiles three different executables behind identical input
@@ -171,9 +214,88 @@ class OnnxFunction:
             closure_key += ";layout=" + str(layout.describe()) + ";" + \
                 ",".join(f"{n}:{self._const_specs[n]}"
                          for n in sorted(self._const_specs))
-        self._jit = profiled_jit(self._run_positional,
-                                 name=f"onnx.{graph_name}",
-                                 closure_key=closure_key)
+        # one entry point for every live OnnxFunction of this program: two
+        # checkpoints of one graph differ in arguments only
+        digest = self._program_digest(closure_key)
+        with _PROGRAMS_LOCK:
+            program = _PROGRAMS.get(digest)
+            if program is None:
+                program = _PROGRAMS[digest] = _SharedProgram(
+                    self._fn_name, closure_key)
+            program.instances.add(self)
+        self._program = program
+        self._jit = program.jit
+        # the weights' part of a call's signature is settled here, once
+        self._call_with_weights = program.jit.bind(*self._weights)
+
+    def _program_digest(self, closure_key: str) -> str:
+        """What a trace of ``_run_positional`` depends on besides its
+        arguments: the nodes, the policy, every initializer's name, type and
+        shape, and the VALUES of those that stay constants. Equal digests
+        trace to equal programs. A sharded layout is part of it by identity
+        (its mesh's devices are in the trace)."""
+        from .wire import _ser_attribute, _ser_node
+
+        h = hashlib.sha256(repr((
+            closure_key, self.channels_last, self._external_dir,
+            sorted(self.model.opset_imports.items()),
+            id(self.layout) if self._const_specs else None,
+            [vi.name for vi in self.graph.input], self.output_names,
+            [(key, f.input, f.output, sorted(f.opset_imports.items()),
+              f.attribute, [_ser_attribute(a) for a in f.attribute_proto])
+             for key, f in self.functions.items()])).encode())
+        for graph in (self.graph, *self.functions.values()):
+            for n in graph.node:
+                h.update(_ser_node(n))
+        weights = set(self._weight_names)
+        for name, const in self.constants.items():
+            h.update(repr((name, str(const.dtype), const.shape)).encode())
+            if name not in weights:
+                h.update(np.ascontiguousarray(const).tobytes())
+        return h.hexdigest()
+
+    def _place_weights(self) -> None:
+        """Cast every weight (module docstring) to the policy's type and put
+        it on the device, once; ``constants`` holds the placed array from
+        here on, under its initializer's name. A tensor-parallel weight goes
+        to its planned shards, any other weight of a sharded layout to every
+        device of it (one program cannot mix a mesh with a lone device)."""
+        import jax
+
+        from ..observability import spans
+        from ..observability.metrics import get_registry
+
+        bf16 = np.dtype("bfloat16")
+        sharded = bool(self._const_specs)
+        self._weight_names: List[str] = [
+            name for name, const in self.constants.items()
+            if name in self._const_specs
+            or (_is_floating(const.dtype)
+                and const.size >= WEIGHT_ARGUMENT_MIN_SIZE)]
+        placed = 0
+        with spans.span("ONNXModel", "place_weights"):
+            for name in self._weight_names:
+                const = self.constants[name]
+                if self.dtype_policy == "bfloat16" and const.dtype != bf16:
+                    # cast BEFORE placement: the executable only ever
+                    # consumes the bf16 view; a resident f32 master copy
+                    # would triple the weights' HBM
+                    const = const.astype(bf16)
+                if sharded:
+                    const = self.layout.put(const, self._const_specs.get(
+                        name, self.layout.replicated()))
+                else:
+                    const = jax.device_put(const)
+                self.constants[name] = const
+                placed += const.nbytes
+            self._weights = tuple(self.constants[n]
+                                  for n in self._weight_names)
+            jax.block_until_ready(self._weights)  # the span ends with the upload
+        get_registry().gauge(
+            "smt_onnx_weight_argument_bytes",
+            "bytes of weights placed on the device at construction and "
+            "passed to the program as arguments",
+            ("fn",), merge="max").labels(self._fn_name).set(placed)
 
     # -- public ------------------------------------------------------------------
 
@@ -188,7 +310,7 @@ class OnnxFunction:
             feeds[n] if isinstance(feeds[n], jax.Array) else np.asarray(feeds[n])
             for n in self.input_names
         ]
-        outs = self._jit(*args)
+        outs = self._call_with_weights(*args)
         return dict(zip(self.output_names, outs))
 
     def input_shapes(self) -> Dict[str, Optional[List[Any]]]:
@@ -278,7 +400,7 @@ class OnnxFunction:
 
         for name, rs in roles.items():
             const = self.constants[name]
-            is_float = np.issubdtype(const.dtype, np.floating)
+            is_float = _is_floating(const.dtype)
             if len(rs) != 1 or None in rs:
                 kinds = sorted(str(r) for r in rs)
                 conflict = (f"consumer-role conflict ({', '.join(kinds)}) — "
@@ -388,15 +510,24 @@ class OnnxFunction:
         return x
 
     def _run_positional(self, *arrays):
+        """The program: the feeds in ``input_names``' order, then the weights
+        in ``_weight_names``' order. Called with the feeds alone it takes the
+        placed weights from ``constants`` (they are then literals of that
+        trace: for a caller that lowers the function itself)."""
         import jax.numpy as jnp
 
+        n_feeds = len(self.input_names)
+        weights = dict(zip(self._weight_names,
+                           arrays[n_feeds:] or self._weights))
         env: Dict[str, Any] = {"": None}
         for name, const in self.constants.items():
-            v = (
-                const.astype(np.dtype("bfloat16"))
-                if self.dtype_policy == "bfloat16" and np.issubdtype(const.dtype, np.floating)
-                else const
-            )
+            if name in weights:
+                v = weights[name]  # cast and placed by _place_weights
+            elif self.dtype_policy == "bfloat16" \
+                    and _is_floating(const.dtype):
+                v = const.astype(np.dtype("bfloat16"))
+            else:
+                v = const
             if name in self._const_specs:
                 # re-pin the tensor-parallel placement inside the traced
                 # program so GSPMD partitions the consuming matmul however
@@ -412,15 +543,18 @@ class OnnxFunction:
                     # of this step — at rest only the row shards persist.
                     v = self.layout.gather_for_use(v, spec)
             env[name] = v
-        for name, arr in zip(self.input_names, arrays):
+        for name, arr in zip(self.input_names, arrays[:n_feeds]):
             env[name] = self._cast_policy_in(arr)
         # float32 bytes handed from node to node under the bfloat16 policy,
         # summed while the graph is traced (so once a compiled program)
         handoff = [0] if self.dtype_policy == "bfloat16" else None
-        self._run_graph(self.graph, env, handoff=handoff)
-        if handoff is not None:
-            from ..observability.metrics import get_registry
+        # what ops say of the program being traced (ops._note)
+        notes: Dict[str, int] = {}
+        self._run_graph(self.graph, env, handoff=handoff, notes=notes)
+        from ..observability.metrics import get_registry
 
+        self._record_notes(notes)
+        if handoff is not None:
             get_registry().gauge(
                 "smt_onnx_float32_handoff_bytes",
                 "bytes of float32 tensors one node hands another in the "
@@ -433,6 +567,34 @@ class OnnxFunction:
                 v = v.astype(jnp.float32)
             outs.append(jnp.asarray(v))
         return tuple(outs)
+
+    def _record_notes(self, notes: Dict[str, int]) -> None:
+        """Once a traced program: how its ``Attention`` nodes were lowered,
+        and what its ``ExpertFFN`` nodes are sized for."""
+        from ..observability.metrics import get_registry
+
+        reg, fn = get_registry(), self._jit.name
+        lowering = reg.counter(
+            "smt_onnx_attention_lowering_total",
+            "Attention nodes of a traced program by lowering: flash (the "
+            "Pallas kernel, scores never written) or dense (materialised "
+            "scores: not a TPU, or lengths that do not tile)",
+            ("fn", "kind"))
+        for kind in ("flash", "dense"):
+            if "attention_" + kind in notes:
+                lowering.labels(fn, kind).inc(notes["attention_" + kind])
+        if "expert_pairs" in notes:
+            reg.gauge(
+                "smt_onnx_expert_pairs",
+                "(token, pick) pairs one call of the newest traced program "
+                "presents to its ExpertFFN nodes: what their grouped "
+                "products are sized for",
+                ("fn",), merge="max").labels(fn).set(notes["expert_pairs"])
+            reg.gauge(
+                "smt_onnx_experts_held",
+                "experts held by the ExpertFFN nodes of the newest traced "
+                "program, summed over nodes",
+                ("fn",), merge="max").labels(fn).set(notes["experts_held"])
 
     # unary ops that are layout-agnostic: run them directly on an NHWC array
     _NHWC_UNARY = frozenset({
@@ -561,7 +723,8 @@ class OnnxFunction:
         return False
 
     def _run_function(self, fdef, call, env: Dict[str, Any], to_std,
-                      handoff: "List[int] | None" = None) -> None:
+                      handoff: "List[int] | None" = None,
+                      notes: "Dict[str, int] | None" = None) -> None:
         """Inline-expand a model-local function call: bind formal inputs,
         substitute ``ref_attr_name`` attributes from the call site (falling
         back to ``attribute_proto`` defaults, recursing into subgraph
@@ -615,14 +778,15 @@ class OnnxFunction:
         # attribute-form Unsqueeze even inside an opset-13+ model)
         self._run_graph(body, fenv,
                         opset=fdef.opset_imports.get("") or None,
-                        handoff=handoff)
+                        handoff=handoff, notes=notes)
         for formal, actual in zip(fdef.output, call.output):
             if actual:
                 env[actual] = fenv[formal]
 
     def _run_graph(self, graph: GraphProto, env: Dict[str, Any],
                    opset: "int | None" = None,
-                   handoff: "List[int] | None" = None) -> None:
+                   handoff: "List[int] | None" = None,
+                   notes: "Dict[str, int] | None" = None) -> None:
         import jax
         import jax.numpy as jnp
 
@@ -643,7 +807,8 @@ class OnnxFunction:
                 for name in list(nhwc):  # subgraphs see standard layout
                     to_std(name)
                 sub_env = dict(env)
-                self._run_graph(sub, sub_env, opset=opset, handoff=handoff)
+                self._run_graph(sub, sub_env, opset=opset, handoff=handoff,
+                                notes=notes)
                 vals = [sub_env[o.name] for o in sub.output]
                 return vals[0] if len(vals) == 1 else tuple(vals)
 
@@ -666,7 +831,8 @@ class OnnxFunction:
             if fdef is not None and (node.domain not in ("", "ai.onnx")
                                      or node.op_type not in OPS):
                 with jax.named_scope(scope):
-                    self._run_function(fdef, node, env, to_std, handoff)
+                    self._run_function(fdef, node, env, to_std, handoff,
+                                       notes)
                 continue
             try:
                 fn = OPS[node.op_type]
@@ -686,6 +852,7 @@ class OnnxFunction:
                 "accum_dtype": accum,
                 "subgraph_runner": subgraph_runner,
                 "external_dir": self._external_dir,
+                "notes": notes,
             }
             # Constant folding: all-constant inputs => evaluate OUTSIDE the
             # trace (omnistaging would otherwise stage jnp ops on concrete

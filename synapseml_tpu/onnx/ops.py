@@ -503,6 +503,20 @@ def _layernorm(inputs, attrs, ctx):
     return out
 
 
+@op("RMSNormalization")
+@_float32_inside
+def _rmsnorm(inputs, attrs, ctx):
+    """Opset 23: ``x * rsqrt(mean(x², axes from axis on) + epsilon) * scale``;
+    ``stash_type`` is float32 (the only value the op defines), which
+    ``_float32_inside`` gives a bfloat16 input."""
+    x, scale = inputs[0], inputs[1]
+    if int(attrs.get("stash_type", 1)) != 1:
+        raise NotImplementedError("RMSNormalization: stash_type must be 1")
+    axes = tuple(range(attrs.get("axis", -1) % x.ndim, x.ndim))
+    mean_sq = jnp.mean(jnp.square(x), axis=axes, keepdims=True)
+    return x * lax.rsqrt(mean_sq + attrs.get("epsilon", 1e-5)) * scale
+
+
 @op("GroupNormalization")
 def _groupnorm(inputs, attrs, ctx):
     x, scale, bias = inputs[:3]
@@ -630,6 +644,15 @@ def _gather(inputs, attrs, ctx):
 def _gather_elements(inputs, attrs, ctx):
     x, idx = inputs[0], jnp.asarray(inputs[1])
     axis = attrs.get("axis", 0)
+    if axis % x.ndim == x.ndim - 1 and x.shape[-1] <= 1024 \
+            and idx.shape[-1] <= 16 and not isinstance(x, np.ndarray):
+        # a few picks out of a short last axis (a router's scores at its
+        # top-k): a compare and a sum fuse, a gather of scalars crawls on
+        # the TPU (4 ms for 6 of 128 over 65,536 rows); the same numbers
+        idx = jnp.where(idx < 0, idx + x.shape[-1], idx)
+        hit = idx[..., :, None] == jnp.arange(x.shape[-1], dtype=idx.dtype)
+        return jnp.sum(jnp.where(hit, x[..., None, :], 0), axis=-1,
+                       dtype=x.dtype)
     return jnp.take_along_axis(x, idx, axis=axis)
 
 
@@ -1011,7 +1034,7 @@ def _argminmax(inputs, attrs, ctx):
 @op("TopK")
 def _topk(inputs, attrs, ctx):
     x = inputs[0]
-    k = int(_static(inputs[1], "TopK.k")) if len(inputs) > 1 else int(attrs["k"])
+    k = _ints(inputs[1], "TopK.k")[0] if len(inputs) > 1 else int(attrs["k"])
     axis = attrs.get("axis", -1)
     largest = attrs.get("largest", 1)
     xm = jnp.moveaxis(x, axis, -1)
@@ -1194,3 +1217,178 @@ def _gru(inputs, attrs, ctx):
 
     h_t, ys = lax.scan(step, h0, gx)
     return ys[:, None], h_t[None]
+
+
+# ---------------------------------------------------------------------------------
+# attention and sparse experts
+# ---------------------------------------------------------------------------------
+
+def _kernels_on() -> bool:
+    """Whether the Pallas TPU kernels (flash attention, the grouped matrix
+    product) are what a program traced now will run on."""
+    return jax.default_backend() == "tpu"
+
+
+def _note(ctx, key: str, amount: int = 1) -> None:
+    """Add to what the executor says of the program it is tracing
+    (``OnnxFunction._run_positional`` turns the notes into metrics)."""
+    notes = ctx.get("notes")
+    if notes is not None:
+        notes[key] = notes.get(key, 0) + amount
+
+
+@op("Attention")
+def _attention(inputs, attrs, ctx):
+    """Opset 23 ``Attention`` as far as a stateless scorer needs it: Q, K, V
+    as ``[batch, seq, heads * size]`` (with ``q_num_heads`` /
+    ``kv_num_heads``) or ``[batch, heads, seq, size]``, grouped-query (the
+    key-value heads divide the query heads), ``is_causal``, ``scale``. No
+    mask, past, softcap, second output or ``softmax_precision``: they raise.
+
+    The scores are never written where ``parallel.flash.flash_attention``
+    runs its kernel (a TPU, sequence lengths that tile); elsewhere the dense
+    form runs and the program's ``attention_dense`` note counts it."""
+    from ..parallel import flash
+
+    q, k, v = inputs[:3]
+    given = [n for n, x in zip(("attn_mask", "past_key", "past_value"),
+                               inputs[3:]) if x is not None]
+    given += [n for n in ("qk_matmul_output_mode", "softcap",
+                          "softmax_precision") if attrs.get(n)]
+    if given or ctx["n_outputs"] > 1:
+        raise NotImplementedError(
+            f"Attention: unsupported {given or 'outputs beyond Y'}; this "
+            f"lowering takes Q, K, V, is_causal, scale, q_num_heads and "
+            f"kv_num_heads")
+    rank = q.ndim
+    if rank == 3:
+        n_q, n_kv = int(attrs["q_num_heads"]), int(attrs["kv_num_heads"])
+        q, k, v = (x.reshape(*x.shape[:2], n, -1)
+                   for x, n in ((q, n_q), (k, n_kv), (v, n_kv)))
+    else:  # [batch, heads, seq, size] -> the kernel's [batch, seq, heads, size]
+        q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
+    b, s_q, h, d = q.shape
+    s_k, h_kv = k.shape[1], k.shape[2]
+    if attrs.get("scale") is not None:  # the kernel's own scale is 1/sqrt(d)
+        q = q * jnp.asarray(attrs["scale"] * np.sqrt(d), q.dtype)
+    causal = bool(attrs.get("is_causal", 0))
+    if _kernels_on() and flash.auto_blocks_tile(b * h, s_q, s_k):
+        _note(ctx, "attention_flash")
+        out = flash.flash_attention(q, k, v, causal=causal)
+    else:
+        _note(ctx, "attention_dense")
+        if h != h_kv:
+            k, v = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
+        out = flash.dense_attention(q, k, v, causal=causal)
+    if rank == 3:
+        return out.reshape(b, s_q, h * d)
+    return jnp.transpose(out, (0, 2, 1, 3))
+
+
+# rows of (token, pick) pairs a grid step of the grouped kernel takes
+_GMM_ROWS = 512
+# sorted pairs ExpertFFN gathers and multiplies at a time
+_PAIR_CHUNK = 48 * _GMM_ROWS
+
+
+def _grouped_product(lhs, rhs, sizes):
+    """``lhs[rows of group g] @ rhs[g]`` for consecutive groups of ``sizes``
+    rows, float32 accumulation, in ``lhs``'s type. Rows past the last group
+    come back unspecified. On a TPU the megablox kernel, which visits only
+    the row tiles a group touches; elsewhere ``lax.ragged_dot``."""
+    rhs = rhs.astype(lhs.dtype)
+    if not _kernels_on():
+        return lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    pad = -m % _GMM_ROWS
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    # 7.3 MB of the 16 MB of scoped VMEM at the caps (two buffers an operand
+    # tile, the float32 accumulator); a rhs tile serves 512 rows, twice the
+    # v5e's FLOPs a byte
+    out = gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+              tiling=(_GMM_ROWS, _tile(k, 512), _tile(n, 1024)))
+    return out[:m] if pad else out
+
+
+def _tile(size: int, cap: int) -> int:
+    """The largest multiple of 128 up to ``cap`` that divides ``size``; else
+    ``cap`` (the kernel masks the last, partial tile) or a smaller ``size``."""
+    for tile in range(cap, 0, -128):
+        if size % tile == 0:
+            return tile
+    return min(cap, size)
+
+
+@op("ExpertFFN")
+def _expert_ffn(inputs, attrs, ctx):
+    """``synapseml_tpu::ExpertFFN(x, topk_index, topk_weight, U, D)``: the
+    part of a sparse-expert layer that THIS program's experts give.
+
+    ``U [held, h, f]`` and ``D [held, f, h]`` are experts ``first_expert ..
+    first_expert + held - 1`` of a router over ``num_experts``; each token
+    carries ``k`` picks (``topk_index [..., k]``) and their weights. The
+    result is ``sum over a token's picks of a held expert e of weight *
+    act(x U_e) D_e`` (``activation`` "relu2": ``relu(.)²``); a pick of an
+    expert held elsewhere adds nothing. No token is dropped and there is no
+    capacity: the (token, pick) pairs are sorted by held expert and go
+    through a grouped product, in chunks, as far as the held experts' pairs
+    reach."""
+    x, index, weight, up, down = inputs[:5]
+    if attrs.get("activation", "relu2") != "relu2":
+        raise NotImplementedError(
+            f"ExpertFFN: activation {attrs.get('activation')!r}; only relu2")
+    first, held = int(attrs["first_expert"]), up.shape[0]
+    if first < 0 or first + held > int(attrs["num_experts"]):
+        raise ValueError(
+            f"ExpertFFN: experts {first}..{first + held - 1} are not among "
+            f"the router's {attrs['num_experts']}")
+    h, k = x.shape[-1], index.shape[-1]
+    tokens = x.reshape(-1, h)
+    n_tokens = tokens.shape[0]
+    # pairs in pick-major order (pair p is pick p // n_tokens of token
+    # p % n_tokens): the sum over a token's picks is then over a leading
+    # axis, which no tiled layout has to be rearranged for
+    local = jnp.asarray(index).reshape(-1, k).T.reshape(-1).astype(
+        jnp.int32) - first
+    n_pairs = local.shape[0]
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held)  # absent experts' pairs sort last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(group[:, None] == jnp.arange(held, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    _note(ctx, "expert_pairs", n_pairs)
+    _note(ctx, "experts_held", held)
+
+    # the sorted pairs a chunk at a time, for as many chunks as hold a held
+    # expert's pair: the work follows the load (a quarter of the pairs where
+    # a quarter of the experts is held), and every pair has its place
+    n_chunks = -(-n_pairs // _PAIR_CHUNK)
+    order_padded = jnp.pad(order, (0, n_chunks * _PAIR_CHUNK - n_pairs))
+
+    def one_chunk(i, results):
+        lo = i * _PAIR_CHUNK
+        pairs = lax.dynamic_slice(order_padded, (lo,), (_PAIR_CHUNK,))
+        inside = (jnp.clip(ends, lo, lo + _PAIR_CHUNK)
+                  - jnp.clip(ends - sizes, lo, lo + _PAIR_CHUNK))
+        hidden = _grouped_product(tokens[pairs % n_tokens], up, inside)
+        out = _grouped_product(jnp.square(jax.nn.relu(hidden)), down, inside)
+        return lax.dynamic_update_slice(results, out, (lo, 0))
+
+    results = lax.fori_loop(
+        0, (ends[-1] + _PAIR_CHUNK - 1) // _PAIR_CHUNK, one_chunk,
+        jnp.zeros((n_chunks * _PAIR_CHUNK, h), x.dtype))
+    # back to pair order (the place of pair p among the sorted is where p
+    # sorts among the places); what the kernel left in the rows of a chunk
+    # past its last group never reaches the sum
+    out = jnp.where(here[:, None], results[jnp.argsort(order)], 0)
+    out = out.astype(jnp.float32) * weight.reshape(-1, k).T.reshape(
+        -1, 1).astype(jnp.float32)
+    return out.reshape(k, n_tokens, h).sum(axis=0).astype(x.dtype).reshape(
+        x.shape)

@@ -188,7 +188,7 @@ class TensorProto:
     name: str = ""
     dims: List[int] = dataclasses.field(default_factory=list)
     data_type: int = DataType.FLOAT
-    raw_data: bytes = b""
+    raw_data: "bytes | memoryview" = b""
     float_data: List[float] = dataclasses.field(default_factory=list)
     int32_data: List[int] = dataclasses.field(default_factory=list)
     int64_data: List[int] = dataclasses.field(default_factory=list)
@@ -321,7 +321,7 @@ def _parse_tensor(data: memoryview) -> TensorProto:
         elif field == 8:
             t.name = bytes(v).decode("utf-8")
         elif field == 9:
-            t.raw_data = bytes(v)
+            t.raw_data = v  # a view into the file's bytes, not a copy
         elif field == 10:
             if wt == 2:
                 t.double_data.extend(struct.unpack(f"<{len(v)//8}d", bytes(v)))
@@ -573,7 +573,9 @@ def numpy_to_tensor(name: str, arr: np.ndarray) -> TensorProto:
         name=name,
         dims=list(arr.shape),
         data_type=DataType.from_numpy(arr.dtype),
-        raw_data=arr.tobytes(),
+        # a view of the array's own bytes: a model's weights are copied
+        # once, by the writer, not here as well
+        raw_data=memoryview(arr.reshape(-1).view(np.uint8)),
     )
 
 
@@ -582,14 +584,24 @@ def numpy_to_tensor(name: str, arr: np.ndarray) -> TensorProto:
 # ---------------------------------------------------------------------------------
 
 def _ser_tensor(t: TensorProto) -> bytes:
-    out = bytearray()
+    return b"".join(_tensor_chunks(t))
+
+
+def _tensor_chunks(t: TensorProto) -> list:
+    """The message as the pieces a writer joins: ``raw_data`` stays the
+    tensor's own buffer, so a model file's weights are copied once."""
+    head = bytearray()
     for d in t.dims:
-        _put_varint_field(out, 1, d)
-    _put_varint_field(out, 2, t.data_type)
+        _put_varint_field(head, 1, d)
+    _put_varint_field(head, 2, t.data_type)
     if t.name:
-        _put_str(out, 8, t.name)
-    if t.raw_data:
-        _put_bytes(out, 9, t.raw_data)
+        _put_str(head, 8, t.name)
+    chunks = [head]
+    if len(t.raw_data):
+        _tag(head, 9, 2)
+        _write_varint(head, len(t.raw_data))
+        chunks.append(t.raw_data)
+    out = bytearray()
     if t.float_data:
         _put_bytes(out, 4, struct.pack(f"<{len(t.float_data)}f", *t.float_data))
     if t.int64_data:
@@ -604,7 +616,8 @@ def _ser_tensor(t: TensorProto) -> bytes:
         _put_bytes(out, 13, bytes(entry))
     if t.data_location:
         _put_varint_field(out, 14, t.data_location)
-    return bytes(out)
+    chunks.append(out)
+    return chunks
 
 
 def _ser_attribute(a: AttributeProto) -> bytes:
@@ -673,34 +686,52 @@ def _ser_value_info(vi: ValueInfo) -> bytes:
     return bytes(out)
 
 
+def _length_prefixed(field: int, chunks: list) -> list:
+    """``chunks`` as one length-delimited field, without joining them."""
+    head = bytearray()
+    _tag(head, field, 2)
+    _write_varint(head, sum(len(c) for c in chunks))
+    return [head, *chunks]
+
+
 def _ser_graph(g: GraphProto) -> bytes:
+    return b"".join(_graph_chunks(g))
+
+
+def _graph_chunks(g: GraphProto) -> list:
     out = bytearray()
     for n in g.node:
         _put_bytes(out, 1, _ser_node(n))
     if g.name:
         _put_str(out, 2, g.name)
+    chunks = [out]
     for t in g.initializer:
-        _put_bytes(out, 5, _ser_tensor(t))
+        chunks += _length_prefixed(5, _tensor_chunks(t))
+    out = bytearray()
     for vi in g.input:
         _put_bytes(out, 11, _ser_value_info(vi))
     for vi in g.output:
         _put_bytes(out, 12, _ser_value_info(vi))
     for vi in g.value_info:
         _put_bytes(out, 13, _ser_value_info(vi))
-    return bytes(out)
+    chunks.append(out)
+    return chunks
 
 
 def serialize_model(m: ModelProto) -> bytes:
-    out = bytearray()
-    _put_varint_field(out, 1, m.ir_version)
+    """The model file's bytes. Tensors' data are joined into it once, from
+    their own buffers (a 3.4 GB model is one 3.4 GB copy)."""
+    head = bytearray()
+    _put_varint_field(head, 1, m.ir_version)
     if m.producer_name:
-        _put_str(out, 2, m.producer_name)
-    _put_bytes(out, 7, _ser_graph(m.graph))
+        _put_str(head, 2, m.producer_name)
+    tail = bytearray()
     opsets = m.opset_imports or {"": 13}
     for domain, version in opsets.items():
         op = bytearray()
         if domain:
             _put_str(op, 1, domain)
         _put_varint_field(op, 2, version)
-        _put_bytes(out, 8, bytes(op))
-    return bytes(out)
+        _put_bytes(tail, 8, bytes(op))
+    return b"".join([head, *_length_prefixed(7, _graph_chunks(m.graph)),
+                     tail])

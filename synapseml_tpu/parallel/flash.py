@@ -100,6 +100,14 @@ def _pick_blocks(bh: int, s_q: int, s_k: int):
     return (_pow2_divisor(s_q, bq_target), _pow2_divisor(s_k, 1024))
 
 
+def auto_blocks_tile(bh: int, s_q: int, s_k: int) -> bool:
+    """Whether the blocks :func:`flash_attention` picks by itself meet
+    Mosaic's (8, 128) tile: it then runs the kernel, else its dense
+    substitute (a caller that has to know which asks here first)."""
+    block_q, block_k = _pick_blocks(bh, s_q, s_k)
+    return min(block_q, s_q) >= 8 and min(block_k, s_k) >= 128
+
+
 def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
                     block_k: int = None, interpret: bool = False):
     """Blockwise-online-softmax attention as ONE Pallas kernel.
