@@ -569,8 +569,8 @@ class OnnxFunction:
         return tuple(outs)
 
     def _record_notes(self, notes: Dict[str, int]) -> None:
-        """Once a traced program: how its ``Attention`` nodes were lowered,
-        and what its ``ExpertFFN`` nodes are sized for."""
+        """Once a traced program: how its ``Attention`` and ``Gelu`` nodes
+        were lowered, and what its ``ExpertFFN`` nodes are sized for."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
@@ -583,6 +583,15 @@ class OnnxFunction:
         for kind in ("flash", "dense"):
             if "attention_" + kind in notes:
                 lowering.labels(fn, kind).inc(notes["attention_" + kind])
+        gelu = reg.counter(
+            "smt_onnx_gelu_lowering_total",
+            "exact Gelu nodes of a traced program by form: erf_float32 (a "
+            "bfloat16 input: one-branch erf on the float32 upcast, rounded "
+            "once) or erfc (any wider input: jax.nn.gelu's two-branch form)",
+            ("fn", "form"))
+        for form in ("erf_float32", "erfc"):
+            if "gelu_" + form in notes:
+                gelu.labels(fn, form).inc(notes["gelu_" + form])
         if "expert_pairs" in notes:
             reg.gauge(
                 "smt_onnx_expert_pairs",

@@ -200,18 +200,14 @@ def _mish(inputs, attrs, ctx):
     return x * jnp.tanh(jax.nn.softplus(x))
 
 
-@op("Gelu")
-def _gelu(inputs, attrs, ctx):
-    approx = attrs.get("approximate", "none") == "tanh"
-    return jax.nn.gelu(inputs[0], approximate=approx)
-
-
 def _float32_inside(fn):
     """A bfloat16 first input is computed on as its float32 upcast and the
     result rounded once to bfloat16: reductions (softmax's sum, a norm's mean
     and variance) keep float32 inside the op while the tensor handed on stays
-    narrow. On the TPU the upcast fuses into the op; any other dtype passes
-    through untouched."""
+    narrow. GELU has no reduction and is here for its rounding steps: in
+    bfloat16 every product of its formula rounds to eight bits, in float32
+    only the result does. On the TPU the upcast fuses into the op; any other
+    dtype passes through untouched."""
 
     @functools.wraps(fn)
     def wrapped(inputs, attrs, ctx):
@@ -222,6 +218,45 @@ def _float32_inside(fn):
         return out.astype(jnp.bfloat16)
 
     return wrapped
+
+
+# below this the exact GELU is under 1.1e-7 in size, and 1 + erf is what
+# float32 leaves of a cancellation: zero is the closer answer
+_GELU_ZERO_BELOW = -5.5
+
+
+@_float32_inside
+def _gelu_erf_float32(inputs, attrs, ctx):
+    x = inputs[0]
+    y = 0.5 * x * (1.0 + lax.erf(x * np.float32(np.sqrt(0.5))))
+    return jnp.where(x < _GELU_ZERO_BELOW, 0.0, y)
+
+
+@op("Gelu")
+def _gelu(inputs, attrs, ctx):
+    """Opset 20 ``Gelu``. ``approximate="tanh"`` and every input type but
+    bfloat16 are ``jax.nn.gelu``, whose exact form is ``0.5 x erfc(-x/sqrt 2)``
+    with BOTH of ``erfc``'s branches computed for every element: some 67
+    vector operations, which buy relative accuracy in the far negative tail
+    (3.8e-7 absolute on [-10, 10] in float32 against the form below's 1.1e-6),
+    and float32, float64 and float16 outputs can hold that. A bfloat16 output
+    cannot, so a bfloat16 input takes the one-branch ``0.5 x (1 + erf(x/sqrt
+    2))`` on its float32 upcast, rounded once (``_float32_inside``): half the
+    vector work in a feed-forward fusion's epilogue, and the correctly rounded
+    bfloat16 of the exact GELU at all but 27 of the 65,280 finite inputs (the
+    bfloat16 ``erfc`` form: all but 771). The form's hazard is the tail, where
+    ``1 + erf`` is a float32 residue (6e-8 at -5.7) that a large ``|x|``
+    multiplies up: below ``_GELU_ZERO_BELOW`` = -5.5, where ``|gelu|`` <
+    1.1e-7, the answer is zero. The trace notes which form a node took
+    (``smt_onnx_gelu_lowering_total{form}``)."""
+    x = inputs[0]
+    if attrs.get("approximate", "none") == "tanh":
+        return jax.nn.gelu(x, approximate=True)
+    if getattr(x, "dtype", None) == jnp.bfloat16:
+        _note(ctx, "gelu_erf_float32")
+        return _gelu_erf_float32(inputs, attrs, ctx)
+    _note(ctx, "gelu_erfc")
+    return jax.nn.gelu(x, approximate=False)
 
 
 @op("Softmax")

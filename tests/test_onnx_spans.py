@@ -172,6 +172,27 @@ def test_lowered_program_names_ops_by_onnx_node(model_bytes):
     assert "/Gather." in text and "/LayerNormalization." in text
 
 
+@pytest.mark.parametrize("policy,form", [
+    ("bfloat16", "erf_float32"), ("float32", "erfc")])
+def test_the_trace_says_which_form_each_gelu_node_took(
+        model_bytes, fresh_registry, policy, form):
+    """BERTTiny's two layers hold one ``Gelu`` each: counted once a traced
+    program by the form the input's type chose and by no other, nothing more
+    on a second call of the same program."""
+    fn = OnnxFunction(model_bytes, dtype_policy=policy)
+    ids = np.zeros((3, 24), np.int64)  # a shape no other test compiled
+
+    def counted():
+        family = fresh_registry.snapshot()["families"].get(
+            "smt_onnx_gelu_lowering_total", {"series": []})
+        return {tuple(s["labels"]): s["value"] for s in family["series"]}
+
+    fn({"input_ids": ids})
+    assert counted() == {(fn._jit.name, form): 2}
+    fn({"input_ids": ids})
+    assert counted() == {(fn._jit.name, form): 2}
+
+
 def test_phase_spans_leave_device_memory_alone(model, table, fresh_registry,
                                                monkeypatch):
     """On a backend with allocator statistics (here: faked) only the stage
