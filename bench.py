@@ -1042,74 +1042,6 @@ def bench_swap_under_load(platform):
     }
 
 
-def bench_worker_warm_start(platform):
-    """Persisted-AOT warm start: time-to-first-served-reply for a FRESH
-    worker process, cold (empty cache — the first reply pays the XLA
-    compile) vs warm (the fleet's shared on-disk cache was pre-warmed
-    before the worker registered).
-
-    Primary: ``warm_start_speedup`` = cold first-reply / warm first-reply
-    (the warm denominator floored at 25 ms so sub-millisecond jitter in
-    an already-instant reply cannot whip the ratchet ratio around).
-    The warm figure is the median over 3 fresh workers.
-
-    Every worker jits, so on an accelerator every worker needs the chip,
-    and a chip belongs to one process at a time: the workers run one after
-    another (a one-worker fleet each, sharing one cache directory), and
-    ``main`` runs this lane before its own process initialises jax."""
-    import os
-    import shutil
-    import tempfile
-    import urllib.request
-
-    from synapseml_tpu.io.serving_v2 import ProcessServingFleet
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from tests.serving_fault_stage import JitBurnReply
-
-    def first_reply(body):
-        """Seconds to the first served reply of a fresh one-worker fleet,
-        and that worker's AOT-cache hit count."""
-        fleet = ProcessServingFleet(
-            JitBurnReply(), n_workers=1, aot_cache_dir=cache_dir,
-            import_modules=["tests.serving_fault_stage"],
-            reply_timeout=60.0, startup_timeout=180.0)
-        try:
-            t0 = time.perf_counter()
-            with urllib.request.urlopen(fleet.addresses[0] + "/", data=body,
-                                        timeout=120) as r:
-                assert r.status == 200
-            dt = time.perf_counter() - t0
-            snap = fleet.metrics_snapshot()
-            return dt, sum(
-                s["value"] for s in (snap["families"].get(
-                    "smt_aot_cache_hits_total") or {}).get("series", []))
-        finally:
-            fleet.stop()
-
-    cache_dir = tempfile.mkdtemp(prefix="bench_aot_")
-    try:
-        # the first worker's FIRST reply pays the cold compile (and
-        # persists it); each later worker finds it in the shared cache
-        cold_s, _ = first_reply(b"cold")
-        warm, hits = [], 0
-        for _ in range(3):
-            dt, h = first_reply(b"warm")
-            warm.append(dt)
-            hits += h
-    finally:
-        shutil.rmtree(cache_dir, ignore_errors=True)
-    warm_s = float(np.median(warm))
-    return {
-        "cold_first_reply_s": round(cold_s, 3),
-        "warm_first_reply_s": round(warm_s, 4),
-        "warm_samples": [round(w, 4) for w in warm],
-        "aot_cache_hits": hits,
-        "warm_start_time_saved_s": round(cold_s - warm_s, 3),
-        "warm_start_speedup": round(cold_s / max(warm_s, 0.025), 2),
-    }
-
-
 def bench_hyperparam_search(platform):
     """ASHA + shared binning vs the legacy random thread pool on
     breast-cancer: same sampled configs, same validation split.
@@ -1724,7 +1656,6 @@ _PRIMARY = {
     "serving_overload": "p99_collapse_ratio",
     "multi_tenant_serving": "uncontended_throughput_ratio",
     "swap_under_load": "swap_p99_ratio",
-    "worker_warm_start": "warm_start_speedup",
     "hyperparam_search": "search_speedup",
 }
 
@@ -1813,22 +1744,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     allow_cpu = args.allow_cpu or bool(os.environ.get("BENCH_ALLOW_CPU"))
 
-    # worker_warm_start's workers each open the device, and a chip belongs
-    # to one process at a time: the lane runs while this process has not
-    # initialised jax. The platform is read in a child that exits (and lets
-    # go of the chip) before anything else starts.
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "tools"))
-    from check_device import probe
-
-    probed = probe(timeout=300.0)["platform"]
-    warm_start = None
-    if allow_cpu or probed != "cpu":
-        try:
-            warm_start = bench_worker_warm_start(probed)
-        except Exception as e:
-            warm_start = {"error": f"{type(e).__name__}: {e}"[:300]}
-
     import jax
 
     from synapseml_tpu.runtime.topology import require_backend
@@ -1868,7 +1783,6 @@ def main(argv=None) -> int:
         ("multi_tenant_serving",
          lambda: bench_multi_tenant_serving(platform)),
         ("swap_under_load", lambda: bench_swap_under_load(platform)),
-        ("worker_warm_start", lambda: warm_start),  # ran above
         ("hyperparam_search", lambda: bench_hyperparam_search(platform)),
         ("observability_span_overhead", lambda: bench_span_overhead(platform)),
         ("tracing_overhead", lambda: bench_tracing_overhead(platform)),

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import logging
 import os
 import shutil
 import signal
@@ -125,6 +126,20 @@ class SmokeDecodeImages(Transformer):
         return table.with_column("data", data)
 
 
+# names of the profiled jits that left the profiled path in this process:
+# ``ProfiledJit`` says so once, as a warning on the package's logger
+LEFT_PROFILED_PATH = set()
+
+
+def _note_who_left(record: logging.LogRecord) -> bool:
+    if "left the profiled path" in str(record.msg):
+        LEFT_PROFILED_PATH.add(record.args[0])
+    return True  # a filter that lets every record through
+
+
+logging.getLogger("synapseml_tpu").addFilter(_note_who_left)
+
+
 class SmokeLogitsReply(Transformer):
     """``logits`` -> one JSON reply per request, stamped with the device
     THIS process computes on and whether its profiled jits are still on the
@@ -133,13 +148,10 @@ class SmokeLogitsReply(Transformer):
     def _transform(self, table: Table) -> Table:
         import jax
 
-        from synapseml_tpu.observability import profiling
-
         dev = jax.devices()[0]
         stamp = {"platform": dev.platform, "device_kind": dev.device_kind,
                  "device_count": len(jax.devices()), "pid": os.getpid(),
-                 "left_profiled_path": sorted(
-                     j.name for j in profiling._INSTANCES if j._aot_broken)}
+                 "left_profiled_path": sorted(LEFT_PROFILED_PATH)}
         replies = np.empty(table.num_rows, dtype=object)
         replies[:] = [dict(stamp, logits=row.tolist())
                       for row in np.asarray(table["logits"])]
@@ -186,13 +198,11 @@ def _compile_account(families: dict, prefix: str) -> dict:
 def _profiled(prefix: str) -> dict:
     """This process's compile accounting for ``prefix``, and which of those
     wrappers left the profiled path (must be none)."""
-    from synapseml_tpu.observability import profiling
     from synapseml_tpu.observability.metrics import get_registry
 
     out = _compile_account(get_registry().snapshot()["families"], prefix)
     out["left_profiled_path"] = sorted(
-        j.name for j in profiling._INSTANCES
-        if j.name.startswith(prefix) and j._aot_broken)
+        name for name in LEFT_PROFILED_PATH if name.startswith(prefix))
     return out
 
 
@@ -403,7 +413,7 @@ def _onnx_phase(phase: str, size: dict, model_name: str, model_kw: dict,
                err < size["ref_tol"], round(err, 5))
         _check(checks, "one_compile_for_every_bucket",
                compiles[0]["profiled"]["compiles"] == 1
-               and not model.fn._jit._aot_broken,
+               and not model.fn._jit._left_profiled_path,
                compiles[0]["profiled"])
         if served:
             path = os.path.join(workdir, "serving_logits.npy")

@@ -1495,8 +1495,7 @@ def prewarm_pipeline(server: ServingServer, pipeline,
     """Run ``pipeline`` once on a replay of the server's most recent real
     request — the off-request-path compile a hot swap pays BEFORE the
     flip, so the first post-swap batch is warm. False when no request has
-    been seen yet (nothing to replay; the persisted AOT cache still
-    covers previously-seen jit signatures). With ``model``, the replay
+    been seen yet (nothing to replay). With ``model``, the replay
     sample is that tenant's OWN last request — another tenant's payload
     shape would compile the wrong signature."""
     req = server.last_request_by_model.get(model) if model is not None \

@@ -257,10 +257,11 @@ class MultiTenantServingEngine:
     door), each tenant dispatcher drains only its own queue, and each
     model sits behind its OWN generation-tagged lifecycle slot — swapping
     one never touches the others. Residency is an LRU
-    (:class:`ResidencySet`) over the persisted-AOT cache: admitting model
-    N+1 beyond ``capacity`` evicts the least-recently-served tenant,
-    whose next request faults it back in from its saved stage (warm
-    start). ``/control/load`` and ``/control/unload`` drive explicit
+    (:class:`ResidencySet`): admitting model N+1 beyond ``capacity``
+    evicts the least-recently-served tenant, whose next request faults it
+    back in from its saved stage (its programs compile again, through
+    jax's persistent cache where the deployment has one).
+    ``/control/load`` and ``/control/unload`` drive explicit
     admission/eviction."""
 
     def __init__(self, server: ServingServer,
@@ -346,7 +347,7 @@ class MultiTenantServingEngine:
         """ResidencySet eviction callback: stop the tenant dispatcher
         (without closing the shared server) and detach its lifecycle
         slot. The catalog entry SURVIVES eviction — the model's next
-        request faults it back in through the AOT cache."""
+        request faults it back in from its saved stage."""
         if eng is not None:
             eng.stop(close_server=False)
         self.server.lifecycles.pop(model, None)
@@ -1464,7 +1465,6 @@ class ProcessServingFleet:
                  trace_knobs: Optional[Dict[str, float]] = None,
                  resilience: Optional[ResilienceConfig] = None,
                  fault_plan=None,
-                 aot_cache_dir: Optional[str] = None,
                  lifecycle: Optional[LifecycleConfig] = None,
                  models: Optional[Dict[str, Transformer]] = None,
                  isolate_workers: int = 1):
@@ -1526,18 +1526,6 @@ class ProcessServingFleet:
             env[faultinject.ENV_VAR] = (
                 fault_plan if isinstance(fault_plan, str)
                 else _json.dumps(fault_plan))
-        # persisted-AOT warm start: every worker shares one on-disk
-        # executable cache ("auto" = under the fleet tempdir), and fresh
-        # workers (scale-up / restart) pre-warm from it BEFORE announcing
-        # their address — previously-seen jit signatures serve their first
-        # request without a cold XLA compile
-        self.aot_cache_dir = None
-        if aot_cache_dir is not None:
-            self.aot_cache_dir = (os.path.join(self._tmp, "aot")
-                                  if aot_cache_dir == "auto"
-                                  else aot_cache_dir)
-            os.makedirs(self.aot_cache_dir, exist_ok=True)
-            env["SMT_AOT_CACHE_DIR"] = self.aot_cache_dir
         self._env = env
         flags = ["--host", host, "--mode", mode,
                  "--reply-timeout", str(reply_timeout)]
@@ -1552,8 +1540,6 @@ class ProcessServingFleet:
                                  lambda v: str(int(v)))):
             if trace_knobs and trace_knobs.get(key) is not None:
                 flags += [flag, conv(trace_knobs[key])]
-        if self.aot_cache_dir is not None:
-            flags += ["--prewarm-aot"]
         self._cmd_flags = flags
         import time as _time
 
@@ -1910,10 +1896,9 @@ class ProcessServingFleet:
 
     def add_worker(self) -> Optional[str]:
         """Scale UP: spawn one more worker serving the CURRENT generation.
-        With a shared AOT cache dir the worker pre-warms every persisted
-        signature BEFORE announcing its address (= before registration),
-        so its first routed request is warm-start bounded. Returns the new
-        address (None on startup failure)."""
+        Its first request of each shape compiles, through jax's persistent
+        cache where the deployment has one. Returns the new address (None
+        on startup failure)."""
         import time as _time
 
         with self._ops_lock:

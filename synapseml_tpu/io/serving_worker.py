@@ -57,12 +57,6 @@ def main(argv=None) -> int:
                     help="initial pipeline generation (a fleet spawning a "
                          "worker after N rolling swaps passes N so "
                          "/healthz reports the truth)")
-    ap.add_argument("--prewarm-aot", action="store_true",
-                    help="deserialize every persisted AOT executable "
-                         "(SMT_AOT_CACHE_DIR) for the loaded pipeline's "
-                         "jit entry points BEFORE announcing the address "
-                         "— previously-seen signatures then serve their "
-                         "first request without a cold XLA compile")
     args = ap.parse_args(argv)
     if (args.stage_path is None) == (args.models_json is None):
         ap.error("exactly one of stage_path or --models-json is required")
@@ -92,7 +86,6 @@ def main(argv=None) -> int:
                                  else None)))
 
     import json as _json
-    import time as _time
 
     from ..runtime.topology import open_requested_platform
 
@@ -105,23 +98,12 @@ def main(argv=None) -> int:
         print(f"DEVICE {device.platform} {list(device.device_kinds)} "
               f"x{device.num_devices}", file=sys.stderr, flush=True)
 
-    t_load0 = _time.perf_counter()
     spec = _json.loads(args.models_json) if args.models_json else None
     if spec is not None:
         models = {m: load_stage(e["stage_path"])
                   for m, e in sorted(spec.items())}
     else:
         pipeline = load_stage(args.stage_path)
-    prewarmed = {}
-    if args.prewarm_aot:
-        # warm start BEFORE the address announcement (= before the fleet
-        # registers this worker): every persisted executable the fleet has
-        # ever compiled for these entry points deserializes now, off the
-        # serving path entirely
-        from ..observability.profiling import prewarm_aot_cache
-
-        prewarmed = prewarm_aot_cache()
-    ready_s = _time.perf_counter() - t_load0
     server = ServingServer(args.host, args.port,
                            reply_timeout=args.reply_timeout)
     if spec is not None:
@@ -143,13 +125,6 @@ def main(argv=None) -> int:
             generation=args.generation).start()
 
     print(f"ADDRESS {server.address}", flush=True)
-    # AFTER the address announcement: the parent's handshake select()s on
-    # an unbuffered view of stdout, so ADDRESS must be the first line;
-    # benches read this one to attribute load-vs-prewarm time without a
-    # second channel
-    print("PREWARM " + _json.dumps(
-        {"loaded": sum(prewarmed.values()), "fns": prewarmed,
-         "ready_s": round(ready_s, 4)}), flush=True)
     try:
         threading.Event().wait()  # serve until killed
     except KeyboardInterrupt:

@@ -4,21 +4,21 @@ The reference's Spark Serving turns ONE pipeline into a web service; a
 production TPU fleet serves a zoo. Every hard single-tenant part already
 exists — generation-tagged hot swap (``io/lifecycle.py``), burn-rate SLOs
 (``observability/slo.py``), per-request FLOPs/HBM cost attribution, the
-breaker/hedge/deadline control plane (``io/resilience.py``), persisted-AOT
-warm start — and this module composes them into a tenancy subsystem
-instead of N parallel fleets:
+breaker/hedge/deadline control plane (``io/resilience.py``) — and this
+module composes them into a tenancy subsystem instead of N parallel fleets:
 
 - :class:`ModelCatalog` — the BOUNDED registry of model ids: model id ->
   saved-stage path + generation + resource class (derived from the cost
   EWMAs the serving engines report per batch). Every ``model`` metric /
   span label in the system comes from this catalog, never from request
   data — the bounded-cardinality contract lint SMT014 enforces.
-- :class:`ResidencySet` — the per-worker LRU of resident pipelines over
-  the existing persisted-AOT cache: a worker holds up to ``capacity``
-  models hot, each behind its OWN generation-tagged
-  :class:`~synapseml_tpu.io.lifecycle.WorkerLifecycle` slot, so swapping
-  one model never touches the others; an evicted model's next request
-  faults it back in through the AOT cache (warm start, not cold compile).
+- :class:`ResidencySet` — the per-worker LRU of resident pipelines: a
+  worker holds up to ``capacity`` models hot, each behind its OWN
+  generation-tagged :class:`~synapseml_tpu.io.lifecycle.WorkerLifecycle`
+  slot, so swapping one model never touches the others; an evicted
+  model's next request faults it back in from its saved stage (its
+  programs compile again, through jax's persistent cache where the
+  deployment has one).
 - :func:`plan_placement` + :class:`PlacementBoard` — cost-driven
   placement: per-model FLOPs/HBM EWMAs classify tenants into resource
   classes; expensive models get isolated workers, cheap chatty ones are
@@ -230,15 +230,16 @@ class ModelCatalog:
 
 
 class ResidencySet:
-    """Per-worker LRU of resident model slots over the persisted-AOT cache.
+    """Per-worker LRU of resident model slots.
 
     A worker holds up to ``capacity`` pipelines hot; each slot is
     generation-tagged by its own :class:`WorkerLifecycle`, so a swap of
     model A flips A's slot and no other. Admitting model N+1 evicts the
     least-recently-USED resident (touch = a processed batch, not an
     enqueue), and the evicted model's next request faults it back in: the
-    reload goes through the shared on-disk AOT cache, so eviction costs a
-    deserialize, not a cold XLA compile. ``capacity=None`` = unbounded
+    reload compiles its programs again, so eviction costs what jax's
+    persistent cache makes of that (a load where the deployment has one
+    and the entry is there, a compile otherwise). ``capacity=None`` = unbounded
     (every cataloged model stays resident — the common small-zoo case).
 
     The slot values are opaque to this class (the serving layer stores

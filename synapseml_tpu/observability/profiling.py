@@ -51,13 +51,9 @@ the fleet-stitched timeline).
 from __future__ import annotations
 
 import functools
-import hashlib
 import os
-import pickle
-import re
 import sys
 import threading
-import weakref
 from time import perf_counter as _perf_counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -67,7 +63,6 @@ from .metrics import MetricsRegistry, get_registry
 __all__ = [
     "PEAK_BF16_FLOPS",
     "ProfiledJit",
-    "aot_cache_dir",
     "chrome_trace_events",
     "cost_snapshot",
     "disable",
@@ -76,10 +71,8 @@ __all__ = [
     "is_enabled",
     "memory_stats",
     "peak_flops",
-    "prewarm_aot_cache",
     "profiled_jit",
     "render_chrome_trace",
-    "set_aot_cache_dir",
     "update_memory_gauges",
 ]
 
@@ -450,76 +443,6 @@ class _CompiledEntry:
         self.bytes = bytes_
 
 
-# ---------------------------------------------------------------------------
-# persisted AOT cache: compiled executables survive the process
-# ---------------------------------------------------------------------------
-#
-# A worker spawned by the autoscaler (or restart_worker) pays the full cold
-# XLA compile before its first reply — exactly when the fleet is under the
-# load that triggered the scale-up. The fix: every compilation a ProfiledJit
-# pays is serialized (jax's AOT executable serialization,
-# ``jax.experimental.serialize_executable`` — the path that actually skips
-# XLA on reload; a ``jax.export`` round trip only skips tracing and still
-# recompiles the StableHLO) into a content-addressed on-disk cache shared by
-# the fleet. The key digests the full abstract signature PLUS jax/jaxlib
-# versions, backend and device kind: a version or hardware mismatch is
-# simply a cache miss (silent recompile), never a wrong executable. A
-# corrupt or undeserializable entry is QUARANTINED (renamed aside, counted)
-# and recompiled — the cache can make a worker faster, never dead.
-
-AOT_CACHE_ENV = "SMT_AOT_CACHE_DIR"
-_AOT_MAGIC = "smt-aot-1"
-_aot_dir_override: Optional[str] = None
-# every live ProfiledJit, so prewarm_aot_cache() can warm them by name
-_INSTANCES: "weakref.WeakSet[ProfiledJit]" = weakref.WeakSet()
-
-
-def set_aot_cache_dir(path: Optional[str]) -> None:
-    """Process-wide override of the persisted-AOT cache directory (None
-    restores the ``SMT_AOT_CACHE_DIR`` environment lookup)."""
-    global _aot_dir_override
-    _aot_dir_override = path
-
-
-def aot_cache_dir() -> Optional[str]:
-    """The persisted-AOT cache directory, or None (cache off)."""
-    if _aot_dir_override is not None:
-        return _aot_dir_override
-    return os.environ.get(AOT_CACHE_ENV) or None
-
-
-def _aot_series(kind: str, fn_name: str):
-    """hits/misses/quarantined counter series, cached per registry."""
-    reg = get_registry()
-    cache = _series_cache(reg)
-    key = ("aot", kind, fn_name)
-    got = cache.get(key)
-    if got is None:
-        helps = {
-            "hits": "compilations avoided by the persisted AOT cache",
-            "misses": "compilations persisted into the AOT cache",
-            "quarantined": "corrupt/undeserializable AOT entries set aside",
-        }
-        got = cache[key] = reg.counter(
-            f"smt_aot_cache_{kind}_total", helps[kind],
-            ("fn",)).labels(fn_name)
-    return got
-
-
-def prewarm_aot_cache() -> Dict[str, int]:
-    """Eagerly deserialize every persisted executable for every live
-    :class:`ProfiledJit` (``{fn_name: n_loaded}``). A fresh worker calls
-    this BEFORE registering with the fleet, so previously-seen signatures
-    serve their first request in milliseconds instead of a cold compile.
-    No cache dir (or nothing persisted) is a graceful no-op."""
-    out: Dict[str, int] = {}
-    for inst in list(_INSTANCES):
-        n = inst.warm_start()
-        if n:
-            out[inst.name] = out.get(inst.name, 0) + n
-    return out
-
-
 _BOUND = "__bound_arguments__"  # marks ``ProfiledJit.bind``'s signature entry
 
 
@@ -541,28 +464,18 @@ class ProfiledJit:
     """
 
     def __init__(self, fn, name: Optional[str] = None,
-                 static_argnames: Sequence[str] = (),
-                 closure_key: Optional[str] = None):
+                 static_argnames: Sequence[str] = ()):
         self._fn = fn
         self.name = name or getattr(fn, "__name__", "fn")
         self._static_argnames = tuple(static_argnames)
-        # fingerprint of closure state the input signature cannot see —
-        # e.g. the ONNX executor's weight placement plan (a (2,2,2)
-        # fsdp-stored executable must never be served from a replicated
-        # instance's persisted entry of the same fn name and input avals)
-        self._closure_key = closure_key or ""
         self._lock = threading.Lock()
         self._cache: Dict[Any, _CompiledEntry] = {}
-        # digest -> entry deserialized from the persisted AOT cache
-        # (warm_start eagerly, or lazily on first call of a signature)
-        self._preloaded: Dict[str, _CompiledEntry] = {}
         self._last_sig = None
         self._plain = None
-        self._aot_broken = False
-        # (avals, placements) of arguments bound by ``bind`` <-> the small
+        self._left_profiled_path = False
+        # (avals, placements) of arguments bound by ``bind`` -> the small
         # integer that stands for them in a signature
-        self._bound_keys: List[Any] = []
-        _INSTANCES.add(self)
+        self._bound_tokens: Dict[Any, int] = {}
 
     def _plain_jit(self):
         if self._plain is None:
@@ -596,9 +509,8 @@ class ProfiledJit:
         key = (tuple(shaped_abstractify(x) for x in arrays),
                tuple(getattr(x, "sharding", None) for x in arrays))
         with self._lock:
-            if key not in self._bound_keys:
-                self._bound_keys.append(key)
-            token = self._bound_keys.index(key)
+            token = self._bound_tokens.setdefault(
+                key, len(self._bound_tokens))
         return functools.partial(self._call, (_BOUND, token), arrays)
 
     def __call__(self, *args, **kwargs):
@@ -607,7 +519,7 @@ class ProfiledJit:
     def _call(self, bound_key, bound, *args, **kwargs):
         import jax
 
-        if not _enabled or self._aot_broken:
+        if not _enabled or self._left_profiled_path:
             return self._plain_jit()(*args, *bound, **kwargs)
         dyn_kwargs, static = self._split(kwargs)
         if bound_key is not None:
@@ -676,7 +588,7 @@ class ProfiledJit:
         figure for ``name`` is missing from then on."""
         import logging
 
-        self._aot_broken = True
+        self._left_profiled_path = True
         logging.getLogger("synapseml_tpu").warning(
             "profiled jit %r left the profiled path (%s); compile and cost "
             "accounting for it stop here", self.name, why)
@@ -691,19 +603,6 @@ class ProfiledJit:
         # dropped, so compiles are still recorded exactly once.
         import jax
 
-        digest = None
-        if aot_cache_dir() is not None:
-            digest = self._digest(sig)
-            entry = self._load_persisted(digest)
-            if entry is not None:
-                with self._lock:
-                    existing = self._cache.get(sig)
-                    if existing is not None:
-                        return existing
-                    self._cache[sig] = entry
-                self._last_sig = sig
-                _aot_series("hits", self.name).inc()
-                return entry
         t0 = _perf_counter()
         try:
             lowered = jax.jit(
@@ -730,152 +629,7 @@ class ProfiledJit:
         cause = _classify_recompile(self._last_sig, sig)
         self._last_sig = sig
         self._record_compile(dt, cause, flops)
-        if digest is not None:
-            self._persist(digest, compiled, flops, bytes_)
-            _aot_series("misses", self.name).inc()
         return entry
-
-    # -- persisted AOT cache ------------------------------------------------
-    def _safe_name(self) -> str:
-        return re.sub(r"[^A-Za-z0-9_.-]", "_", self.name)
-
-    def _digest(self, sig) -> str:
-        """Content address of one (fn, signature, toolchain, device)
-        combination. jax/jaxlib versions, backend and device kind join the
-        key because serialized executables are exactly that fragile — a
-        mismatch must read as a miss (silent recompile), never a load."""
-        treedef, avals, placements, static = sig
-        # a bound-arguments token is this process's; what it stands for is not
-        static = tuple(self._bound_keys[e[1]] if e[0] is _BOUND else e
-                       for e in static)
-        parts = [
-            _AOT_MAGIC, self.name, str(treedef),
-            "|".join(repr(a) for a in avals),
-            "|".join(repr(p) for p in placements),
-            repr(static), self._closure_key,
-        ] + self._runtime_key()
-        return hashlib.sha256("\x1f".join(parts).encode()).hexdigest()[:32]
-
-    def _cache_path(self, digest: str) -> str:
-        return os.path.join(aot_cache_dir(),
-                            f"{self._safe_name()}-{digest}.aot")
-
-    def _quarantine(self, path: str) -> None:
-        """Set a damaged entry aside (never delete evidence, never crash):
-        the recompile it forces re-persists a good entry under the same
-        digest."""
-        try:
-            os.replace(path, path + ".quarantined")
-        except OSError:
-            pass
-        _aot_series("quarantined", self.name).inc()
-
-    def _runtime_key(self) -> List[str]:
-        """What a serialized executable is compatible with: the same
-        toolchain on the same hardware."""
-        import jax
-        import jaxlib
-
-        st = _DEV.probe()
-        kind = ""
-        if st.devices:
-            kind = getattr(st.devices[0], "device_kind", "")
-        jx = _jax_if_loaded()
-        backend = jx.default_backend() if jx is not None else "?"
-        return [jax.__version__, jaxlib.__version__, backend, kind]
-
-    def _deserialize_file(self, path: str) -> Optional[_CompiledEntry]:
-        """One persisted entry -> a live executable. A RUNTIME mismatch
-        (another jax/jaxlib version or device kind sharing the cache dir —
-        its digests differ, so bulk warm_start is the only caller that
-        sees them) is a silent skip: the entry is perfectly valid for the
-        worker that wrote it. Quarantine is reserved for entries that are
-        actually damaged (unreadable pickle, bad header, a deserialize
-        failure on a MATCHING runtime)."""
-        from jax.experimental.serialize_executable import \
-            deserialize_and_load
-
-        try:
-            with open(path, "rb") as f:
-                blob = pickle.loads(f.read())
-            if (not isinstance(blob, dict)
-                    or blob.get("magic") != _AOT_MAGIC):
-                raise ValueError("bad AOT cache entry header")
-        except Exception:
-            self._quarantine(path)
-            return None
-        if list(blob.get("runtime") or []) != self._runtime_key():
-            return None  # someone else's valid entry: leave it alone
-        try:
-            loaded = deserialize_and_load(blob["payload"], blob["in_tree"],
-                                          blob["out_tree"])
-            return _CompiledEntry(loaded, float(blob.get("flops", 0.0)),
-                                  float(blob.get("bytes", 0.0)))
-        except Exception:
-            self._quarantine(path)
-            return None
-
-    def _load_persisted(self, digest: str) -> Optional[_CompiledEntry]:
-        entry = self._preloaded.get(digest)
-        if entry is not None:
-            return entry
-        path = self._cache_path(digest)
-        if not os.path.isfile(path):
-            return None
-        entry = self._deserialize_file(path)
-        if entry is not None:
-            self._preloaded[digest] = entry
-        return entry
-
-    def _persist(self, digest: str, compiled, flops: float,
-                 bytes_: float) -> None:
-        """Serialize + atomically publish one executable (tmp + rename, so
-        a concurrent fleet's racing writers and readers only ever see
-        complete entries). Any failure is logged-and-forgotten: the cache
-        is an accelerator, not a dependency."""
-        try:
-            from jax.experimental.serialize_executable import serialize
-
-            payload, in_tree, out_tree = serialize(compiled)
-            blob = pickle.dumps({
-                "magic": _AOT_MAGIC, "fn": self.name,
-                "runtime": self._runtime_key(), "payload": payload,
-                "in_tree": in_tree, "out_tree": out_tree,
-                "flops": flops, "bytes": bytes_,
-            })
-            path = self._cache_path(digest)
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            tmp = f"{path}.{os.getpid()}.tmp"
-            with open(tmp, "wb") as f:
-                f.write(blob)
-            os.replace(tmp, path)
-        except Exception:
-            import logging
-
-            logging.getLogger("synapseml_tpu").debug(
-                "persisting AOT executable for %s failed", self.name,
-                exc_info=True)
-
-    def warm_start(self) -> int:
-        """Deserialize every persisted executable for THIS entry point into
-        the preloaded map (first call of a seen signature then skips both
-        compile and load). Returns how many loaded."""
-        d = aot_cache_dir()
-        if d is None or not os.path.isdir(d):
-            return 0
-        prefix = self._safe_name() + "-"
-        n = 0
-        for fname in sorted(os.listdir(d)):
-            if not (fname.startswith(prefix) and fname.endswith(".aot")):
-                continue
-            digest = fname[len(prefix):-len(".aot")]
-            if digest in self._preloaded:
-                continue
-            entry = self._deserialize_file(os.path.join(d, fname))
-            if entry is not None:
-                self._preloaded[digest] = entry
-                n += 1
-        return n
 
     def _record_compile(self, dt: float, cause: str, flops: float) -> None:
         jax = _jax_if_loaded()
@@ -904,33 +658,17 @@ class ProfiledJit:
                             uid=self.name, duration_s=dt, cause=cause,
                             backend=backend, flops=flops)
 
-    def cost(self) -> Dict[str, Any]:
-        """Cached cost analysis per compiled signature (newest last):
-        ``[{"flops": ..., "bytes": ...}, ...]`` — what ``/metrics`` MFU
-        figures are computed from."""
-        with self._lock:
-            return {"fn": self.name,
-                    "executables": [{"flops": e.flops, "bytes": e.bytes}
-                                    for e in self._cache.values()]}
-
 
 def profiled_jit(fn=None, *, name: Optional[str] = None,
-                 static_argnames: Sequence[str] = (),
-                 closure_key: Optional[str] = None):
+                 static_argnames: Sequence[str] = ()):
     """Wrap ``fn`` in a :class:`ProfiledJit` (decorator or call form).
-
-    ``closure_key`` joins the persisted-AOT digest: pass a fingerprint of
-    any closure state (weight placement plans, dtype policy) that two
-    same-named wrappers could disagree on.
 
     >>> step = profiled_jit(_step_impl, name="gbdt.step")
     """
     if fn is None:
         return lambda f: ProfiledJit(f, name=name,
-                                     static_argnames=static_argnames,
-                                     closure_key=closure_key)
-    return ProfiledJit(fn, name=name, static_argnames=static_argnames,
-                       closure_key=closure_key)
+                                     static_argnames=static_argnames)
+    return ProfiledJit(fn, name=name, static_argnames=static_argnames)
 
 
 # ---------------------------------------------------------------------------

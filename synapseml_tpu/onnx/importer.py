@@ -113,7 +113,7 @@ class _SharedProgram:
     instance's weights it is then called with. It holds its instances
     weakly, so a dropped model's weights leave the device."""
 
-    def __init__(self, name: str, closure_key: str):
+    def __init__(self, name: str):
         from ..observability.profiling import profiled_jit
 
         self.instances: "weakref.WeakSet[OnnxFunction]" = weakref.WeakSet()
@@ -121,8 +121,7 @@ class _SharedProgram:
         # timed into smt_compile_seconds{fn=...}, its cost_analysis FLOPs
         # cached, and warm calls attribute achieved MFU to the enclosing
         # stage span (observability/profiling.py)
-        self.jit = profiled_jit(self._run_positional, name=name,
-                                closure_key=closure_key)
+        self.jit = profiled_jit(self._run_positional, name=name)
 
     def _run_positional(self, *arrays):  # the XLA module is named after it
         with _PROGRAMS_LOCK:  # a WeakSet must not change size under the look
@@ -142,7 +141,6 @@ class OnnxFunction:
     """
 
     def __init__(self, model: "ModelProto | bytes", dtype_policy: str = "float32",
-                 channels_last: bool = False,
                  external_data_dir: "str | None" = None,
                  layout=None):
         import jax
@@ -155,15 +153,6 @@ class OnnxFunction:
         if dtype_policy not in ("float32", "bfloat16"):
             raise ValueError(f"unknown dtype_policy {dtype_policy!r}")
         self.dtype_policy = dtype_policy
-        # NHWC layout propagation (opt-in): Conv/BatchNorm/elementwise chains
-        # execute channels-last; other consumers transpose back on demand.
-        # An ISOLATED mid-network conv measures ~1.6x faster NHWC on v5e,
-        # but on the full ResNet-50 graph XLA's layout assignment already
-        # picks optimal physical layouts for the logical-NCHW program and the
-        # pass's edge transposes cost more than they save (measured 12.9 vs
-        # 16.4 ms/fwd at batch 128) — hence default OFF; kept for backends
-        # whose layout assignment is weaker.
-        self.channels_last = bool(channels_last)
         self._external_dir = external_data_dir
         # model-local functions: nodes whose (domain, op_type) matches expand
         # to the function body (real exporters emit e.g. LayerNormalization
@@ -205,30 +194,25 @@ class OnnxFunction:
                  or getattr(layout, "fsdp_size", 1) > 1) else {})
         self._fn_name = "onnx." + (getattr(self.graph, "name", "") or "graph")
         self._place_weights()
-        # the persisted-AOT digest must see the weight placement: the
-        # same graph under a replicated, (1,2)-tp or (2,2,2)-fsdp layout
-        # compiles three different executables behind identical input
-        # avals, and loading the wrong one raises (at best)
-        closure_key = f"dtype={self.dtype_policy}"
+        policy_and_placement = f"dtype={self.dtype_policy}"
         if self._const_specs:
-            closure_key += ";layout=" + str(layout.describe()) + ";" + \
+            policy_and_placement += ";layout=" + str(layout.describe()) + ";" + \
                 ",".join(f"{n}:{self._const_specs[n]}"
                          for n in sorted(self._const_specs))
         # one entry point for every live OnnxFunction of this program: two
         # checkpoints of one graph differ in arguments only
-        digest = self._program_digest(closure_key)
+        digest = self._program_digest(policy_and_placement)
         with _PROGRAMS_LOCK:
             program = _PROGRAMS.get(digest)
             if program is None:
-                program = _PROGRAMS[digest] = _SharedProgram(
-                    self._fn_name, closure_key)
+                program = _PROGRAMS[digest] = _SharedProgram(self._fn_name)
             program.instances.add(self)
         self._program = program
         self._jit = program.jit
         # the weights' part of a call's signature is settled here, once
         self._call_with_weights = program.jit.bind(*self._weights)
 
-    def _program_digest(self, closure_key: str) -> str:
+    def _program_digest(self, policy_and_placement: str) -> str:
         """What a trace of ``_run_positional`` depends on besides its
         arguments: the nodes, the policy, every initializer's name, type and
         shape, and the VALUES of those that stay constants. Equal digests
@@ -237,7 +221,7 @@ class OnnxFunction:
         from .wire import _ser_attribute, _ser_node
 
         h = hashlib.sha256(repr((
-            closure_key, self.channels_last, self._external_dir,
+            policy_and_placement, self._external_dir,
             sorted(self.model.opset_imports.items()),
             id(self.layout) if self._const_specs else None,
             [vi.name for vi in self.graph.input], self.output_names,
@@ -605,133 +589,7 @@ class OnnxFunction:
                 "program, summed over nodes",
                 ("fn",), merge="max").labels(fn).set(notes["experts_held"])
 
-    # unary ops that are layout-agnostic: run them directly on an NHWC array
-    _NHWC_UNARY = frozenset({
-        "Relu", "LeakyRelu", "Sigmoid", "Tanh", "Elu", "Selu", "Softplus",
-        "HardSigmoid", "Identity", "Neg", "Abs", "Sqrt", "Exp", "Log",
-        "Floor", "Ceil", "Erf", "Clip", "Cast",
-    })
-    _NHWC_BINARY = frozenset({"Add", "Sub", "Mul", "Div", "Min", "Max",
-                              "Pow", "PRelu"})
-
-    def _try_nhwc(self, node, env: Dict[str, Any], nhwc: set) -> bool:
-        """Execute ``node`` channels-last when profitable. Returns True when
-        the node was handled (outputs written to env, layout recorded)."""
-        import jax.numpy as jnp
-        from jax import lax
-
-        op_type = node.op_type
-        ins = [env.get(i) if i else None for i in node.input]
-        if all(v is None or _is_const(v) for v in ins):
-            return False  # leave constant folding to the generic path
-
-        def as_nhwc(name):
-            v = env[name]
-            return v if name in nhwc else jnp.transpose(v, (0, 2, 3, 1))
-
-        accum = jnp.float32 if self.dtype_policy == "bfloat16" else None
-
-        if op_type == "Conv" and ins[0] is not None and ins[0].ndim == 4 \
-                and ins[1] is not None and ins[1].ndim == 4:
-            attrs = node.attrs()
-            x = as_nhwc(node.input[0])
-            w = ins[1]  # OIHW
-            strides = [int(s) for s in attrs.get("strides", [1, 1])]
-            dils = [int(d) for d in attrs.get("dilations", [1, 1])]
-            groups = int(attrs.get("group", 1))
-            from .ops import _resolve_pads
-
-            # _resolve_pads reads spatial dims at x_shape[2+i]; feed std dims
-            std_shape = (x.shape[0], x.shape[3], x.shape[1], x.shape[2])
-            pads = _resolve_pads(attrs, 2, std_shape, w.shape[2:], strides,
-                                 dils)
-            hwio = (w.shape[2], w.shape[3], w.shape[1], w.shape[0])
-            dn = lax.conv_dimension_numbers(x.shape, hwio,
-                                            ("NHWC", "HWIO", "NHWC"))
-            out = lax.conv_general_dilated(
-                x, jnp.transpose(w, (2, 3, 1, 0)), window_strides=strides,
-                padding=pads, rhs_dilation=dils, dimension_numbers=dn,
-                feature_group_count=groups, preferred_element_type=accum)
-            if out.dtype != x.dtype:
-                out = out.astype(x.dtype)
-            if len(ins) > 2 and ins[2] is not None:
-                out = out + ins[2].reshape((1, 1, 1, -1))
-            env[node.output[0]] = out
-            nhwc.add(node.output[0])
-            return True
-
-        if op_type == "BatchNormalization" and len(node.output) == 1 \
-                and node.input[0] in nhwc:
-            attrs = node.attrs()
-            x = env[node.input[0]]
-            scale, bias, mean, var = ins[1:5]
-            eps = attrs.get("epsilon", 1e-5)
-            inv = lax.rsqrt(jnp.asarray(var, jnp.float32) + eps).astype(x.dtype)
-            env[node.output[0]] = (x - mean) * (scale * inv) + bias
-            nhwc.add(node.output[0])
-            return True
-
-        if op_type in self._NHWC_UNARY and node.input and \
-                node.input[0] in nhwc and len(node.output) == 1:
-            inputs = [env[i] if i else None for i in node.input]
-            ctx = {"op_type": op_type, "opset": self.opset, "n_outputs": 1,
-                   "accum_dtype": accum, "subgraph_runner": None,
-                   "external_dir": self._external_dir}
-            env[node.output[0]] = OPS[op_type](inputs, node.attrs(), ctx)
-            nhwc.add(node.output[0])
-            return True
-
-        if op_type in self._NHWC_BINARY and len(node.input) >= 2 \
-                and len(node.output) == 1:
-            a_name, b_name = node.input[0], node.input[1]
-            va, vb = env.get(a_name), env.get(b_name)
-            if va is None or vb is None:
-                return False
-            na, nb = a_name in nhwc, b_name in nhwc
-
-            def compatible(other, other_is_nhwc):
-                """Rewritten operand broadcastable against NHWC, or None."""
-                if other_is_nhwc:
-                    return other
-                if np.isscalar(other) or getattr(other, "ndim", None) == 0 \
-                        or getattr(other, "size", None) == 1:
-                    return other
-                # NCHW-broadcast constants (1, C, 1, 1) / (C, 1, 1) -> last-axis
-                shp = getattr(other, "shape", None)
-                if shp is not None and len(shp) == 4 and shp[2] == shp[3] == 1:
-                    return jnp.transpose(jnp.asarray(other), (0, 2, 3, 1))
-                if shp is not None and len(shp) == 3 and shp[1] == shp[2] == 1:
-                    return jnp.asarray(other).reshape(1, 1, 1, -1)
-                return None
-
-            if na and nb:
-                if getattr(va, "shape", None) != getattr(vb, "shape", None):
-                    return False
-                xa, xb = va, vb
-            elif na:
-                xb = compatible(vb, False)
-                if xb is None:
-                    return False
-                xa = va
-            elif nb:
-                xa = compatible(va, False)
-                if xa is None:
-                    return False
-                xb = vb
-            else:
-                return False
-            ctx = {"op_type": op_type, "opset": self.opset, "n_outputs": 1,
-                   "accum_dtype": accum, "subgraph_runner": None,
-                   "external_dir": self._external_dir}
-            env[node.output[0]] = OPS[op_type](
-                [xa, xb] + [env[i] if i else None for i in node.input[2:]],
-                node.attrs(), ctx)
-            nhwc.add(node.output[0])
-            return True
-
-        return False
-
-    def _run_function(self, fdef, call, env: Dict[str, Any], to_std,
+    def _run_function(self, fdef, call, env: Dict[str, Any],
                       handoff: "List[int] | None" = None,
                       notes: "Dict[str, int] | None" = None) -> None:
         """Inline-expand a model-local function call: bind formal inputs,
@@ -741,8 +599,6 @@ class OnnxFunction:
         own opset, and export the formal outputs."""
         import dataclasses
 
-        for i in call.input:
-            to_std(i)
         call_attrs = {a.name: a for a in call.attribute}
         for a in fdef.attribute_proto:  # declared params with defaults
             call_attrs.setdefault(a.name, a)
@@ -801,20 +657,12 @@ class OnnxFunction:
 
         opset = self.opset if opset is None else opset
         accum = jnp.float32 if self.dtype_policy == "bfloat16" else None
-        nhwc: set = set()  # value names currently stored channels-last
         # names this graph's nodes make that no node has been seen to read yet
         unread = ({o for n in graph.node for o in n.output}
                   if handoff is not None else set())
 
-        def to_std(name: str) -> None:
-            if name in nhwc:
-                env[name] = jnp.transpose(env[name], (0, 3, 1, 2))
-                nhwc.discard(name)
-
         def subgraph_runner(sub: GraphProto):
             def run():
-                for name in list(nhwc):  # subgraphs see standard layout
-                    to_std(name)
                 sub_env = dict(env)
                 self._run_graph(sub, sub_env, opset=opset, handoff=handoff,
                                 notes=notes)
@@ -840,19 +688,12 @@ class OnnxFunction:
             if fdef is not None and (node.domain not in ("", "ai.onnx")
                                      or node.op_type not in OPS):
                 with jax.named_scope(scope):
-                    self._run_function(fdef, node, env, to_std, handoff,
-                                       notes)
+                    self._run_function(fdef, node, env, handoff, notes)
                 continue
             try:
                 fn = OPS[node.op_type]
             except KeyError:
                 raise NotImplementedError(f"unsupported ONNX op {node.op_type}") from None
-            if self.channels_last:
-                with jax.named_scope(scope):
-                    if self._try_nhwc(node, env, nhwc):
-                        continue
-            for i in node.input:  # fallback consumers get standard layout
-                to_std(i)
             inputs = [env[i] if i else None for i in node.input]
             ctx = {
                 "op_type": node.op_type,
@@ -893,8 +734,6 @@ class OnnxFunction:
             for name, val in zip(node.output, outs):
                 if name:
                     env[name] = val
-        for vi in graph.output:  # graph outputs leave in standard layout
-            to_std(vi.name)
 
 
 def load_model(path_or_bytes, dtype_policy: str = "float32") -> OnnxFunction:

@@ -17,11 +17,10 @@ Two backends implement ``run(task, on_rung) -> result``:
   of ``io/serving_worker``: one worker per slot, line-oriented
   stdin/stdout protocol (``READY`` handshake, ``TASK``/``RUNG``/``CONT``/
   ``STOP``/``DONE``/``FAIL``), models shipped between segments via
-  ``core.serialization`` round-trips, and all workers sharing one
-  ``SMT_AOT_CACHE_DIR`` so identical static configs compile once
-  fleet-wide. A worker that dies or stops answering within
-  ``task_timeout_s`` raises :class:`WorkerCrash`; the study retries the
-  task once on a fresh worker, then records the trial ``failed``.
+  ``core.serialization`` round-trips. A worker that dies or stops
+  answering within ``task_timeout_s`` raises :class:`WorkerCrash`; the
+  study retries the task once on a fresh worker, then records the trial
+  ``failed``.
 
 Fault injection: the ``"tuning.trial"`` seam (``io/faultinject``) is
 consulted at segment start and at every rung boundary with key
@@ -378,8 +377,8 @@ class _WorkerHandle:
 class ProcessExecutor:
     """Process-pool backend: each study slot thread owns one persistent
     worker subprocess (thread-local), respawned lazily after a crash. All
-    workers inherit the study's ``SMT_AOT_CACHE_DIR`` (persisted-AOT
-    sharing) and ``SMT_FAULT_PLAN`` (each worker parses its own plan)."""
+    workers inherit the study's ``SMT_FAULT_PLAN`` (each worker parses its
+    own plan)."""
 
     kind = "processes"
 

@@ -16,10 +16,8 @@ band: the estimator template (``core.serialization`` stage dir), the
 fitted ``BinMapper`` as JSON, and the raw/binned/label matrices as
 ``.npy`` files loaded ``mmap_mode="r"`` — the shared-binning design means
 a worker never re-runs the binning pass, it just maps the study's binned
-matrix into memory. ``SMT_AOT_CACHE_DIR`` and ``SMT_FAULT_PLAN`` arrive
-via the environment; the ``DONE`` payload reports this process's compile
-and AOT-cache counters so the study (and tests) can prove that identical
-static configs compiled once fleet-wide.
+matrix into memory. ``SMT_FAULT_PLAN`` arrives via the environment; the
+``DONE`` payload reports how many compiles this process has paid.
 
 Jax-free at import: everything heavy loads inside :func:`main` after the
 argument parse.
@@ -45,21 +43,13 @@ def _worker_crash(rule) -> None:
 
 
 def _compile_stats() -> Dict[str, Any]:
-    """This process's compile/AOT counters, shipped home in ``DONE`` so
-    the study can aggregate fleet-wide compile behavior."""
+    """This process's compile count, shipped home in ``DONE`` so the
+    study can aggregate fleet-wide compile behavior."""
     from synapseml_tpu.observability.metrics import get_registry
 
-    fams = get_registry().snapshot()["families"]
-    out: Dict[str, Any] = {"compile_samples": 0, "aot": {}}
-    fam = fams.get("smt_compile_seconds")
-    if fam:
-        out["compile_samples"] = sum(
-            int(s.get("count", 0)) for s in fam["series"])
-    for name, f in fams.items():
-        if name.startswith("smt_aot_cache_"):
-            out["aot"][name] = sum(
-                int(s.get("value", 0)) for s in f["series"])
-    return out
+    fam = get_registry().snapshot()["families"].get("smt_compile_seconds")
+    series = fam["series"] if fam else []
+    return {"compile_samples": sum(int(s.get("count", 0)) for s in series)}
 
 
 def build_context(study_dir: str):

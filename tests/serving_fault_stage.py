@@ -82,16 +82,15 @@ def _burn_impl(x):
     return x
 
 
-# module-level so every process that imports this module shares one entry
-# point (the persisted AOT cache is keyed by this name)
+# module-level so every process that imports this module names the entry
+# point alike in its compile accounting
 burn = profiled_jit(_burn_impl, name="test.lifecycle_burn")
 
 
 class JitBurnReply(Transformer):
     """Runs a deliberately compile-heavy profiled jit once per batch, then
-    echoes ``{pid}:{body}`` — the warm-start tests' workload: a cold
-    worker pays a multi-hundred-ms XLA compile on its first batch, a
-    warm-started one (persisted AOT cache) does not."""
+    echoes ``{pid}:{body}`` — the scale-up tests' workload: a fresh
+    worker pays a multi-hundred-ms XLA compile on its first batch."""
 
     reply_col = "reply"
 

@@ -161,23 +161,33 @@ def test_cache_dir_defaults_to_the_checkout_in_all_three(tmp_path, jax_first):
     assert os.path.exists(default) == existed
 
 
-def test_one_place_decides_the_cache_dir():
+@pytest.mark.parametrize("names, named_in", [
+    # the variable and the jax option that place jax's persistent cache
+    (("compilation_cache_dir", "JAX_COMPILATION_CACHE_DIR"),
+     ["synapseml_tpu/runtime/compile_cache.py"]),
+    # a second place for compiled programs (executables pickled under a
+    # directory of the package's own) and a second tensor layout in the
+    # executor; in halves, so that a grep over tests/ finds neither
+    (("serialize_" "executable", "SMT_AOT_" "CACHE_DIR", "channels_" "last"),
+     []),
+], ids=["jax_cache", "no_private_tier_no_second_layout"])
+def test_one_place_decides_the_cache_dir(names, named_in):
     """Acceptance: grep finds ONE module in the package (and no tool or
-    entry script) that names the variable or the jax option."""
+    entry script) that places a compiled program on disk, and it places
+    jax's own cache."""
     hits = []
     roots = [os.path.join(REPO, "synapseml_tpu"), os.path.join(REPO, "tools")]
     files = [os.path.join(REPO, f) for f in ("bench.py", "chip_smoke.py",
                                              "__graft_entry__.py")]
     for root in roots:
-        for d, _, names in os.walk(root):
-            files += [os.path.join(d, n) for n in names if n.endswith(".py")]
+        for d, _, found in os.walk(root):
+            files += [os.path.join(d, n) for n in found if n.endswith(".py")]
     for path in files:
         with open(path, encoding="utf-8") as f:
             src = f.read()
-        if "compilation_cache_dir" in src \
-                or "JAX_COMPILATION_CACHE_DIR" in src:
+        if any(name in src for name in names):
             hits.append(os.path.relpath(path, REPO))
-    assert hits == ["synapseml_tpu/runtime/compile_cache.py"]
+    assert hits == named_in
 
 
 # -- the four-chip sibling ---------------------------------------------------

@@ -141,25 +141,6 @@ def test_resnet18_zoo_runs():
     assert np.isfinite(np.asarray(out["logits"])).all()
 
 
-def test_channels_last_layout_pass_matches_nchw():
-    """The opt-in NHWC propagation (Conv/BN/elementwise chains channels-last,
-    transposes only at graph edges) must be numerically equivalent to the
-    default NCHW execution."""
-    from synapseml_tpu.models import build_model_bytes
-    from synapseml_tpu.onnx import OnnxFunction
-
-    mb = build_model_bytes("ResNet18", num_classes=10)
-    x = np.random.default_rng(9).normal(size=(2, 3, 224, 224)).astype(np.float32)
-    out_nchw = OnnxFunction(mb)({"data": x})
-    out_nhwc = OnnxFunction(mb, channels_last=True)({"data": x})
-    np.testing.assert_allclose(np.asarray(out_nhwc["logits"]),
-                               np.asarray(out_nchw["logits"]),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(out_nhwc["features"]),
-                               np.asarray(out_nchw["features"]),
-                               rtol=2e-4, atol=2e-4)
-
-
 def test_bert_tiny_zoo_runs():
     from synapseml_tpu.models import build_model_bytes
     from synapseml_tpu.onnx import OnnxFunction

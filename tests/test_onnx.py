@@ -666,6 +666,46 @@ def test_tp_sharded_matmul_weights_match_single_device():
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 
+def test_replicated_and_tp_instances_of_one_graph_never_share_a_program():
+    """One graph live twice in one process, replicated and under a (1, 2)
+    model-parallel layout: the same feeds and the same weight shapes, but
+    two programs (``_program_digest`` sees the placement), each compiled
+    once, and the same answers."""
+    import jax
+
+    from synapseml_tpu.observability.metrics import get_registry
+    from synapseml_tpu.runtime.layout import SpecLayout
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 devices for the (1, 2) layout")
+
+    def compiles(fn_name):
+        family = get_registry().snapshot()["families"].get(
+            "smt_compile_seconds") or {"series": []}
+        return sum(int(s["count"]) for s in family["series"]
+                   if s["labels"][0] == fn_name)
+
+    rng = np.random.default_rng(29)
+    # a hidden width of this test's own: no program of an earlier test's
+    # graph, still alive and compiled, is found in its place
+    mb = _tp_mlp_bytes(rng, h=48)
+    x = rng.normal(size=(16, 32)).astype(np.float32)
+    replicated = OnnxFunction(mb)
+    sharded = OnnxFunction(mb, layout=SpecLayout.build(
+        data=1, model=2, devices=jax.devices()[:2]))
+    assert set(sharded._const_specs) == {"w1", "w2"}
+    assert sharded._program is not replicated._program
+    assert sharded._jit is not replicated._jit
+    name = replicated._jit.name
+    assert sharded._jit.name == name
+    before = compiles(name)
+    for _ in range(2):  # the second round compiles nothing
+        ref = np.asarray(replicated({"x": x})["y"])
+        out = np.asarray(sharded({"x": x})["y"])
+    assert compiles(name) == before + 2
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+
+
 def test_tp_sharding_degrades_to_single_chip():
     """(1, 1) layout: no weight sharded, outputs bit-identical."""
     import jax

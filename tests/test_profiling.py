@@ -514,145 +514,3 @@ def test_perf_timeline_cli_jax_free_on_artifacts(tmp_path):
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr
-
-
-# ---------------------------------------------------------------------------
-# persisted AOT cache (fleet warm start)
-# ---------------------------------------------------------------------------
-
-@pytest.fixture
-def aot_dir(tmp_path):
-    d = str(tmp_path / "aot")
-    os.makedirs(d)
-    profiling.set_aot_cache_dir(d)
-    try:
-        yield d
-    finally:
-        profiling.set_aot_cache_dir(None)
-
-
-def _heavy(x):
-    import jax.numpy as jnp
-
-    return jnp.tanh(x @ x.T).sum()
-
-
-def test_aot_cache_persists_and_new_instance_hits(fresh_registry, aot_dir):
-    pj = profiling.profiled_jit(_heavy, name="t.aot")
-    x = np.ones((16, 16), np.float32)
-    pj(x)
-    files = [f for f in os.listdir(aot_dir) if f.endswith(".aot")]
-    assert len(files) == 1 and files[0].startswith("t.aot-")
-    snap = fresh_registry.snapshot()
-    assert _series(snap, "smt_aot_cache_misses_total")[("t.aot",)][
-        "value"] == 1
-    # a FRESH instance (a new worker process in miniature): the compile is
-    # served from disk — hit counted, NO new smt_compile_seconds sample
-    before = _series(snap, "smt_compile_seconds")[("t.aot", "cpu")]["count"]
-    pj2 = profiling.profiled_jit(_heavy, name="t.aot")
-    pj2(x)
-    snap2 = fresh_registry.snapshot()
-    assert _series(snap2, "smt_aot_cache_hits_total")[("t.aot",)][
-        "value"] == 1
-    assert _series(snap2, "smt_compile_seconds")[("t.aot", "cpu")][
-        "count"] == before
-
-
-def test_aot_cache_closure_key_separates_placement_plans(fresh_registry,
-                                                         aot_dir):
-    # same fn name, same input avals, DIFFERENT closure placement plan
-    # (a replicated vs an fsdp-stored ONNX executor in miniature): the
-    # digests must differ so neither instance loads the other's
-    # executable — a distinct .aot file per closure key, miss counted
-    # for each
-    x = np.ones((16, 16), np.float32)
-    pj_rep = profiling.profiled_jit(_heavy, name="t.ckey",
-                                    closure_key="layout=replicated")
-    pj_rep(x)
-    pj_fsdp = profiling.profiled_jit(
-        _heavy, name="t.ckey",
-        closure_key="layout=(1,2,2);w:P('fsdp', 'model')")
-    pj_fsdp(x)
-    files = [f for f in os.listdir(aot_dir) if f.startswith("t.ckey-")]
-    assert len(files) == 2
-    snap = fresh_registry.snapshot()
-    assert _series(snap, "smt_aot_cache_misses_total")[("t.ckey",)][
-        "value"] == 2
-    # and a fresh same-key instance still hits its own entry
-    pj3 = profiling.profiled_jit(_heavy, name="t.ckey",
-                                 closure_key="layout=replicated")
-    pj3(x)
-    snap2 = fresh_registry.snapshot()
-    assert _series(snap2, "smt_aot_cache_hits_total")[("t.ckey",)][
-        "value"] == 1
-
-
-def test_aot_cache_prewarm_loads_every_entry(fresh_registry, aot_dir):
-    pj = profiling.profiled_jit(_heavy, name="t.prewarm")
-    pj(np.ones((8, 8), np.float32))
-    pj(np.ones((12, 12), np.float32))  # second signature, second entry
-    pj2 = profiling.profiled_jit(_heavy, name="t.prewarm")
-    assert pj2.warm_start() == 2
-    assert pj2.warm_start() == 0  # per-instance idempotent
-    pj2(np.ones((8, 8), np.float32))
-    pj2(np.ones((12, 12), np.float32))
-    snap = fresh_registry.snapshot()
-    assert _series(snap, "smt_aot_cache_hits_total")[("t.prewarm",)][
-        "value"] == 2
-
-
-def test_aot_cache_corrupt_entry_quarantined_and_recompiled(fresh_registry,
-                                                            aot_dir):
-    pj = profiling.profiled_jit(_heavy, name="t.corrupt")
-    x = np.ones((16, 16), np.float32)
-    pj(x)
-    (path,) = [os.path.join(aot_dir, f) for f in os.listdir(aot_dir)
-               if f.endswith(".aot")]
-    with open(path, "wb") as f:
-        f.write(b"\x00garbage")
-    pj2 = profiling.profiled_jit(_heavy, name="t.corrupt")
-    assert float(pj2(x)) == float(pj(x))  # NEVER a crash: recompiles
-    snap = fresh_registry.snapshot()
-    assert _series(snap, "smt_aot_cache_quarantined_total")[("t.corrupt",)][
-        "value"] == 1
-    # the damaged entry was set aside, and the recompile re-persisted a
-    # good one under the same digest
-    assert os.path.exists(path + ".quarantined")
-    assert os.path.exists(path)
-
-
-def test_aot_cache_version_mismatch_is_silent_recompile(fresh_registry,
-                                                        aot_dir,
-                                                        monkeypatch):
-    import jax
-
-    pj = profiling.profiled_jit(_heavy, name="t.version")
-    x = np.ones((16, 16), np.float32)
-    pj(x)
-    assert len(os.listdir(aot_dir)) == 1
-    # a worker on a different jax: the digest differs, so the persisted
-    # entry is simply invisible — silent recompile, never a wrong load
-    monkeypatch.setattr(jax, "__version__", "999.0.0")
-    pj2 = profiling.profiled_jit(_heavy, name="t.version")
-    assert float(pj2(x)) == float(pj(x))
-    snap = fresh_registry.snapshot()
-    hits = snap["families"].get("smt_aot_cache_hits_total")
-    assert hits is None or all(s["value"] == 0 for s in hits["series"])
-    assert _series(snap, "smt_aot_cache_misses_total")[("t.version",)][
-        "value"] == 2  # both compiles persisted under their own digests
-    assert len([f for f in os.listdir(aot_dir) if f.endswith(".aot")]) == 2
-    # bulk warm_start on the mismatched runtime SKIPS the foreign entry —
-    # it is valid for whoever wrote it, so never quarantined
-    pj3 = profiling.profiled_jit(_heavy, name="t.version")
-    assert pj3.warm_start() == 1  # only the 999.0.0 entry loads
-    assert "smt_aot_cache_quarantined_total" not in \
-        fresh_registry.snapshot()["families"]
-    assert not [f for f in os.listdir(aot_dir) if "quarantined" in f]
-
-
-def test_aot_cache_off_means_no_files(fresh_registry, tmp_path):
-    assert profiling.aot_cache_dir() is None
-    pj = profiling.profiled_jit(_heavy, name="t.off")
-    pj(np.ones((8, 8), np.float32))
-    snap = fresh_registry.snapshot()
-    assert "smt_aot_cache_misses_total" not in snap["families"]

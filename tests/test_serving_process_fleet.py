@@ -295,12 +295,9 @@ def test_rolling_swap_across_processes_with_mid_roll_kill():
         fleet.stop()
 
 
-def test_scale_up_under_load_is_warm_start_bounded():
-    """Satellite: a worker added under load with a shared persisted-AOT
-    cache pre-warms before registering — its metrics show a persisted
-    cache HIT and NO cold ``smt_compile_seconds`` sample for the
-    pre-warmed signature, and its first direct request answers in a
-    fraction of the measured cold-compile time."""
+def test_scale_up_under_load_drops_nothing_and_new_worker_answers():
+    """A worker added under load drops no request, and its first direct
+    request answers 200 with its own compile on its own books."""
     import json as _json
     import threading
     import time
@@ -311,18 +308,15 @@ def test_scale_up_under_load_is_warm_start_bounded():
     fleet = ProcessServingFleet(
         JitBurnReply(), n_workers=1,
         import_modules=["tests.serving_fault_stage"], reply_timeout=30.0,
-        startup_timeout=120.0, aot_cache_dir="auto")
+        startup_timeout=120.0)
     try:
-        # worker 0 compiles COLD and persists the executable
-        _hit(fleet.address)
+        _hit(fleet.address)  # worker 0 compiles cold
         snap0 = _json.loads(urllib.request.urlopen(
             fleet.addresses[0] + "/metrics?format=json",
             timeout=15).read().decode())
         fam0 = snap0["families"]
         comp = [s for s in fam0["smt_compile_seconds"]["series"]]
         assert comp and comp[0]["count"] >= 1  # the cold compile happened
-        cold_compile_s = comp[0]["sum"]
-        assert fam0["smt_aot_cache_misses_total"]["series"][0]["value"] >= 1
 
         # sustained load while the fleet scales up
         stop = threading.Event()
@@ -343,26 +337,14 @@ def test_scale_up_under_load_is_warm_start_bounded():
         assert addr is not None
         assert all(codes)  # the scale-up dropped nothing
 
-        # the NEW worker's first direct request: warm-start bounded
-        t0 = time.perf_counter()
-        with urllib.request.urlopen(addr + "/", data=b"warm?",
+        # the NEW worker's first direct request
+        with urllib.request.urlopen(addr + "/", data=b"new?",
                                     timeout=30) as r:
             assert r.status == 200
-        first_reply_s = time.perf_counter() - t0
         snap1 = _json.loads(urllib.request.urlopen(
             addr + "/metrics?format=json", timeout=15).read().decode())
-        fam1 = snap1["families"]
-        # persisted cache hit counter > 0 ...
-        hits = fam1["smt_aot_cache_hits_total"]["series"]
-        assert hits and hits[0]["value"] >= 1, hits
-        # ... and NO cold compile sample for the pre-warmed signature
-        comp1 = fam1.get("smt_compile_seconds")
-        total1 = sum(s["count"] for s in comp1["series"]) if comp1 else 0
-        assert total1 == 0, comp1
-        # first reply beat the cold compile alone (generous 2x margin for
-        # CI noise; the bench lane measures the real speedup)
-        assert first_reply_s < max(cold_compile_s, 0.05) * 2.0, (
-            first_reply_s, cold_compile_s)
+        comp1 = snap1["families"]["smt_compile_seconds"]["series"]
+        assert sum(s["count"] for s in comp1) >= 1, comp1
     finally:
         fleet.stop()
 
@@ -375,12 +357,10 @@ def test_beyond_hbm_model_served_fsdp_under_device_budget():
     INSIDE the worker processes: (a) the replicated control really busts
     the budget, (b) the fsdp worker's at-rest residency sits under it —
     and under the replicated control, (c) numeric parity across the two
-    fleets, (d) a worker added later warm-starts the fsdp executable from
-    the persisted AOT cache (hit counter > 0, zero cold-compile samples).
-    The strict >= 0.9x throughput gate runs on real hardware in the
+    fleets, (d) a worker added later holds the same bytes and returns the
+    same sum. The strict >= 0.9x throughput gate runs on real hardware in the
     ``onnx_fsdp_hbm`` bench lane; here a loose wall-clock sanity bound
     keeps CI honest without timing flakes."""
-    import json as _json
     import time
 
     import jax
@@ -419,7 +399,7 @@ def test_beyond_hbm_model_served_fsdp_under_device_budget():
     fleet = ProcessServingFleet(
         FsdpOnnxReply(use_fsdp=True), n_workers=1,
         import_modules=["tests.serving_fault_stage"], reply_timeout=60.0,
-        startup_timeout=120.0, aot_cache_dir="auto")
+        startup_timeout=120.0)
     try:
         fsdp_bytes, fsdp_sum = _ask(fleet.address)
         assert fsdp_bytes < FSDP_DEVICE_BUDGET_BYTES
@@ -437,26 +417,10 @@ def test_beyond_hbm_model_served_fsdp_under_device_budget():
         fsdp_best = min(fsdp_times)
         assert fsdp_best < max(rep_best, 0.02) * 10.0, (fsdp_times, rep_times)
 
-        # worker 0 compiled cold and persisted the (1,2,2) executable
-        fam0 = _json.loads(urllib.request.urlopen(
-            fleet.addresses[0] + "/metrics?format=json",
-            timeout=15).read().decode())["families"]
-        assert fam0["smt_aot_cache_misses_total"]["series"][0]["value"] >= 1
-
-        # a worker added later serves its first request from the persisted
-        # cache: hit counter up, NO cold smt_compile_seconds sample
         addr = fleet.add_worker()
         assert addr is not None
         new_bytes, new_sum = _ask(addr)
         assert new_bytes == fsdp_bytes
         assert abs(new_sum - fsdp_sum) <= 1e-6 * abs(fsdp_sum)
-        fam1 = _json.loads(urllib.request.urlopen(
-            addr + "/metrics?format=json",
-            timeout=15).read().decode())["families"]
-        hits = fam1["smt_aot_cache_hits_total"]["series"]
-        assert hits and hits[0]["value"] >= 1, hits
-        comp1 = fam1.get("smt_compile_seconds")
-        total1 = sum(s["count"] for s in comp1["series"]) if comp1 else 0
-        assert total1 == 0, comp1
     finally:
         fleet.stop()

@@ -137,9 +137,9 @@ def check_onnx(size, devices, on_chip):
         sharded_weights=len(fn._const_specs),
         weight_shard_devices=shard_devices, rel_err_vs_unsharded=rel,
         bytes_in_use=_bytes_in_use(devices[:4]), bytes_grown=grew_tp,
-        left_profiled_path=bool(fn._jit._aot_broken),
+        left_profiled_path=bool(fn._jit._left_profiled_path),
         ok=rel < 1e-2 and len(shard_devices) == 4
-        and not fn._jit._aot_broken
+        and not fn._jit._left_profiled_path
         and (not on_chip or all(b for b in _bytes_in_use(devices[:4]))))
     return ok_place and ok_tp
 
