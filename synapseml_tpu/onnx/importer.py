@@ -554,7 +554,8 @@ class OnnxFunction:
 
     def _record_notes(self, notes: Dict[str, int]) -> None:
         """Once a traced program: how its ``Attention`` and ``Gelu`` nodes
-        were lowered, and what its ``ExpertFFN`` nodes are sized for."""
+        were lowered, what its ``ExpertFFN`` nodes are sized for and how
+        they combine."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
@@ -576,6 +577,16 @@ class OnnxFunction:
         for form in ("erf_float32", "erfc"):
             if "gelu_" + form in notes:
                 gelu.labels(fn, form).inc(notes["gelu_" + form])
+        combine = reg.counter(
+            "smt_onnx_expert_combine_total",
+            "ExpertFFN nodes of a traced program by how their product rows "
+            "reach their tokens: held_first (a token's held picks first, "
+            "the first few gathered for every token, the few beyond those "
+            "added row by row)",
+            ("fn", "form"))
+        for key, count in notes.items():
+            if key.startswith("expert_combine_"):
+                combine.labels(fn, key[len("expert_combine_"):]).inc(count)
         if "expert_pairs" in notes:
             reg.gauge(
                 "smt_onnx_expert_pairs",

@@ -1324,6 +1324,10 @@ def _attention(inputs, attrs, ctx):
 _GMM_ROWS = 512
 # sorted pairs ExpertFFN gathers and multiplies at a time
 _PAIR_CHUNK = 48 * _GMM_ROWS
+# product rows ExpertFFN adds to their tokens at a time, where a token has
+# more held picks than are gathered for every token (a go pays for the rows
+# it is padded with)
+_REST_ROWS = 1024
 
 
 def _grouped_product(lhs, rhs, sizes):
@@ -1400,10 +1404,14 @@ def _expert_ffn(inputs, attrs, ctx):
     ends = jnp.cumsum(sizes)
     _note(ctx, "expert_pairs", n_pairs)
     _note(ctx, "experts_held", held)
+    _note(ctx, "expert_combine_held_first")
 
     # the sorted pairs a chunk at a time, for as many chunks as hold a held
     # expert's pair: the work follows the load (a quarter of the pairs where
-    # a quarter of the experts is held), and every pair has its place
+    # a quarter of the experts is held), and every pair has its place. The
+    # buffer starts as it is found: what no chunk wrote, and what the kernel
+    # left in the rows of a chunk past its last group, is selected away
+    # below and never reaches a sum
     n_chunks = -(-n_pairs // _PAIR_CHUNK)
     order_padded = jnp.pad(order, (0, n_chunks * _PAIR_CHUNK - n_pairs))
 
@@ -1418,12 +1426,54 @@ def _expert_ffn(inputs, attrs, ctx):
 
     results = lax.fori_loop(
         0, (ends[-1] + _PAIR_CHUNK - 1) // _PAIR_CHUNK, one_chunk,
-        jnp.zeros((n_chunks * _PAIR_CHUNK, h), x.dtype))
-    # back to pair order (the place of pair p among the sorted is where p
-    # sorts among the places); what the kernel left in the rows of a chunk
-    # past its last group never reaches the sum
-    out = jnp.where(here[:, None], results[jnp.argsort(order)], 0)
-    out = out.astype(jnp.float32) * weight.reshape(-1, k).T.reshape(
-        -1, 1).astype(jnp.float32)
-    return out.reshape(k, n_tokens, h).sum(axis=0).astype(x.dtype).reshape(
-        x.shape)
+        lax.empty((n_chunks * _PAIR_CHUNK, h), x.dtype))
+    return _held_picks_sum(
+        results, ~here.reshape(k, n_tokens),
+        # the place of pair p among the sorted is where p sorts among the
+        # places
+        jnp.argsort(order).astype(jnp.int32).reshape(k, n_tokens),
+        weight.reshape(-1, k).T.astype(jnp.float32),
+        min(k, -(-k * held // int(attrs["num_experts"])) + 1)
+    ).astype(x.dtype).reshape(x.shape)
+
+
+def _held_picks_sum(results, absent, place, weight, every):
+    """``sum over a token's picks p that are not absent of weight[p, t] *
+    results[place[p, t]]``, float32 ``[n_tokens, h]``; ``absent``, ``place``
+    and ``weight`` are ``[k, n_tokens]``.
+
+    A row gather costs by the row it moves, wanted or not (43 ns of
+    ``[2688]`` bfloat16 on a v5e), and a row scatter six times that
+    (``tools/row_move_rates.py``), so neither all ``k`` picks of every token
+    are gathered (where a quarter of the experts is held, three in four are
+    absent) nor the held ones scattered. A token's picks are put held ones
+    first; the first ``every`` of them (one more than a router that spreads
+    its picks evenly fills) are gathered for every token. A token's held
+    picks beyond those are added to its sum row by row, in a loop whose
+    length the load sets: one row in forty where the router is even. A row
+    added so costs what seven gathered ones do, so a router that sends this
+    program over twice its even share of the picks is served slower than by
+    a gather of all ``k`` (``tools/expert_combine_forms.py``)."""
+    k, n_tokens = place.shape
+    absent, place, weight = lax.sort((absent, place, weight), dimension=0,
+                                     num_keys=1, is_stable=True)
+    rows = jnp.where(absent[:every, :, None], 0, results[place[:every]])
+    total = (rows.astype(jnp.float32) * weight[:every, :, None]).sum(axis=0)
+    if every == k:
+        return total
+    rest = ~absent[every:].reshape(-1)
+    n_rest = jnp.sum(rest, dtype=jnp.int32)
+    first = jnp.pad(jnp.argsort(~rest, stable=True),
+                    (0, -rest.shape[0] % _REST_ROWS))
+    rest_place = place[every:].reshape(-1)
+    rest_weight = weight[every:].reshape(-1)
+
+    def some_rows(i, total):
+        at = lax.dynamic_slice(first, (i * _REST_ROWS,), (_REST_ROWS,))
+        held_pick = i * _REST_ROWS + jnp.arange(_REST_ROWS) < n_rest
+        rows = jnp.where(held_pick[:, None], results[rest_place[at]], 0)
+        return total.at[jnp.where(held_pick, at % n_tokens, n_tokens)].add(
+            rows.astype(jnp.float32) * rest_weight[at][:, None], mode="drop")
+
+    return lax.fori_loop(0, (n_rest + _REST_ROWS - 1) // _REST_ROWS,
+                         some_rows, total)

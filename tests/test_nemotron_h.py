@@ -105,32 +105,27 @@ def _one_node_model(op_type, inputs, attrs, initializers=None, domain="",
         graph, opset=23, domains={domain: 1} if domain else None))
 
 
-@pytest.mark.parametrize("first,chunk", [(None, None), (None, 8), (0, None),
-                                         (2, 8), (4, None), (6, 8)])
-def test_expert_shares_add_up_to_the_uncut_layer(first, chunk, monkeypatch):
-    """Four ``ExpertFFN`` shares of two experts each, summed, plus the shared
-    expert counted once, are the reference's uncut layer; each share alone is
-    the reference's share. With ``chunk`` the 60 sorted pairs take several
-    chunks of 8, as 393,216 take several of 24,576 on the chip."""
-    import jax
+def _small_chunks(monkeypatch, chunk):
+    """``_PAIR_CHUNK`` of ``chunk`` sorted pairs (None: one chunk holds
+    them all), and no program of an earlier test to borrow."""
+    import weakref
+
+    from synapseml_tpu.onnx import importer, ops
+
+    if chunk:
+        monkeypatch.setattr(ops, "_PAIR_CHUNK", chunk)
+    # a live model of the same graph would lend its program
+    monkeypatch.setattr(importer, "_PROGRAMS", weakref.WeakValueDictionary())
+
+
+def _routed_layer(seed=11, shape=(3, 10), h=32, f=48, experts=8, k=2):
+    """A sparse-expert layer's input and weights, and its router's picks."""
     import jax.numpy as jnp
 
     from benchmark.reference import nemotron_h as ref
-    from synapseml_tpu.onnx import ops
 
-    if chunk:
-        import weakref
-
-        from synapseml_tpu.onnx import importer
-
-        monkeypatch.setattr(ops, "_PAIR_CHUNK", chunk)
-        # a live model of the same graph would lend its one-chunk program
-        monkeypatch.setattr(importer, "_PROGRAMS",
-                            weakref.WeakValueDictionary())
-
-    rng = np.random.default_rng(11)
-    h, f, experts, k = 32, 48, 8, 2
-    u = rng.standard_normal((3, 10, h), dtype=np.float32)
+    rng = np.random.default_rng(seed)
+    u = rng.standard_normal((*shape, h), dtype=np.float32)
     w = {"router_w": rng.standard_normal((h, experts), dtype=np.float32),
          "router_bias": rng.normal(0, 0.01, experts).astype(np.float32),
          "experts_up": rng.normal(0, h ** -0.5, (experts, h, f)
@@ -141,40 +136,244 @@ def test_expert_shares_add_up_to_the_uncut_layer(first, chunk, monkeypatch):
                                        ).astype(np.float32),
          "moe_shared_down_w": rng.normal(0, f ** -0.5, (2 * f, h)
                                          ).astype(np.float32)}
+    picks, weights = ref.route(jnp.asarray(u),
+                               {n: jnp.asarray(v) for n, v in w.items()},
+                               k, 2.5, "float32")
+    return u, w, {"x": u, "index": np.asarray(picks, np.int64),
+                  "weight": np.asarray(weights)}
+
+
+def _share_function(feeds, w, lo, held, policy="float32"):
+    """The one-node program of experts ``lo .. lo + held - 1``."""
+    model = _one_node_model(
+        "ExpertFFN", feeds,
+        dict(first_expert=lo, num_experts=w["experts_up"].shape[0],
+             activation="relu2"),
+        {"up": w["experts_up"][lo:lo + held],
+         "down": w["experts_down"][lo:lo + held]}, domain="synapseml_tpu")
+    return OnnxFunction(model, dtype_policy=policy)
+
+
+def _share(feeds, w, lo, held, policy="float32"):
+    import jax
+
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(_share_function(feeds, w, lo, held, policy)(
+            feeds)["y0"])
+
+
+def _share_by_hand(feeds, w, lo, held):
+    """``sum over a token's picks of a held expert e of weight x relu(x
+    U_e)² D_e``, pair by pair in float64."""
+    x = feeds["x"].astype(np.float64)
+    out = np.zeros_like(x)
+    for at in np.ndindex(*feeds["index"].shape):
+        e = int(feeds["index"][at])
+        if lo <= e < lo + held:
+            hidden = np.maximum(x[at[:-1]] @ w["experts_up"][e].astype(
+                np.float64), 0) ** 2
+            out[at[:-1]] += feeds["weight"][at] * (
+                hidden @ w["experts_down"][e].astype(np.float64))
+    return out
+
+
+def _reference_share(u, w, lo, held, shared, k=2):
+    import jax.numpy as jnp
+
+    from benchmark.reference import nemotron_h as ref
+
     wj = {name: jnp.asarray(v) for name, v in w.items()}
-    picks, weights = ref.route(jnp.asarray(u), wj, k, 2.5, "float32")
-    feeds = {"x": u, "index": np.asarray(picks, np.int64),
-             "weight": np.asarray(weights)}
+    part = dict(wj, experts_up=wj["experts_up"][lo:lo + held],
+                experts_down=wj["experts_down"][lo:lo + held])
+    return np.asarray(ref.expert_mixer(
+        jnp.asarray(u), part, top_k=k, scaling=2.5, first_expert=lo,
+        precision="float32", shared=shared))
 
-    def share(lo, held):
-        model = _one_node_model(
-            "ExpertFFN", feeds, dict(first_expert=lo, num_experts=experts,
-                                     activation="relu2"),
-            {"up": w["experts_up"][lo:lo + held],
-             "down": w["experts_down"][lo:lo + held]},
-            domain="synapseml_tpu")
-        with jax.default_matmul_precision("highest"):
-            return np.asarray(OnnxFunction(model)(feeds)["y0"])
 
-    def reference(lo, held, shared):
-        part = dict(wj, experts_up=wj["experts_up"][lo:lo + held],
-                    experts_down=wj["experts_down"][lo:lo + held])
-        return np.asarray(ref.expert_mixer(
-            jnp.asarray(u), part, top_k=k, scaling=2.5, first_expert=lo,
-            precision="float32", shared=shared))
-
+@pytest.mark.parametrize("first,chunk", [(None, None), (None, 8), (0, None),
+                                         (2, 8), (4, None), (6, 8)])
+def test_expert_shares_add_up_to_the_uncut_layer(first, chunk, monkeypatch):
+    """Four ``ExpertFFN`` shares of two experts each, summed, plus the shared
+    expert counted once, are the reference's uncut layer; each share alone is
+    the reference's share. With ``chunk`` the 60 sorted pairs take several
+    chunks of 8, as 393,216 take several of 24,576 on the chip."""
+    _small_chunks(monkeypatch, chunk)
+    u, w, feeds = _routed_layer()
+    experts = 8
     if first is not None:
-        got, want = share(first, 2), reference(first, 2, shared=False)
+        got = _share(feeds, w, first, 2)
+        want = _reference_share(u, w, first, 2, shared=False)
         assert np.abs(want).max() > 0.1  # the share is not empty
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
         return
-    routed = sum(share(lo, 2) for lo in (0, 2, 4, 6))
-    np.testing.assert_allclose(routed, reference(0, experts, shared=False),
+    routed = sum(_share(feeds, w, lo, 2) for lo in (0, 2, 4, 6))
+    uncut = _reference_share(u, w, 0, experts, shared=False)
+    np.testing.assert_allclose(routed, uncut, rtol=2e-5, atol=2e-5)
+    with_shared = _reference_share(u, w, 0, experts, shared=True)
+    np.testing.assert_allclose(routed + (with_shared - uncut), with_shared,
                                rtol=2e-5, atol=2e-5)
-    shared_once = reference(0, experts, True) - reference(0, experts, False)
-    np.testing.assert_allclose(routed + shared_once,
-                               reference(0, experts, shared=True),
+
+
+@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("load", ["nobody_picked", "all_held"])
+def test_expert_share_at_the_ends_of_its_load(load, chunk, monkeypatch):
+    """A share no token picked gives exact zeros (its loop runs no chunk);
+    a share that holds every expert of its router (every pair is held, the
+    loop runs every chunk) is the reference's uncut routed layer."""
+    _small_chunks(monkeypatch, chunk)
+    u, w, feeds = _routed_layer()
+    if load == "all_held":
+        np.testing.assert_allclose(
+            _share(feeds, w, 0, 8), _reference_share(u, w, 0, 8, False),
+            rtol=2e-5, atol=2e-5)
+        return
+    feeds = dict(feeds, index=feeds["index"] % 6)  # experts 0-5 alone
+    got = _share(feeds, w, 6, 2)
+    assert got.shape == u.shape and not got.any()
+
+
+def _boundary_case():
+    """Twelve tokens, top-3 of eight experts, experts 2-5 held, chunks of 8:
+    every token picks expert 2, so its group of 12 crosses the first chunk
+    boundary; token 0's picks (2, 3, 4) are all held and their rows lie in
+    chunks 0, 1 and 2; tokens 8-11 pick nothing else that is held."""
+    u, w, feeds = _routed_layer(seed=7, shape=(1, 12), k=3)
+    index = np.array([[2, 3, 4]] + [[2, 3, 7]] * 3 + [[2, 5, 0]] * 4
+                     + [[2, 1, 6]] * 4, np.int64)
+    return w, dict(feeds, index=index[None])
+
+
+def test_expert_chunks_may_cut_a_group_and_a_tokens_picks(monkeypatch):
+    _small_chunks(monkeypatch, 8)
+    w, feeds = _boundary_case()
+    got = _share(feeds, w, 2, 4)
+    np.testing.assert_allclose(got, _share_by_hand(feeds, w, 2, 4),
                                rtol=2e-5, atol=2e-5)
+    assert np.abs(got[0, 8:]).min() > 1e-3  # one held pick is still an answer
+
+
+def test_expert_rows_past_a_chunks_last_group_never_reach_the_sum(monkeypatch):
+    """The chip's grouped kernel leaves NaN in the rows past the last group
+    (PERF.md section 5); ``lax.ragged_dot`` on the CPU leaves zeros. With NaN
+    put there the answer is finite and the same, bit for bit: those rows are
+    selected away, never multiplied."""
+    import jax.numpy as jnp
+
+    from synapseml_tpu.onnx import ops
+
+    w, feeds = _boundary_case()
+    _small_chunks(monkeypatch, 8)
+    want = _share(feeds, w, 2, 4)
+    plain = ops._grouped_product
+
+    def nan_past_the_groups(lhs, rhs, sizes):
+        out = plain(lhs, rhs, sizes)
+        past = jnp.arange(out.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(ops, "_grouped_product", nan_past_the_groups)
+    _small_chunks(monkeypatch, 8)
+    got = _share(feeds, w, 2, 4)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+
+
+def test_expert_answers_repeat_bit_for_bit(monkeypatch):
+    """The order of the float32 sum over a token's picks is the program's
+    own, and the same from call to call and from program to program
+    (``correct``'s ``repeat_mismatch`` has limit 0)."""
+    _small_chunks(monkeypatch, 8)
+    u, w, feeds = _routed_layer(k=4)
+    first = _share_function(feeds, w, 0, 8)
+    a, b = (np.asarray(first(feeds)["y0"]) for _ in range(2))
+    _small_chunks(monkeypatch, 8)  # a program of its own
+    second = _share_function(feeds, w, 0, 8)
+    assert second._jit is not first._jit
+    c = np.asarray(second(feeds)["y0"])
+    assert a.tobytes() == b.tobytes() == c.tobytes() and a.any()
+
+
+def _lopsided_case(held_counts):
+    """41 tokens, top-6 of sixteen experts, experts 4-7 held: a router that
+    spreads its picks evenly fills 1.5 of a token's picks here, so the first
+    3 held picks are gathered for every token; ``held_counts[t]`` of token
+    ``t``'s picks are held, at places of their own among its six."""
+    u, w, feeds = _routed_layer(seed=13, shape=(1, 41), experts=16, k=6)
+    rng = np.random.default_rng(5)
+    index = np.empty((1, 41, 6), np.int64)
+    for t, n_held in enumerate(held_counts):
+        picks = np.concatenate([
+            rng.permutation([4, 5, 6, 7])[:n_held],
+            rng.permutation([0, 1, 2, 3, 8, 9, 10, 11, 12, 13, 14, 15]
+                            )[:6 - n_held]])
+        index[0, t] = rng.permutation(picks)
+    return w, dict(feeds, index=index)
+
+
+@pytest.mark.parametrize("rest", ["none", "few", "many"])
+def test_expert_held_picks_beyond_those_gathered_for_every_token(
+        rest, monkeypatch):
+    """A token's held picks past the first three are added row by row, in as
+    many goes of 2 rows as the load asks for: none, two (3 such picks) or 21
+    (a fourth pick of all 41 tokens)."""
+    from synapseml_tpu.onnx import ops
+
+    _small_chunks(monkeypatch, 8)
+    monkeypatch.setattr(ops, "_REST_ROWS", 2)
+    counts = np.random.default_rng(3).integers(0, 4, 41)
+    if rest == "few":
+        counts[[2, 17, 40]] = 4
+    elif rest == "many":
+        counts[:] = 4
+    w, feeds = _lopsided_case(counts)
+    got = _share(feeds, w, 4, 4)
+    np.testing.assert_allclose(got, _share_by_hand(feeds, w, 4, 4),
+                               rtol=2e-5, atol=2e-5)
+    assert np.abs(got[0, counts > 0]).min() > 1e-4
+    assert not got[0, counts == 0].any()
+
+
+@pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+def test_expert_program_gathers_a_tokens_first_held_picks_only(
+        policy, monkeypatch):
+    """41 tokens x top-6 = 246 pairs, h = 32, a quarter of the experts held:
+    the lowered program gathers 3 rows a token, not 6, into one float32
+    ``[41, 32]`` sum; nothing in it has 246 rows of 32 (the sorted pairs'
+    buffer has its padding's 248)."""
+    import re
+
+    import jax
+
+    _small_chunks(monkeypatch, 8)
+    w, feeds = _lopsided_case(np.full(41, 2))
+    fn = _share_function(feeds, w, 4, 4, policy)
+    text = jax.jit(fn._run_positional).lower(
+        *(feeds[name] for name in fn.input_names)).as_text(dialect="hlo")
+    shapes = set(re.findall(r"\b[a-z]+[0-9]+\[[0-9,]*\]", text))
+    rows = "bf16" if policy == "bfloat16" else "f32"
+    assert {"f32[41,32]", rows + "[3,41,32]", rows + "[248,32]"} <= shapes
+    assert not [s for s in shapes
+                if re.search(r"\[(6,41|246),32\]", s)], sorted(shapes)
+
+
+def test_expert_sum_over_picks_is_float32_rounded_once(monkeypatch):
+    """Under the bfloat16 policy rows leave the grouped product in bfloat16;
+    the weights, the sum over a token's picks and its one rounding are
+    float32's. Against the pair-by-pair float64 sum of the same bfloat16
+    operands: 0.00436 read here, 0.00436 by the gather of every pair before
+    it (bfloat16's 2^-9 through two products and one rounding of the
+    sum)."""
+    import jax.numpy as jnp
+
+    _small_chunks(monkeypatch, 8)
+    u, w, feeds = _routed_layer(k=4)
+    bf16 = lambda a: np.asarray(  # noqa: E731
+        jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+    rounded = {name: bf16(v) for name, v in w.items()}
+    want = _share_by_hand(dict(feeds, x=bf16(feeds["x"])), rounded, 0, 8)
+    got = _share(feeds, w, 0, 8, policy="bfloat16")
+    assert got.dtype == np.float32
+    assert _relative(got, want) < 0.006
 
 
 def test_expert_ffn_refuses_what_it_does_not_do():
@@ -262,19 +461,23 @@ def test_rms_normalization_reduces_in_float32():
                           + 1e-5) * scale)) < 8e-3
 
 
-def test_the_trace_says_how_attention_was_lowered_and_what_experts_hold():
+def test_the_trace_says_how_attention_was_lowered_and_what_experts_hold(
+        monkeypatch):
     from synapseml_tpu.observability.metrics import get_registry
 
+    _small_chunks(monkeypatch, None)  # a program of its own: it is traced
     fn = OnnxFunction(zoo.build_model_bytes("NemotronHTiny", seed=5),
                       dtype_policy="bfloat16")
-    fn({"input_ids": _ids(3, 16)})
-    families = get_registry().snapshot()["families"]
 
     def series(name):
-        family = families[name]
-        return {tuple(s["labels"]): s["value"] for s in family["series"]
+        family = get_registry().snapshot()["families"].get(name) or {}
+        return {tuple(s["labels"]): s["value"]
+                for s in family.get("series", [])
                 if s["labels"][0] == fn._jit.name}
 
+    held_first = (fn._jit.name, "held_first")
+    before = series("smt_onnx_expert_combine_total").get(held_first, 0)
+    fn({"input_ids": _ids(3, 16)})
     # the CPU has no Pallas kernel: the dense form, and the counter says so
     lowered = series("smt_onnx_attention_lowering_total")
     assert lowered.get((fn._jit.name, "dense"), 0) >= 1
@@ -284,6 +487,11 @@ def test_the_trace_says_how_attention_was_lowered_and_what_experts_hold():
     assert series("smt_onnx_experts_held")[(fn._jit.name,)] == 2 * 8
     placed = series("smt_onnx_weight_argument_bytes")[(fn._jit.name,)]
     assert placed == sum(w.nbytes for w in fn._weights) > 0
+    # once a traced program, one count an ExpertFFN node, in the one form
+    combine = series("smt_onnx_expert_combine_total")
+    assert combine == {held_first: before + 2}
+    fn({"input_ids": _ids(3, 16, seed=1)})
+    assert series("smt_onnx_expert_combine_total") == combine
 
 
 @pytest.mark.parametrize("builder", ["BERTTiny", "NemotronHTiny"])
