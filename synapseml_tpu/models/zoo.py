@@ -10,7 +10,9 @@ validation (weights are obviously not the pretrained ones; load real weights via
 
 Models: ResNet-18/50 (v1.5 bottleneck), a BERT-base-style encoder, ViT-B/16, and one
 chip's share of the hybrid Mamba-2 / sparse-expert / grouped-query decoder
-``nemotron_h`` (``models/nemotron_h.py``: weights as BFLOAT16 initializers).
+``nemotron_h`` (``models/nemotron_h.py``: weights as BFLOAT16 initializers), and a
+pipeline stage of the block-diffusion sparse-expert decoder ``sdar_moe``, generation
+included (``models/sdar_moe.py``: a ``Loop`` over blocks carrying a key-value cache).
 All emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
 can "cut" the head exactly like the reference's ``cutOutputLayers``
 (``ImageFeaturizer.scala:40-197``).
@@ -289,6 +291,8 @@ MODEL_BUILDERS = {
     "ViTB16": lambda **kw: vit(**kw),
     "NemotronH": lambda **kw: _nemotron_h(**kw),
     "NemotronHTiny": lambda **kw: _nemotron_h(**{**NEMOTRON_H_TINY, **kw}),
+    "SDARMoE": lambda **kw: _sdar_moe(**kw),
+    "SDARMoETiny": lambda **kw: _sdar_moe(**{**SDAR_MOE_TINY, **kw}),
 }
 
 # widths of the CPU tests' nemotron_h: every mechanism of the full graph
@@ -303,6 +307,20 @@ def _nemotron_h(**kw) -> ModelProto:
     from .nemotron_h import nemotron_h
 
     return nemotron_h(**kw)
+
+
+# widths of the CPU tests' sdar_moe: every mechanism of the full graph (two
+# key-value groups, a router wider than its top-k, two blocks, two passes)
+SDAR_MOE_TINY = dict(
+    layers=2, hidden=64, vocab=256, heads=4, kv_heads=2, head_dim=16,
+    experts=8, top_k=2, expert_width=32, generate=8, block=4, passes=2,
+    mask_id=255)
+
+
+def _sdar_moe(**kw) -> ModelProto:
+    from .sdar_moe import sdar_moe
+
+    return sdar_moe(**kw)
 
 
 def build_model_bytes(name: str, **kw) -> bytes:

@@ -554,18 +554,21 @@ class OnnxFunction:
 
     def _record_notes(self, notes: Dict[str, int]) -> None:
         """Once a traced program: how its ``Attention`` and ``Gelu`` nodes
-        were lowered, what its ``ExpertFFN`` nodes are sized for and how
-        they combine."""
+        were lowered, what its ``ExpertFFN`` nodes are sized for, their form
+        and how they combine, how often its ``Loop`` bodies run and what
+        they carry."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
         lowering = reg.counter(
             "smt_onnx_attention_lowering_total",
             "Attention nodes of a traced program by lowering: flash (the "
-            "Pallas kernel, scores never written) or dense (materialised "
-            "scores: not a TPU, or lengths that do not tile)",
+            "Pallas kernel, scores never written), dense (materialised "
+            "scores where the kernel could have served: not a TPU, or "
+            "lengths that do not tile) or masked (a mask only the run "
+            "knows: the grouped dense form is the lowering)",
             ("fn", "kind"))
-        for kind in ("flash", "dense"):
+        for kind in ("flash", "dense", "masked"):
             if "attention_" + kind in notes:
                 lowering.labels(fn, kind).inc(notes["attention_" + kind])
         gelu = reg.counter(
@@ -584,9 +587,30 @@ class OnnxFunction:
             "the first few gathered for every token, the few beyond those "
             "added row by row)",
             ("fn", "form"))
+        form = reg.counter(
+            "smt_onnx_expert_form_total",
+            "ExpertFFN nodes of a traced program by activation: relu2 (one "
+            "up-projection) or swiglu (a gate and an up-projection)",
+            ("fn", "form"))
+        trips = reg.gauge(
+            "smt_onnx_loop_trips",
+            "times a call of the newest traced program runs the body of "
+            "each Loop node, outer loops multiplied in",
+            ("fn", "loop"), merge="max")
         for key, count in notes.items():
             if key.startswith("expert_combine_"):
                 combine.labels(fn, key[len("expert_combine_"):]).inc(count)
+            elif key.startswith("expert_form_"):
+                form.labels(fn, key[len("expert_form_"):]).inc(count)
+            elif key.startswith("loop_trips."):
+                trips.labels(fn, key[len("loop_trips."):]).set(count)
+        if "loop_state_bytes" in notes:
+            reg.gauge(
+                "smt_onnx_loop_state_bytes",
+                "bytes the outermost Loop nodes of the newest traced "
+                "program carry from trip to trip (a key-value cache)",
+                ("fn",), merge="max").labels(fn).set(
+                    notes["loop_state_bytes"])
         if "expert_pairs" in notes:
             reg.gauge(
                 "smt_onnx_expert_pairs",
@@ -673,8 +697,12 @@ class OnnxFunction:
                   if handoff is not None else set())
 
         def subgraph_runner(sub: GraphProto):
-            def run():
+            """The subgraph as a function of its own inputs (``Loop``'s body;
+            ``If``'s branches have none); every other name it reads is this
+            graph's, weights among them."""
+            def run(*values):
                 sub_env = dict(env)
+                sub_env.update(zip((vi.name for vi in sub.input), values))
                 self._run_graph(sub, sub_env, opset=opset, handoff=handoff,
                                 notes=notes)
                 vals = [sub_env[o.name] for o in sub.output]
@@ -708,6 +736,7 @@ class OnnxFunction:
             inputs = [env[i] if i else None for i in node.input]
             ctx = {
                 "op_type": node.op_type,
+                "node_name": node.name,
                 "opset": opset,
                 "n_outputs": len(node.output),
                 "accum_dtype": accum,

@@ -1154,6 +1154,71 @@ def _if(inputs, attrs, ctx):
     )
 
 
+@op("Loop")
+def _loop(inputs, attrs, ctx):
+    """``Loop`` with a trip count known at trace time and loop-carried values
+    of fixed shape and type: one ``lax.fori_loop`` whose state is the carried
+    values, so a buffer the body updates at computed positions (a key-value
+    cache) stays one buffer. The body reads the enclosing graph's names
+    (weights stay the program's arguments). Anything else raises by name: a
+    trip count or a condition computed at run time, scan outputs, a carried
+    value whose shape or type the body changes."""
+    trips, cond, carried = inputs[0], inputs[1], list(inputs[2:])
+    body, name = attrs["body"], ctx.get("node_name") or "loop"
+    if trips is None or not _is_trace_constant(trips):
+        raise NotImplementedError(
+            f"Loop {name}: the trip count M must be a constant of the graph")
+    if cond is not None and not (_is_trace_constant(cond) and bool(cond)):
+        raise NotImplementedError(
+            f"Loop {name}: a condition that is not the constant true")
+    if len(body.input) != 2 + len(carried):
+        raise ValueError(
+            f"Loop {name}: {len(carried)} carried values for a body of "
+            f"{len(body.input)} inputs")
+    if len(body.output) != 1 + len(carried) \
+            or ctx["n_outputs"] != len(carried):
+        raise NotImplementedError(
+            f"Loop {name}: scan outputs ({len(body.output) - 1} body outputs "
+            f"for {len(carried)} carried values)")
+    trips = int(np.asarray(trips).reshape(()))
+    run = ctx["subgraph_runner"](body)
+    state = tuple(jnp.asarray(v) for v in carried)
+    notes = ctx.get("notes")
+    if notes is None:
+        notes = {}
+    outer = notes.get("loop_factor", 1)
+    notes["loop_trips." + name] = outer * trips
+    if outer == 1:  # an inner loop's state is part of its outer loop's
+        _note(ctx, "loop_state_bytes",
+              sum(v.size * v.dtype.itemsize for v in state))
+
+    def one_trip(i, state):
+        outs = run(i, np.bool_(True), *state)
+        outs = outs if isinstance(outs, tuple) else (outs,)
+        if not (_is_trace_constant(outs[0]) and bool(outs[0])):
+            raise NotImplementedError(
+                f"Loop {name}: the body computes its condition")
+        for vi, was, now in zip(body.input[2:], state, outs[1:]):
+            if jnp.shape(now) != was.shape \
+                    or jnp.result_type(now) != was.dtype:
+                raise ValueError(
+                    f"Loop {name}: carried value {vi.name!r} enters as "
+                    f"{was.dtype}{list(was.shape)} and leaves as "
+                    f"{jnp.result_type(now)}{list(jnp.shape(now))}")
+        return tuple(jnp.asarray(v) for v in outs[1:])
+
+    notes["loop_factor"] = outer * trips
+    try:
+        state = lax.fori_loop(0, trips, one_trip, state)
+    finally:
+        notes["loop_factor"] = outer
+    return state if len(state) != 1 else state[0]
+
+
+def _is_trace_constant(v) -> bool:
+    return isinstance(v, (np.ndarray, np.generic, bool, int))
+
+
 # ---------------------------------------------------------------------------------
 # recurrent (LSTM / GRU)
 # ---------------------------------------------------------------------------------
@@ -1272,29 +1337,139 @@ def _note(ctx, key: str, amount: int = 1) -> None:
         notes[key] = notes.get(key, 0) + amount
 
 
+@op("RotaryEmbedding")
+def _rotary_embedding(inputs, attrs, ctx):
+    """Opset 23 ``RotaryEmbedding``: ``X`` as ``[batch, seq, heads * size]``
+    (with ``num_heads``) or ``[batch, heads, seq, size]``; ``cos_cache`` and
+    ``sin_cache`` ``[positions, rot / 2]`` read at ``position_ids [batch,
+    seq]``, or ``[batch, seq, rot / 2]`` without them; ``interleaved`` 0
+    rotates halves, 1 rotates neighbours; ``rotary_embedding_dim`` rotates
+    the first ``rot`` of a head's size and passes the rest. Float32 inside,
+    the result in ``X``'s type."""
+    x, cos, sin = inputs[:3]
+    position_ids = inputs[3] if len(inputs) > 3 else None
+    rank = x.ndim
+    if rank == 3:
+        heads = int(attrs.get("num_heads", 0))
+        if heads <= 0:
+            raise ValueError("RotaryEmbedding: a rank-3 input needs num_heads")
+        x4 = x.reshape(*x.shape[:2], heads, -1)
+    elif rank == 4:  # [batch, heads, seq, size] -> [batch, seq, heads, size]
+        x4 = jnp.transpose(x, (0, 2, 1, 3))
+    else:
+        raise ValueError(f"RotaryEmbedding: rank {rank} input")
+    rot = int(attrs.get("rotary_embedding_dim", 0)) or x4.shape[-1]
+    if position_ids is not None:
+        if isinstance(position_ids, np.ndarray) \
+                and (position_ids == position_ids[:1]).all():
+            # every row at the same positions: one row of angles, broadcast
+            position_ids = position_ids[:1]
+        gather = np.take if all(isinstance(v, np.ndarray) for v in
+                                (cos, sin, position_ids)) else jnp.take
+        cos, sin = (gather(c, position_ids, axis=0) for c in (cos, sin))
+    if cos.shape[-1] * 2 != rot:
+        raise ValueError(f"RotaryEmbedding: caches of {cos.shape[-1]} "
+                         f"angles for a rotation over {rot}")
+    cos, sin = (jnp.asarray(c, jnp.float32)[:, :, None, :] for c in (cos, sin))
+    turned = x4[..., :rot].astype(jnp.float32)
+    if attrs.get("interleaved", 0):
+        x1, x2 = turned[..., 0::2], turned[..., 1::2]
+    else:
+        x1, x2 = jnp.split(turned, 2, axis=-1)
+    real, imag = cos * x1 - sin * x2, sin * x1 + cos * x2
+    if attrs.get("interleaved", 0):
+        turned = jnp.stack([real, imag], axis=-1).reshape(turned.shape)
+    else:
+        turned = jnp.concatenate([real, imag], axis=-1)
+    out = jnp.concatenate([turned.astype(x.dtype), x4[..., rot:]], axis=-1) \
+        if rot != x4.shape[-1] else turned.astype(x.dtype)
+    return out.reshape(x.shape) if rank == 3 \
+        else jnp.transpose(out, (0, 2, 1, 3))
+
+
+@op("TensorScatter")
+def _tensor_scatter(inputs, attrs, ctx):
+    """Opset 24 ``TensorScatter`` (``mode`` linear): ``update`` written into
+    ``past_cache`` along ``axis`` from ``write_indices[batch]`` on, the way a
+    key-value cache takes new positions. On a loop-carried cache the write is
+    in place."""
+    cache, update = jnp.asarray(inputs[0]), jnp.asarray(inputs[1])
+    write = inputs[2] if len(inputs) > 2 else None
+    if attrs.get("mode", "linear") != "linear":
+        raise NotImplementedError(
+            f"TensorScatter: mode {attrs.get('mode')!r}; only linear")
+    axis = int(attrs.get("axis", -2)) % cache.ndim
+    if axis == 0:
+        raise ValueError("TensorScatter: axis 0 is the batch axis")
+    update = update.astype(cache.dtype)
+    write = jnp.zeros(cache.shape[0], jnp.int32) if write is None \
+        else jnp.asarray(write, jnp.int32)
+    # rows that all start at one position (a batch generating in step) take
+    # one slice of the buffer; rows that differ, a slice each. Where the
+    # starts are a constant or a broadcast scalar the compiler folds the
+    # choice away
+    return lax.cond(
+        jnp.all(write == write[0]),
+        lambda: lax.dynamic_update_slice_in_dim(cache, update, write[0],
+                                                axis),
+        lambda: jax.vmap(
+            lambda c, u, at: lax.dynamic_update_slice_in_dim(c, u, at,
+                                                             axis - 1)
+        )(cache, update, write))
+
+
+def _causal_block(mask: np.ndarray, s_q: int, s_k: int):
+    """What a constant boolean mask says, if it is one the kernel has: 0 for
+    "every key visible", ``B`` for causal at a granularity of ``B`` positions
+    (``B`` a power of two; key ``j`` visible to query ``i`` where ``j <= (i +
+    s_k - s_q) | (B - 1)``; 1 is the plain causal mask), None for any other."""
+    if mask.shape[-2:] != (s_q, s_k) or mask.size != s_q * s_k:
+        return None
+    mask = mask.reshape(s_q, s_k)
+    if mask.all():
+        return 0
+    off = s_k - s_q
+    block = int(mask[0].sum()) - off
+    if block < 1 or block & (block - 1) or off % block or s_q % block:
+        return None
+    q_pos = np.arange(s_q)[:, None] + off
+    same = np.array_equal(mask, np.arange(s_k)[None, :] <= (q_pos | (block - 1)))
+    return block if same else None
+
+
 @op("Attention")
 def _attention(inputs, attrs, ctx):
-    """Opset 23 ``Attention`` as far as a stateless scorer needs it: Q, K, V
-    as ``[batch, seq, heads * size]`` (with ``q_num_heads`` /
-    ``kv_num_heads``) or ``[batch, heads, seq, size]``, grouped-query (the
-    key-value heads divide the query heads), ``is_causal``, ``scale``. No
-    mask, past, softcap, second output or ``softmax_precision``: they raise.
+    """Opset 23 ``Attention`` as far as a stateless scorer and a generating
+    loop need it: Q, K, V as ``[batch, seq, heads * size]`` (with
+    ``q_num_heads`` / ``kv_num_heads``) or ``[batch, heads, seq, size]``,
+    grouped-query (the key-value heads divide the query heads), ``is_causal``,
+    ``scale``, and a BOOLEAN ``attn_mask`` broadcastable to ``[batch, heads,
+    q, kv]``. No float mask, past, softcap, second output or
+    ``softmax_precision``: they raise.
 
     The scores are never written where ``parallel.flash.flash_attention``
-    runs its kernel (a TPU, sequence lengths that tile); elsewhere the dense
-    form runs and the program's ``attention_dense`` note counts it."""
+    runs its kernel (a TPU, sequence lengths that tile) and the mask is one
+    the kernel has: none, causal, or a constant that reads as causal at a
+    granularity of a block of positions. Where the kernel could have served
+    and did not, the dense form runs and the program's ``attention_dense``
+    note counts it. Under any other mask (one computed at run time: a few
+    queries against a cache filled so far) the grouped dense form is the
+    lowering, counted as ``attention_masked``."""
     from ..parallel import flash
 
     q, k, v = inputs[:3]
-    given = [n for n, x in zip(("attn_mask", "past_key", "past_value"),
-                               inputs[3:]) if x is not None]
+    mask = inputs[3] if len(inputs) > 3 else None
+    given = [n for n, x in zip(("past_key", "past_value"), inputs[4:])
+             if x is not None]
     given += [n for n in ("qk_matmul_output_mode", "softcap",
                           "softmax_precision") if attrs.get(n)]
+    if mask is not None and mask.dtype != np.bool_:
+        given.append(f"attn_mask of type {mask.dtype}")
     if given or ctx["n_outputs"] > 1:
         raise NotImplementedError(
             f"Attention: unsupported {given or 'outputs beyond Y'}; this "
-            f"lowering takes Q, K, V, is_causal, scale, q_num_heads and "
-            f"kv_num_heads")
+            f"lowering takes Q, K, V, a boolean attn_mask, is_causal, scale, "
+            f"q_num_heads and kv_num_heads")
     rank = q.ndim
     if rank == 3:
         n_q, n_kv = int(attrs["q_num_heads"]), int(attrs["kv_num_heads"])
@@ -1306,15 +1481,25 @@ def _attention(inputs, attrs, ctx):
     s_k, h_kv = k.shape[1], k.shape[2]
     if attrs.get("scale") is not None:  # the kernel's own scale is 1/sqrt(d)
         q = q * jnp.asarray(attrs["scale"] * np.sqrt(d), q.dtype)
-    causal = bool(attrs.get("is_causal", 0))
-    if _kernels_on() and flash.auto_blocks_tile(b * h, s_q, s_k):
+    causal, block = bool(attrs.get("is_causal", 0)), 1
+    if isinstance(mask, np.ndarray) and not causal:
+        # a constant mask may be one of the kernel's own
+        found = _causal_block(mask, s_q, s_k)
+        if found is not None:
+            mask, causal, block = None, found > 0, max(found, 1)
+    if mask is not None:
+        _note(ctx, "attention_masked")
+        out = flash.masked_attention(q, k, v, mask, causal=causal)
+    elif _kernels_on() and flash.auto_blocks_tile(b * h, s_q, s_k, block):
         _note(ctx, "attention_flash")
-        out = flash.flash_attention(q, k, v, causal=causal)
+        out = flash.flash_attention(q, k, v, causal=causal,
+                                    causal_block=block)
     else:
         _note(ctx, "attention_dense")
         if h != h_kv:
             k, v = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
-        out = flash.dense_attention(q, k, v, causal=causal)
+        out = flash.dense_attention(q, k, v, causal=causal,
+                                    causal_block=block)
     if rank == 3:
         return out.reshape(b, s_q, h * d)
     return jnp.transpose(out, (0, 2, 1, 3))
@@ -1366,22 +1551,30 @@ def _tile(size: int, cap: int) -> int:
 
 @op("ExpertFFN")
 def _expert_ffn(inputs, attrs, ctx):
-    """``synapseml_tpu::ExpertFFN(x, topk_index, topk_weight, U, D)``: the
-    part of a sparse-expert layer that THIS program's experts give.
+    """``synapseml_tpu::ExpertFFN(x, topk_index, topk_weight, U, D[, G])``:
+    the part of a sparse-expert layer that THIS program's experts give.
 
     ``U [held, h, f]`` and ``D [held, f, h]`` are experts ``first_expert ..
     first_expert + held - 1`` of a router over ``num_experts``; each token
     carries ``k`` picks (``topk_index [..., k]``) and their weights. The
     result is ``sum over a token's picks of a held expert e of weight *
-    act(x U_e) D_e`` (``activation`` "relu2": ``relu(.)²``); a pick of an
+    act_e(x) D_e``; ``activation`` "relu2": ``act_e(x) = relu(x U_e)²``;
+    "swiglu", with the gate weight ``G [held, h, f]``: ``silu(x G_e) * (x
+    U_e)``, the product taken in float32 and rounded once. A pick of an
     expert held elsewhere adds nothing. No token is dropped and there is no
     capacity: the (token, pick) pairs are sorted by held expert and go
-    through a grouped product, in chunks, as far as the held experts' pairs
+    through grouped products, in chunks, as far as the held experts' pairs
     reach."""
     x, index, weight, up, down = inputs[:5]
-    if attrs.get("activation", "relu2") != "relu2":
+    gate = inputs[5] if len(inputs) > 5 else None
+    activation = attrs.get("activation", "relu2")
+    if activation not in ("relu2", "swiglu"):
         raise NotImplementedError(
-            f"ExpertFFN: activation {attrs.get('activation')!r}; only relu2")
+            f"ExpertFFN: activation {activation!r}; relu2 or swiglu")
+    if (gate is not None) != (activation == "swiglu"):
+        raise ValueError(
+            f"ExpertFFN: activation {activation!r} "
+            f"{'needs' if gate is None else 'takes no'} gate weight (input 6)")
     first, held = int(attrs["first_expert"]), up.shape[0]
     if first < 0 or first + held > int(attrs["num_experts"]):
         raise ValueError(
@@ -1402,9 +1595,12 @@ def _expert_ffn(inputs, attrs, ctx):
     sizes = jnp.sum(group[:, None] == jnp.arange(held, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
     ends = jnp.cumsum(sizes)
-    _note(ctx, "expert_pairs", n_pairs)
+    # a node in a Loop body presents its pairs once a trip
+    _note(ctx, "expert_pairs",
+          n_pairs * (ctx.get("notes") or {}).get("loop_factor", 1))
     _note(ctx, "experts_held", held)
     _note(ctx, "expert_combine_held_first")
+    _note(ctx, "expert_form_" + activation)
 
     # the sorted pairs a chunk at a time, for as many chunks as hold a held
     # expert's pair: the work follows the load (a quarter of the pairs where
@@ -1420,8 +1616,15 @@ def _expert_ffn(inputs, attrs, ctx):
         pairs = lax.dynamic_slice(order_padded, (lo,), (_PAIR_CHUNK,))
         inside = (jnp.clip(ends, lo, lo + _PAIR_CHUNK)
                   - jnp.clip(ends - sizes, lo, lo + _PAIR_CHUNK))
-        hidden = _grouped_product(tokens[pairs % n_tokens], up, inside)
-        out = _grouped_product(jnp.square(jax.nn.relu(hidden)), down, inside)
+        rows = tokens[pairs % n_tokens]
+        hidden = _grouped_product(rows, up, inside)
+        if gate is None:
+            hidden = jnp.square(jax.nn.relu(hidden))
+        else:
+            gated = _grouped_product(rows, gate, inside).astype(jnp.float32)
+            hidden = (jax.nn.silu(gated) * hidden.astype(jnp.float32)
+                      ).astype(x.dtype)
+        out = _grouped_product(hidden, down, inside)
         return lax.dynamic_update_slice(results, out, (lo, 0))
 
     results = lax.fori_loop(

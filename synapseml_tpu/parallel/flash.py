@@ -24,18 +24,20 @@ from __future__ import annotations
 import functools
 import math
 
-__all__ = ["flash_attention", "dense_attention"]
+__all__ = ["flash_attention", "dense_attention", "masked_attention"]
 
 _NEG = -1e30
 
 
-def dense_attention(q, k, v, causal: bool = False, pv_dtype=None):
+def dense_attention(q, k, v, causal: bool = False, pv_dtype=None,
+                    causal_block: int = 1):
     """Reference dense attention, (B, S, H, D) layout, f32 accumulation.
 
     ``pv_dtype`` casts the probabilities for the P@V matmul (e.g. bf16 —
     the performant-XLA baseline bench.py compares flash against; the flash
     kernel makes the same cast). Default keeps everything f32 (the exact
-    parity reference the tests use)."""
+    parity reference the tests use). ``causal_block`` as in
+    :func:`flash_attention`."""
     import jax.numpy as jnp
 
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -43,8 +45,10 @@ def dense_attention(q, k, v, causal: bool = False, pv_dtype=None):
                    k.astype(jnp.float32)) * scale
     if causal:
         S_q, S_k = s.shape[1], s.shape[3]
-        mask = (jnp.arange(S_q)[:, None] + (S_k - S_q)
-                >= jnp.arange(S_k)[None, :])
+        q_pos = jnp.arange(S_q)[:, None] + (S_k - S_q)
+        if causal_block > 1:  # a query sees its whole block of positions
+            q_pos = q_pos | (causal_block - 1)
+        mask = q_pos >= jnp.arange(S_k)[None, :]
         s = jnp.where(mask[None, :, None, :], s, _NEG)
     p = jnp.exp(s - s.max(-1, keepdims=True))
     p = p / p.sum(-1, keepdims=True)
@@ -54,6 +58,38 @@ def dense_attention(q, k, v, causal: bool = False, pv_dtype=None):
     else:
         out = jnp.einsum("bqhk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def masked_attention(q, k, v, mask, causal: bool = False):
+    """Dense attention under a boolean ``mask`` (true: visible) that
+    broadcasts to ``[B, H, S_q, S_k]``; (B, S, H, D) layout. The form for a
+    mask only the run knows, as a few queries against a cache filled so far:
+    grouped key-value heads are read as they lie (never repeated in HBM), the
+    two products run in the inputs' type with float32 accumulation, the
+    softmax in float32; the probabilities take the values' type for the
+    second product, as in the kernel."""
+    import jax.numpy as jnp
+
+    b, s_q, h, d = q.shape
+    s_k, h_kv = k.shape[1], k.shape[2]
+    rep = h // h_kv
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, s_q, h_kv, rep, d), k,
+                   preferred_element_type=jnp.float32) / math.sqrt(d)
+    mask = jnp.asarray(mask)
+    mask = mask.reshape((1,) * (4 - mask.ndim) + mask.shape)
+    if causal:
+        mask = mask & (jnp.arange(s_q)[:, None] + (s_k - s_q)
+                       >= jnp.arange(s_k)[None, :])
+    if mask.shape[1] == 1:
+        mask = mask[:, :, None]
+    else:
+        mask = mask.reshape(mask.shape[0], h_kv, rep, *mask.shape[2:])
+    s = jnp.where(mask, s, _NEG)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s_q, h, d).astype(q.dtype)
 
 
 @functools.lru_cache(maxsize=256)
@@ -100,16 +136,21 @@ def _pick_blocks(bh: int, s_q: int, s_k: int):
     return (_pow2_divisor(s_q, bq_target), _pow2_divisor(s_k, 1024))
 
 
-def auto_blocks_tile(bh: int, s_q: int, s_k: int) -> bool:
+def auto_blocks_tile(bh: int, s_q: int, s_k: int,
+                     causal_block: int = 1) -> bool:
     """Whether the blocks :func:`flash_attention` picks by itself meet
-    Mosaic's (8, 128) tile: it then runs the kernel, else its dense
-    substitute (a caller that has to know which asks here first)."""
+    Mosaic's (8, 128) tile and hold whole blocks of ``causal_block``
+    positions: it then runs the kernel, else its dense substitute (a caller
+    that has to know which asks here first)."""
     block_q, block_k = _pick_blocks(bh, s_q, s_k)
-    return min(block_q, s_q) >= 8 and min(block_k, s_k) >= 128
+    block_q, block_k = min(block_q, s_q), min(block_k, s_k)
+    return (block_q >= 8 and block_k >= 128 and block_q % causal_block == 0
+            and (s_k - s_q) % causal_block == 0)
 
 
 def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
-                    block_k: int = None, interpret: bool = False):
+                    block_k: int = None, interpret: bool = False,
+                    causal_block: int = 1):
     """Blockwise-online-softmax attention as ONE Pallas kernel.
 
     ``q`` (B, S_q, H, D), ``k``/``v`` (B, S_k, H_kv, D) -> (B, S_q, H, D).
@@ -121,7 +162,11 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
     ``causal`` aligns the diagonal to the END of the key sequence (queries
     are the LAST S_q positions), matching decode/ring conventions. Block
     sizes default to :func:`_pick_blocks`; explicit values must divide the
-    sequence lengths.
+    sequence lengths. ``causal_block`` B > 1 (a power of two that divides
+    ``block_q`` and ``S_k - S_q``) makes the causal mask one of blocks of B
+    positions: a query sees every earlier block and all of its own, ``kpos
+    <= qpos | (B - 1)``. Only the diagonal tiles' comparison changes; which
+    tiles are skipped does not, since B divides the tiles.
 
     This is the long-sequence path: dense attention at S=32k would need
     ~34 GB for the score tensor alone.
@@ -144,6 +189,9 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
         # (and silently all-zero outputs)
         raise ValueError(f"causal flash attention needs s_q <= s_k, got "
                          f"s_q={s_q} > s_k={s_k}")
+    causal_block = int(causal_block) if causal else 1
+    if causal_block < 1 or causal_block & (causal_block - 1):
+        raise ValueError(f"causal_block {causal_block} is not a power of two")
     req_q, req_k = block_q, block_k  # the USER's values, pre-clamp
     auto_bq, auto_bk = _pick_blocks(b * h, s_q, s_k)
     block_q = min(block_q or auto_bq, s_q)  # each side auto-fills alone
@@ -151,6 +199,10 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
     if s_q % block_q or s_k % block_k:
         raise ValueError(f"block sizes ({block_q}, {block_k}) must divide "
                          f"sequence lengths ({s_q}, {s_k})")
+    if block_q % causal_block or (s_k - s_q) % causal_block:
+        raise ValueError(
+            f"causal_block {causal_block} must divide block_q {block_q} and "
+            f"the diagonal's offset {s_k - s_q}")
     # Mosaic tile minimum: the (block_q, block_k) score tile needs >= 8
     # sublanes and >= 128 lanes. Awkward sequence lengths with few
     # power-of-2 factors (S=1200 -> 16, odd S -> 1) used to auto-pick
@@ -185,7 +237,8 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
         if rep != 1:  # dense needs matching head counts: expand GQA K/V
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        return dense_attention(q, k, v, causal=causal)
+        return dense_attention(q, k, v, causal=causal,
+                               causal_block=causal_block)
 
     # (B, S, H, D) -> (B*H, S, D): batch*head is the embarrassing grid axis.
     # K/V keep their GROUPED head count; the kernel's index map divides.
@@ -194,7 +247,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
             b * x.shape[2], x.shape[1], d)
 
     out = _flash_bh(to_bh(q), to_bh(k), to_bh(v), bool(causal), int(block_q),
-                    int(block_k), int(rep), bool(interpret))
+                    int(block_k), int(rep), bool(interpret), causal_block)
     return (out.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)).astype(q.dtype)
 
 
@@ -210,15 +263,18 @@ def _flash_bh_jit():
 
     return profiled_jit(_flash_bh_impl, name="flash.attention",
                         static_argnames=("causal", "block_q", "block_k",
-                                         "rep", "interpret"))
+                                         "rep", "interpret", "causal_block"))
 
 
-def _flash_bh(q, k, v, causal, block_q, block_k, rep, interpret):
+def _flash_bh(q, k, v, causal, block_q, block_k, rep, interpret,
+              causal_block=1):
     return _flash_bh_jit()(q, k, v, causal=causal, block_q=block_q,
-                           block_k=block_k, rep=rep, interpret=interpret)
+                           block_k=block_k, rep=rep, interpret=interpret,
+                           causal_block=causal_block)
 
 
-def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret):
+def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret,
+                   causal_block=1):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -263,6 +319,8 @@ def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret):
             if causal:
                 qpos = iq * block_q + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0) + diag_off
+                if causal_block > 1:  # the whole block of positions
+                    qpos = qpos | (causal_block - 1)
                 kpos = jk * block_k + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
                 s = jnp.where(qpos >= kpos, s, _NEG)

@@ -425,15 +425,21 @@ def test_attention_is_dense_grouped_query_attention(layout, causal):
 
 
 @pytest.mark.parametrize("what", ["softcap", "qk_matmul_output_mode",
-                                  "softmax_precision", "attn_mask",
-                                  "second_output"])
+                                  "softmax_precision", "float_attn_mask",
+                                  "past_key", "second_output"])
 def test_attention_refuses_what_it_does_not_lower(what):
+    """A boolean ``attn_mask`` is lowered (``tests/test_sdar_moe.py`` holds
+    it to a form written out by hand); an additive one is not, nor a cache
+    handed in as ``past_key``."""
     x = np.zeros((1, 4, 8), np.float32)
     feeds = {"q": x, "k": x, "v": x}
     attrs = dict(q_num_heads=2, kv_num_heads=2)
     n_outputs = 1
-    if what == "attn_mask":
+    if what == "float_attn_mask":
         feeds["mask"] = np.zeros((4, 4), np.float32)
+    elif what == "past_key":
+        feeds["mask"] = np.ones((4, 4), bool)
+        feeds["past_key"] = np.zeros((1, 2, 3, 4), np.float32)
     elif what == "second_output":
         n_outputs = 2
     else:

@@ -1,0 +1,642 @@
+"""The block-diffusion ``sdar_moe`` graph (an ONNX ``Loop`` carrying a
+key-value cache, block-masked attention, rotary positions, gated experts) at
+its tiny preset on the CPU: ``transform`` against the benchmark's plain
+reference replayed at every (block, pass), cached passes against the uncached
+full forward, and each new operator against a form written out by hand."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from synapseml_tpu.models import zoo  # noqa: E402
+from synapseml_tpu.onnx import builder as ob  # noqa: E402
+from synapseml_tpu.onnx.importer import OnnxFunction  # noqa: E402
+from synapseml_tpu.onnx.wire import serialize_model  # noqa: E402
+
+TINY = zoo.SDAR_MOE_TINY
+BLOCK, PASSES, GENERATE = TINY["block"], TINY["passes"], TINY["generate"]
+CONFIG = {
+    "num_hidden_layers": TINY["layers"], "hidden_size": TINY["hidden"],
+    "num_attention_heads": TINY["heads"],
+    "num_key_value_heads": TINY["kv_heads"], "head_dim": TINY["head_dim"],
+    "num_experts": TINY["experts"], "num_experts_per_tok": TINY["top_k"],
+    "rms_norm_eps": 1e-6, "rope_theta": 1e6,
+    "mask_token_id": TINY["mask_id"],
+    "builder_kwargs": {"block": BLOCK, "passes": PASSES},
+}
+PROMPT = 16
+
+
+def _reference(model_bytes):
+    from benchmark.reference import sdar_moe
+    from benchmark.reference.onnx_initializers import read_initializers
+
+    return sdar_moe.Reference(CONFIG, read_initializers(model_bytes))
+
+
+def _prompts(rows, seed=0, length=PROMPT):
+    return np.random.default_rng(seed).integers(0, TINY["vocab"],
+                                                (rows, length))
+
+
+def _relative(got, want):
+    want = np.asarray(want, np.float64)
+    return float(np.linalg.norm(np.asarray(got, np.float64) - want)
+                 / np.linalg.norm(want))
+
+
+def _gauge(family, **where):
+    from synapseml_tpu.observability.metrics import get_registry
+
+    fam = get_registry().snapshot()["families"].get(family) or {}
+    names = fam.get("labelnames", [])
+    return {tuple(s["labels"]): s.get("value", s.get("count"))
+            for s in fam.get("series", [])
+            if all(dict(zip(names, s["labels"])).get(k) == v
+                   for k, v in where.items())}
+
+
+def _fresh_programs(monkeypatch):
+    import weakref
+
+    from synapseml_tpu.onnx import importer
+
+    monkeypatch.setattr(importer, "_PROGRAMS", weakref.WeakValueDictionary())
+
+
+def _transform(model_bytes, prompts, policy):
+    import jax
+
+    from synapseml_tpu.core import Table
+    from synapseml_tpu.onnx import ONNXModel
+
+    model = ONNXModel(
+        model_bytes=model_bytes, feed_dict={"input_ids": "input_ids"},
+        fetch_dict={c: c for c in ("tokens", "unmask_pass", "chosen_logprob",
+                                   "pooled")},
+        batch_size=len(prompts), dtype_policy=policy)
+    with jax.default_matmul_precision("highest"):
+        out = model.transform(Table({"input_ids": prompts}))
+    return {c: np.asarray(out[c]) for c in ("tokens", "unmask_pass",
+                                            "chosen_logprob", "pooled")}
+
+
+# float32 policy: the program (cache, loop, grouped products) and the
+# reference (one full forward a state, a loop over experts) are the same
+# arithmetic in another order: 1e-4 leaves two orders of magnitude over the
+# 8e-7 read, and a choice the reference would not have made reads as a gap of
+# tenths of a unit. bfloat16 policy: every node hands on 8 bits of mantissa,
+# the logits too; 0.03 / 0.1 in logits' standard deviations is what a
+# rounding of two near-tied logits can move (0.007 / 0.015 read), 0.02 of
+# relative error in a log-probability of about -3.5 (0.003 read).
+@pytest.mark.parametrize("policy,limit", [
+    ("float32", {"logprob": 1e-4, "argmax_gap": 1e-4, "confidence_gap": 1e-4}),
+    ("bfloat16", {"logprob": 0.02, "argmax_gap": 0.03,
+                  "confidence_gap": 0.1})])
+def test_transform_agrees_with_the_reference_replayed_at_every_pass(
+        policy, limit):
+    from benchmark.checks import replayed_generation as check
+
+    model_bytes = zoo.build_model_bytes("SDARMoETiny", seed=3)
+    prompts = _prompts(5, seed=1)
+    got = _transform(model_bytes, prompts, policy)
+    assert got["tokens"].shape == (5, GENERATE)
+    assert not (got["tokens"] == TINY["mask_id"]).any()
+    assert check.schedule_mismatch(got["unmask_pass"], BLOCK, PASSES) == 0
+    blocks = [list(range(GENERATE // BLOCK))] * len(prompts)
+    logits = _reference(model_bytes).replay(
+        prompts, got["tokens"], got["unmask_pass"], blocks, block_rows=2
+    )["logits"]
+    numbers = check.replay_numbers(logits, got["tokens"], got["unmask_pass"],
+                                   got["chosen_logprob"], blocks, BLOCK,
+                                   TINY["mask_id"])
+    assert numbers["chosen_logprob.rel_rms"] < limit["logprob"]
+    assert numbers["argmax_gap"] < limit["argmax_gap"]
+    assert numbers["confidence_gap"] < limit["confidence_gap"]
+
+
+# the commit passes read the prompt's and the earlier blocks' keys and values
+# from the cache; the reference runs one uncached forward over the row's
+# final ids. float32: rounding alone (2e-7 read); bfloat16: 0.015 read with
+# the reference in float32, 0.05 leaves a float8 product's 0.2 outside.
+@pytest.mark.parametrize("policy,limit", [("float32", 1e-5),
+                                          ("bfloat16", 0.05)])
+def test_cached_passes_agree_with_the_uncached_full_forward(policy, limit):
+    model_bytes = zoo.build_model_bytes("SDARMoETiny", seed=4)
+    prompts = _prompts(4, seed=2, length=24)
+    got = _transform(model_bytes, prompts, policy)
+    want = _reference(model_bytes).pooled(prompts, got["tokens"])
+    assert got["pooled"].dtype == np.float32
+    assert _relative(got["pooled"], want) < limit
+
+
+def test_a_tampered_choice_or_weight_shows_in_the_replay():
+    from benchmark.checks import replayed_generation as check
+    from benchmark.reference.onnx_initializers import read_initializers
+
+    model_bytes = zoo.build_model_bytes("SDARMoETiny", seed=3)
+    prompts = _prompts(3, seed=5)
+    got = _transform(model_bytes, prompts, "float32")
+    blocks = [list(range(GENERATE // BLOCK))] * len(prompts)
+    reference = _reference(model_bytes)
+
+    def numbers(tokens, ref=reference):
+        logits = ref.replay(prompts, tokens, got["unmask_pass"],
+                            blocks)["logits"]
+        return check.replay_numbers(logits, tokens, got["unmask_pass"],
+                                    got["chosen_logprob"], blocks, BLOCK,
+                                    TINY["mask_id"])
+
+    tampered = got["tokens"].copy()
+    tampered[1, 5] = (tampered[1, 5] + 1) % (TINY["vocab"] - 1)
+    assert numbers(tampered)["argmax_gap"] > 0.3
+    from benchmark.reference import sdar_moe
+
+    weights = dict(read_initializers(model_bytes))
+    weights["l1_o_w"] = np.asarray(weights["l1_o_w"]).astype(np.float32) * 1.5
+    other = sdar_moe.Reference(CONFIG, weights)
+    assert numbers(got["tokens"], other)["chosen_logprob.rel_rms"] > 0.01
+
+
+def test_one_dispatch_a_bucket_and_weights_are_arguments(monkeypatch):
+    import jax
+
+    _fresh_programs(monkeypatch)
+    model_bytes = zoo.build_model_bytes("SDARMoETiny", seed=6)
+    fn = OnnxFunction(model_bytes, dtype_policy="bfloat16")
+    prompts = _prompts(2, seed=3)
+    lowered = jax.jit(fn._run_positional).lower(prompts.astype(np.int32),
+                                                *fn._weights)
+    text = lowered.as_text()
+    # the bodies read the outer graph's weights: each is one argument of the
+    # program, the embedding (three passes read it) among them
+    n_args = text.split("func.func public @main(")[1].split(") ->")[0]
+    assert n_args.count("%arg") == 1 + len(fn._weights)
+    assert "l0_experts_gate" in fn._weight_names
+    assert "stablehlo.while" in text
+    placed = sum(w.size * 2 for w in fn._weights)
+    assert _gauge("smt_onnx_weight_argument_bytes",
+                  fn=fn._fn_name) == {(fn._fn_name,): placed}
+    # no literal of the size of a weight (the largest tiny weight is the
+    # embedding, 256 x 64)
+    import re
+
+    biggest = max((int(np.prod([int(d) for d in m.group(1).split("x")]))
+                   for m in re.finditer(
+                       r"stablehlo.constant dense<[^>]*> : tensor<([\dx]+)x",
+                       text)), default=0)
+    assert biggest < 64 * 64
+
+
+def test_loop_gauges_count_the_passes_of_a_call(monkeypatch):
+    _fresh_programs(monkeypatch)
+    model_bytes = zoo.build_model_bytes("SDARMoETiny", seed=7)
+    fn = OnnxFunction(model_bytes, dtype_policy="bfloat16")
+    name = fn._fn_name
+    before = {family: _gauge(family, fn=name) for family in (
+        "smt_onnx_attention_lowering_total", "smt_onnx_expert_form_total")}
+
+    def since(family):  # counters add up over a process's traces
+        return {k: v - before[family].get(k, 0)
+                for k, v in _gauge(family, fn=name).items()}
+
+    fn({"input_ids": _prompts(2, seed=4)})
+    trips = _gauge("smt_onnx_loop_trips", fn=name)
+    assert trips == {(name, "blocks"): GENERATE // BLOCK,
+                     (name, "passes"): PASSES * GENERATE // BLOCK}
+    assert sum(trips.values()) == (PASSES + 1) * GENERATE // BLOCK
+    # the caches: 2 a layer of [rows, S + G, kv * size] bfloat16, and the
+    # outputs the loop fills (tokens, unmask_pass, chosen_logprob, pooled sum)
+    cache = 2 * TINY["layers"] * 2 * (PROMPT + GENERATE) \
+        * TINY["kv_heads"] * TINY["head_dim"] * 2
+    outputs = 2 * (3 * GENERATE + TINY["hidden"]) * 4
+    assert _gauge("smt_onnx_loop_state_bytes", fn=name) == {
+        (name,): cache + outputs}
+    lowering = since("smt_onnx_attention_lowering_total")
+    # the prompt's pass could have had the kernel (dense on the CPU); a pass
+    # against the cache is masked by the run: a kind of its own
+    assert lowering == {(name, "dense"): TINY["layers"],
+                        (name, "masked"): 2 * TINY["layers"]}
+    assert since("smt_onnx_expert_form_total") == {
+        (name, "swiglu"): 3 * TINY["layers"]}
+
+
+# ---------------------------------------------------------------- operators
+
+
+def _model(nodes, inputs, outputs, initializers=None, opset=24, domain=""):
+    graph = ob.make_graph(
+        nodes, "test",
+        [ob.value_info(k, v.dtype, list(v.shape)) for k, v in inputs.items()],
+        [ob.value_info(o, np.float32, None) for o in outputs],
+        initializers or {})
+    return serialize_model(ob.make_model(
+        graph, opset=opset, domains={domain: 1} if domain else None))
+
+
+def _counting_loop(trips, carried_out="acc_out", scan=False, cond_out=None,
+                   trips_fed=False, cond_in=""):
+    """``acc <- acc * 2 + i`` over ``trips`` trips, ``acc [2, 3]`` float32."""
+    body_nodes = [
+        ob.node("Cast", ["i"], ["i_f"], to=1),
+        ob.node("Mul", ["acc", "two"], ["twice"]),
+        ob.node("Add", ["twice", "i_f"], ["acc_out"]),
+        ob.node("Concat", ["acc", "acc"], ["acc_wide"], axis=1),
+        ob.node("Identity", ["keep"], ["keep_out"]),
+        ob.node("Less", ["i_f", "two"], ["keep_traced"]),
+    ]
+    outs = [cond_out or "keep_out", carried_out] + (["twice"] if scan else [])
+    body = ob.make_graph(
+        body_nodes, "body",
+        [ob.value_info("i", np.int64, []), ob.value_info("keep", np.bool_, []),
+         ob.value_info("acc", np.float32, [2, 3])],
+        [ob.value_info(o, np.float32, None) for o in outs])
+    inits = {"two": np.asarray(2.0, np.float32)}
+    if not trips_fed:
+        inits["trips"] = np.asarray(trips, np.int64)
+    if cond_in == "false":
+        inits["go"] = np.asarray(False)
+    loop_outs = ["y"] + (["ys"] if scan else [])
+    return [ob.node("Loop", ["trips", "go" if cond_in else "", "x"],
+                    loop_outs, name="count", body=body)], inits, loop_outs
+
+
+def test_loop_runs_its_static_trip_count_over_carried_values():
+    x = np.arange(6, dtype=np.float32).reshape(2, 3)
+    nodes, inits, outs = _counting_loop(5)
+    got = OnnxFunction(_model(nodes, {"x": x}, outs, inits))({"x": x})["y"]
+    want = x.copy()
+    for i in range(5):
+        want = want * 2 + i
+    np.testing.assert_allclose(np.asarray(got), want)
+    # the body reads the outer graph's ``two``; the loop is one while
+    assert _gauge("smt_onnx_loop_trips", loop="count")[
+        ("onnx.test", "count")] == 5
+
+
+@pytest.mark.parametrize("what,kwargs,error,says", [
+    ("trip_count_at_run_time", dict(trips_fed=True), NotImplementedError,
+     "trip count"),
+    ("condition_not_true", dict(cond_in="false"), NotImplementedError,
+     "condition"),
+    ("condition_computed_in_the_body", dict(cond_out="keep_traced"),
+     NotImplementedError, "computes its condition"),
+    ("scan_outputs", dict(scan=True), NotImplementedError, "scan outputs"),
+    ("carried_shape_changes", dict(carried_out="acc_wide"), ValueError,
+     r"enters as float32\[2, 3\] and leaves as float32\[2, 6\]"),
+    ("carried_type_changes", dict(carried_out="keep_traced"), ValueError,
+     "leaves as bool")])
+def test_loop_refuses_by_name_what_it_does_not_lower(what, kwargs, error,
+                                                     says):
+    x = np.ones((2, 3), np.float32)
+    nodes, inits, outs = _counting_loop(3, **kwargs)
+    feeds = {"x": x}
+    if kwargs.get("trips_fed"):
+        feeds["trips"] = np.asarray(3, np.int64)
+    with pytest.raises(error, match="Loop count.*" + says):
+        OnnxFunction(_model(nodes, feeds, outs, inits))(feeds)
+
+
+def _rotated_by_hand(x, positions, theta, interleaved, rot):
+    """``x [b, s, heads, size]`` float64, pair by pair."""
+    out = x.copy()
+    half = rot // 2
+    for j in range(half):
+        angle = positions[..., None] * theta ** (-2.0 * j / rot)  # [b, s, 1]
+        a, b = (2 * j, 2 * j + 1) if interleaved else (j, j + half)
+        out[..., a] = x[..., a] * np.cos(angle) - x[..., b] * np.sin(angle)
+        out[..., b] = x[..., b] * np.cos(angle) + x[..., a] * np.sin(angle)
+    return out
+
+
+@pytest.mark.parametrize("layout", ["batch_seq_hidden", "batch_heads_seq"])
+@pytest.mark.parametrize("interleaved,rot,with_ids", [
+    (0, 8, True), (1, 8, True), (0, 4, True), (0, 8, False)])
+def test_rotary_embedding_turns_pairs_by_their_position(layout, interleaved,
+                                                        rot, with_ids):
+    rng = np.random.default_rng(8)
+    b, s, heads, size, theta = 2, 5, 3, 8, 100.0
+    x = rng.standard_normal((b, s, heads, size), dtype=np.float32)
+    positions = rng.integers(0, 40, (b, s))
+    angles = np.arange(40)[:, None] * theta ** (
+        -2.0 * np.arange(rot // 2) / rot)[None, :]
+    cos, sin = (f(angles).astype(np.float32) for f in (np.cos, np.sin))
+    want = _rotated_by_hand(x.astype(np.float64), positions, theta,
+                            interleaved, rot)
+    attrs = dict(interleaved=interleaved)
+    if rot != size:
+        attrs["rotary_embedding_dim"] = rot
+    if layout == "batch_seq_hidden":
+        fed, attrs["num_heads"] = x.reshape(b, s, heads * size), heads
+        want = want.reshape(b, s, heads * size)
+    else:
+        fed, want = x.transpose(0, 2, 1, 3), want.transpose(0, 2, 1, 3)
+    feeds = {"x": fed}
+    if with_ids:
+        inits = {"cos": cos, "sin": sin, "ids": positions.astype(np.int64)}
+    else:  # the caches are the rows' own angles
+        inits = {"cos": cos[positions], "sin": sin[positions]}
+    model = _model([ob.node("RotaryEmbedding", ["x"] + list(inits), ["y"],
+                            **attrs)], feeds, ["y"], inits)
+    got = OnnxFunction(model)(feeds)["y"]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+    narrow = OnnxFunction(model, dtype_policy="bfloat16")(feeds)["y"]
+    assert _relative(narrow, want) < 0.01
+
+
+def _attention_by_hand(q, k, v, visible):
+    """``q [b, sq, h, d]``, ``k``/``v [b, sk, hkv, d]``, ``visible [b, h, sq,
+    sk]``; float64."""
+    b, sq, h, d = q.shape
+    rep = h // k.shape[2]
+    out = np.zeros_like(q, dtype=np.float64)
+    for n in range(b):
+        for head in range(h):
+            s = q[n, :, head].astype(np.float64) @ k[n, :, head // rep].T \
+                / np.sqrt(d)
+            s = np.where(visible[n, head], s, -np.inf)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[n, :, head] = (p / p.sum(-1, keepdims=True)) @ v[n, :,
+                                                                 head // rep]
+    return out
+
+
+def _qkv(rng, b, sq, sk, heads, kv, d):
+    return (rng.standard_normal((b, sq, heads, d), dtype=np.float32),
+            rng.standard_normal((b, sk, kv, d), dtype=np.float32),
+            rng.standard_normal((b, sk, kv, d), dtype=np.float32))
+
+
+@pytest.mark.parametrize("mask_kind,lowering", [
+    ("block_causal_constant", "dense"), ("arbitrary_constant", "masked"),
+    ("fed_at_run_time", "masked"), ("per_head", "masked")])
+def test_attention_takes_a_boolean_mask(mask_kind, lowering, monkeypatch):
+    import jax
+
+    _fresh_programs(monkeypatch)
+    rng = np.random.default_rng(9)
+    b, s, heads, kv, d = 2, 12, 4, 2, 8
+    q, k, v = _qkv(rng, b, s, s, heads, kv, d)
+    if mask_kind == "block_causal_constant":
+        blocks = np.arange(s) // 4
+        mask = blocks[None, :] <= blocks[:, None]
+    elif mask_kind == "per_head":
+        mask = rng.random((1, heads, s, s)) < 0.6
+        mask[..., 0] = True
+    else:
+        mask = rng.random((s, s)) < 0.6
+        mask[:, 0] = True  # no row without a visible key
+    want = _attention_by_hand(q, k, v, np.broadcast_to(mask, (b, heads, s, s)))
+    feeds = {n: x.reshape(b, s, -1) for n, x in zip("qkv", (q, k, v))}
+    inits = {}
+    if mask_kind == "fed_at_run_time":
+        feeds["mask"] = mask
+    else:
+        inits["mask"] = mask
+    model = _model([ob.node("Attention", ["q", "k", "v", "mask"], ["y"],
+                            name="att", q_num_heads=heads, kv_num_heads=kv)],
+                   feeds, ["y"], inits)
+    fn = OnnxFunction(model)
+    before = _gauge("smt_onnx_attention_lowering_total", fn=fn._fn_name)
+    with jax.default_matmul_precision("highest"):
+        got = fn(feeds)["y"]
+    np.testing.assert_allclose(np.asarray(got).reshape(b, s, heads, d), want,
+                               rtol=1e-5, atol=1e-5)
+    after = _gauge("smt_onnx_attention_lowering_total", fn=fn._fn_name)
+    assert {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)} == {(fn._fn_name, lowering): 1}
+
+
+@pytest.mark.parametrize("policy,limit", [("float32", 1e-5),
+                                          ("bfloat16", 0.02)])
+def test_attention_of_a_few_queries_against_a_longer_key_axis(policy, limit):
+    """Four queries, a cache of 24 positions of which the run says how many
+    are filled: grouped key-value heads, never repeated."""
+    import jax
+
+    rng = np.random.default_rng(10)
+    b, sq, sk, heads, kv, d = 3, 4, 24, 4, 2, 8
+    q, k, v = _qkv(rng, b, sq, sk, heads, kv, d)
+    filled = np.asarray(17, np.int64)
+    nodes = [ob.node("Less", ["positions", "filled"], ["visible"]),
+             ob.node("Unsqueeze", ["visible", "axes_0"], ["mask"]),
+             ob.node("Attention", ["q", "k", "v", "mask"], ["y"],
+                     q_num_heads=heads, kv_num_heads=kv)]
+    feeds = {"q": q.reshape(b, sq, -1), "k": k.reshape(b, sk, -1),
+             "v": v.reshape(b, sk, -1), "filled": filled}
+    model = _model(nodes, feeds, ["y"],
+                   {"positions": np.arange(sk), "axes_0": np.asarray([0])})
+    if policy == "bfloat16":
+        import jax.numpy as jnp
+
+        q, k, v = (np.asarray(jnp.asarray(x, jnp.bfloat16).astype(
+            jnp.float32)) for x in (q, k, v))
+    visible = np.broadcast_to(np.arange(sk) < 17, (b, heads, sq, sk))
+    want = _attention_by_hand(q, k, v, visible)
+    with jax.default_matmul_precision("highest"):
+        got = OnnxFunction(model, dtype_policy=policy)(feeds)["y"]
+    assert _relative(np.asarray(got).reshape(b, sq, heads, d), want) < limit
+
+
+@pytest.mark.parametrize("block", [2, 4, 8])
+@pytest.mark.parametrize("s_q,s_k", [(16, 16), (8, 24)])
+def test_flash_block_granular_causal_mask_in_interpret_mode(block, s_q, s_k):
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel.flash import dense_attention, flash_attention
+
+    rng = np.random.default_rng(11)
+    q, k, v = _qkv(rng, 2, s_q, s_k, 4, 2, 16)
+    off = s_k - s_q
+    visible = (np.arange(s_k)[None, :]
+               <= ((np.arange(s_q)[:, None] + off) | (block - 1)))
+    want = _attention_by_hand(q, k, v, np.broadcast_to(visible,
+                                                       (2, 4, s_q, s_k)))
+    with jax.default_matmul_precision("highest"):
+        got = flash_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                              causal=True, causal_block=block, block_q=8,
+                              block_k=8, interpret=True)
+        dense = dense_attention(jnp.asarray(q),
+                                jnp.repeat(jnp.asarray(k), 2, axis=2),
+                                jnp.repeat(jnp.asarray(v), 2, axis=2),
+                                causal=True, causal_block=block)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(dense), want, rtol=1e-5, atol=1e-5)
+
+
+def test_flash_refuses_a_block_its_tiles_do_not_hold():
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel.flash import auto_blocks_tile, flash_attention
+
+    x = jnp.zeros((1, 16, 2, 8))
+    with pytest.raises(ValueError, match="causal_block"):
+        flash_attention(x, x, x, causal=True, causal_block=3, interpret=True)
+    with pytest.raises(ValueError, match="causal_block"):
+        flash_attention(x, x, x, causal=True, causal_block=16, block_q=8,
+                        block_k=8, interpret=True)
+    assert auto_blocks_tile(64, 256, 256, 4)
+    assert not auto_blocks_tile(64, 256, 258, 4)
+
+
+@pytest.mark.parametrize("write", ["constant", "uniform_at_run_time",
+                                   "a_start_each_row", "absent"])
+def test_tensor_scatter_writes_rows_from_their_start(write):
+    rng = np.random.default_rng(12)
+    cache = rng.standard_normal((3, 10, 4), dtype=np.float32)
+    update = rng.standard_normal((3, 2, 4), dtype=np.float32)
+    starts = {"constant": [5, 5, 5], "uniform_at_run_time": [7, 7, 7],
+              "a_start_each_row": [0, 8, 3], "absent": [0, 0, 0]}[write]
+    want = cache.copy()
+    for r, at in enumerate(starts):
+        want[r, at:at + 2] = update[r]
+    feeds, inits = {"cache": cache, "update": update}, {}
+    names = ["cache", "update"]
+    if write != "absent":
+        (inits if write == "constant" else feeds)["at"] = np.asarray(
+            starts, np.int64)
+        names.append("at")
+    model = _model([ob.node("TensorScatter", names, ["y"], axis=1)], feeds,
+                   ["y"], inits)
+    got = OnnxFunction(model)(feeds)["y"]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _gated_layer(seed=13, shape=(3, 10), h=32, f=48, experts=8, k=2):
+    rng = np.random.default_rng(seed)
+    u = rng.standard_normal((*shape, h), dtype=np.float32)
+    w = {"router_w": rng.standard_normal((h, experts), dtype=np.float32),
+         "experts_gate": rng.normal(0, h ** -0.5, (experts, h, f)
+                                    ).astype(np.float32),
+         "experts_up": rng.normal(0, h ** -0.5, (experts, h, f)
+                                  ).astype(np.float32),
+         "experts_down": rng.normal(0, f ** -0.5, (experts, f, h)
+                                    ).astype(np.float32)}
+    scores = u @ w["router_w"]
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    probs = probs / probs.sum(-1, keepdims=True)
+    picks = np.argsort(-probs, axis=-1, kind="stable")[..., :k]
+    top = np.take_along_axis(probs, picks, -1)
+    return u, w, {"x": u, "index": picks.astype(np.int64),
+                  "weight": (top / top.sum(-1, keepdims=True)
+                             ).astype(np.float32)}
+
+
+def _gated_share(feeds, w, lo, held, policy="float32"):
+    import jax
+
+    weights = {n: w[n][lo:lo + held] for n in ("experts_up", "experts_down",
+                                               "experts_gate")}
+    model = _model(
+        [ob.node("ExpertFFN", list(feeds) + list(weights), ["y"],
+                 domain="synapseml_tpu", first_expert=lo,
+                 num_experts=w["router_w"].shape[1], activation="swiglu")],
+        feeds, ["y"], weights, opset=23, domain="synapseml_tpu")
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(OnnxFunction(model, dtype_policy=policy)(feeds)["y"])
+
+
+def _gated_by_hand(feeds, w, lo, held):
+    """``sum over a token's picks of a held expert e of weight x (silu(x G_e)
+    * (x U_e)) D_e``, pair by pair in float64."""
+    x = feeds["x"].astype(np.float64)
+    out = np.zeros_like(x)
+    for at in np.ndindex(*feeds["index"].shape):
+        e = int(feeds["index"][at])
+        if lo <= e < lo + held:
+            gate = x[at[:-1]] @ w["experts_gate"][e].astype(np.float64)
+            hidden = gate / (1 + np.exp(-gate)) * (
+                x[at[:-1]] @ w["experts_up"][e].astype(np.float64))
+            out[at[:-1]] += feeds["weight"][at] * (
+                hidden @ w["experts_down"][e].astype(np.float64))
+    return out
+
+
+@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("policy,limit", [("float32", 1e-5),
+                                          ("bfloat16", 0.01)])
+def test_expert_ffn_swiglu_against_a_loop_over_experts(chunk, policy, limit,
+                                                       monkeypatch):
+    from synapseml_tpu.onnx import ops
+
+    _fresh_programs(monkeypatch)
+    if chunk:
+        monkeypatch.setattr(ops, "_PAIR_CHUNK", chunk)
+    u, w, feeds = _gated_layer(k=3)
+    if policy == "bfloat16":
+        import jax.numpy as jnp
+
+        def rounded(a):
+            return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+
+        want = _gated_by_hand(dict(feeds, x=rounded(feeds["x"])),
+                              {n: rounded(v) for n, v in w.items()}, 0, 8)
+    else:
+        want = _gated_by_hand(feeds, w, 0, 8)
+    got = _gated_share(feeds, w, 0, 8, policy)
+    assert got.dtype == np.float32
+    assert _relative(got, want) < limit
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_swiglu_shares_over_first_expert_add_up_to_the_uncut_layer(
+        shares, monkeypatch):
+    """The guide's share test: what the chips of a deployment that divides
+    the experts each compute adds up to the whole layer, which is also what
+    the plain reference gives for it."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import sdar_moe as ref
+
+    _fresh_programs(monkeypatch)
+    u, w, feeds = _gated_layer(experts=8, k=3)
+    held = 8 // shares
+    total = sum(_gated_share(feeds, w, lo, held)
+                for lo in range(0, 8, held))
+    whole = _gated_share(feeds, w, 0, 8)
+    np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
+    with jax.default_matmul_precision("highest"):
+        want = ref.experts(jnp.asarray(u), {n: jnp.asarray(v)
+                                            for n, v in w.items()},
+                           top_k=3, precision="float32")
+    np.testing.assert_allclose(whole, np.asarray(want), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("what", ["unknown_activation",
+                                  "swiglu_without_its_gate",
+                                  "relu2_with_a_gate"])
+def test_expert_ffn_refuses_a_form_it_does_not_have(what):
+    x = np.zeros((2, 3, 8), np.float32)
+    feeds = {"x": x, "index": np.zeros((2, 3, 1), np.int64),
+             "weight": np.ones((2, 3, 1), np.float32)}
+    weights = {"up": np.zeros((2, 8, 4), np.float32),
+               "down": np.zeros((2, 4, 8), np.float32)}
+    activation = {"unknown_activation": "geglu",
+                  "swiglu_without_its_gate": "swiglu",
+                  "relu2_with_a_gate": "relu2"}[what]
+    if what != "swiglu_without_its_gate":
+        weights["gate"] = np.zeros((2, 8, 4), np.float32)
+    model = _model([ob.node("ExpertFFN", list(feeds) + list(weights), ["y"],
+                            domain="synapseml_tpu", first_expert=0,
+                            num_experts=4, activation=activation)],
+                   feeds, ["y"], weights, opset=23, domain="synapseml_tpu")
+    error = NotImplementedError if what == "unknown_activation" else ValueError
+    with pytest.raises(error, match="ExpertFFN"):
+        OnnxFunction(model)(feeds)
+
+
+def test_builder_refuses_sizes_the_schedule_cannot_have():
+    from synapseml_tpu.models.sdar_moe import sdar_moe
+
+    for kwargs in (dict(generate=6), dict(block=4, passes=3),
+                   dict(block=6, generate=12, passes=2), dict(mask_id=256)):
+        with pytest.raises(ValueError):
+            sdar_moe(**{**TINY, **kwargs})
