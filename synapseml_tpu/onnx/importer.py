@@ -554,9 +554,9 @@ class OnnxFunction:
 
     def _record_notes(self, notes: Dict[str, int]) -> None:
         """Once a traced program: how its ``Attention`` and ``Gelu`` nodes
-        were lowered, what its ``ExpertFFN`` nodes are sized for, their form
-        and how they combine, how often its ``Loop`` bodies run and what
-        they carry."""
+        were lowered, what its ``ExpertFFN`` nodes are sized for, their form,
+        their row tile and chunk and how they combine, how often its ``Loop``
+        bodies run and what they carry."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
@@ -592,6 +592,12 @@ class OnnxFunction:
             "ExpertFFN nodes of a traced program by activation: relu2 (one "
             "up-projection) or swiglu (a gate and an up-projection)",
             ("fn", "form"))
+        tile = reg.counter(
+            "smt_onnx_expert_tile_total",
+            "ExpertFFN nodes of a traced program by the row tile of their "
+            "grouped products, which follows the pairs an expert is expected "
+            "to get (512 at 512 pairs an expert or more)",
+            ("fn", "rows"))
         trips = reg.gauge(
             "smt_onnx_loop_trips",
             "times a call of the newest traced program runs the body of "
@@ -602,6 +608,8 @@ class OnnxFunction:
                 combine.labels(fn, key[len("expert_combine_"):]).inc(count)
             elif key.startswith("expert_form_"):
                 form.labels(fn, key[len("expert_form_"):]).inc(count)
+            elif key.startswith("expert_tile_"):
+                tile.labels(fn, key[len("expert_tile_"):]).inc(count)
             elif key.startswith("loop_trips."):
                 trips.labels(fn, key[len("loop_trips."):]).set(count)
         if "loop_state_bytes" in notes:
@@ -623,6 +631,13 @@ class OnnxFunction:
                 "experts held by the ExpertFFN nodes of the newest traced "
                 "program, summed over nodes",
                 ("fn",), merge="max").labels(fn).set(notes["experts_held"])
+            reg.gauge(
+                "smt_onnx_expert_chunk_rows",
+                "sorted (token, pick) pairs the ExpertFFN nodes of the newest "
+                "traced program gather and multiply at a time: the smallest "
+                "chunk of any node",
+                ("fn",), merge="max").labels(fn).set(
+                    notes["expert_chunk_rows"])
 
     def _run_function(self, fdef, call, env: Dict[str, Any],
                       handoff: "List[int] | None" = None,

@@ -1505,21 +1505,69 @@ def _attention(inputs, attrs, ctx):
     return jnp.transpose(out, (0, 2, 1, 3))
 
 
-# rows of (token, pick) pairs a grid step of the grouped kernel takes
+# rows of (token, pick) pairs a grid step of the grouped kernel takes where an
+# expert is expected to get that many or more
 _GMM_ROWS = 512
-# sorted pairs ExpertFFN gathers and multiplies at a time
-_PAIR_CHUNK = 48 * _GMM_ROWS
+# row tiles of sorted pairs ExpertFFN gathers and multiplies at a time, at most
+_CHUNK_TILES = 48
 # product rows ExpertFFN adds to their tokens at a time, where a token has
 # more held picks than are gathered for every token (a go pays for the rows
 # it is padded with)
 _REST_ROWS = 1024
 
 
-def _grouped_product(lhs, rhs, sizes):
+def _expert_tiling(n_pairs: int, num_experts: int) -> "tuple[int, int]":
+    """``(row tile, chunk length)`` of ``ExpertFFN``'s grouped products, from
+    the pairs an expert is expected to get under an even router, ``n_pairs
+    // num_experts``: all a node knows of its load when it is traced.
+
+    The kernel visits a row tile once for every group that touches it and
+    computes the whole tile each visit, so a tile far over an expert's pairs
+    multiplies rows of other experts that the store then masks: at 32 pairs
+    an expert a 512-row tile does 17 times the arithmetic wanted. Under 128
+    rows a visit costs the same whatever the tile (the MXU loads the
+    expert's weight tiles for a few rows), so a smaller tile only adds
+    visits. One layer of ``sdar_30b_a3b`` on a v5e, ms an ``ExpertFFN`` by
+    row tile 512 / 256 / 128 / 64 / 32 (``tools/expert_tile_forms.py``'s
+    ``<rows>:load:whole``; PERF.md section 6, PR 33): 3.81 / 2.39 / 2.23 /
+    2.46 / 2.61 at 32 pairs an expert, 4.31 / 2.83 / 3.04 / 3.09 / 3.45 at
+    64, 8.68 / 6.56 / 6.85 / 7.20 / 8.19 at 256. So 128 rows is the least,
+    256 from 64 expected pairs on, and from 512 on 512: the tiles and the
+    lowered text every program had before the rule."""
+    expected = n_pairs // num_experts
+    tile = 128 if expected < 64 else 256 if expected < _GMM_ROWS else _GMM_ROWS
+    return tile, _chunk_rows(n_pairs, tile)
+
+
+def _chunk_rows(n_pairs: int, tile: int) -> int:
+    """Sorted pairs a chunk holds: ``_CHUNK_TILES`` row tiles (24,576 pairs at
+    the 512-row tile) or, where there are fewer pairs, all of them rounded
+    up to a tile: the row gather, the activation and the buffer's update
+    cover a chunk whole, filled or not."""
+    return min(_CHUNK_TILES * tile, -(-n_pairs // tile) * tile)
+
+
+def _gmm_tiling(rows: int, k: int, n: int, itemsize: int):
+    """The grouped kernel's ``(tm, tk, tn)`` for row tiles of ``rows``. At
+    512 rows: 7.3 MB of the 16 MB of scoped VMEM at the caps (two buffers an
+    operand tile, the float32 accumulator); a rhs tile serves 512 rows, twice
+    the v5e's FLOPs a byte. Under that a visit is a few rows against a whole
+    expert's weights, and VMEM goes to the weights: the whole of ``k`` is one
+    tile where two buffers of it fit in half the scoped VMEM, so a weight
+    tile is fetched once a visit and kept between consecutive visits of one
+    expert."""
+    tk, tn = _tile(k, 512), _tile(n, 1024)
+    if rows < _GMM_ROWS and 2 * k * tn * itemsize <= 8 << 20:
+        tk = k
+    return rows, tk, tn
+
+
+def _grouped_product(lhs, rhs, sizes, rows):
     """``lhs[rows of group g] @ rhs[g]`` for consecutive groups of ``sizes``
     rows, float32 accumulation, in ``lhs``'s type. Rows past the last group
     come back unspecified. On a TPU the megablox kernel, which visits only
-    the row tiles a group touches; elsewhere ``lax.ragged_dot``."""
+    the row tiles (of ``rows`` rows) a group touches; elsewhere
+    ``lax.ragged_dot``."""
     rhs = rhs.astype(lhs.dtype)
     if not _kernels_on():
         return lax.ragged_dot(lhs, rhs, sizes,
@@ -1528,15 +1576,11 @@ def _grouped_product(lhs, rhs, sizes):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, k = lhs.shape
-    n = rhs.shape[2]
-    pad = -m % _GMM_ROWS
+    pad = -m % rows
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    # 7.3 MB of the 16 MB of scoped VMEM at the caps (two buffers an operand
-    # tile, the float32 accumulator); a rhs tile serves 512 rows, twice the
-    # v5e's FLOPs a byte
     out = gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
-              tiling=(_GMM_ROWS, _tile(k, 512), _tile(n, 1024)))
+              tiling=_gmm_tiling(rows, k, rhs.shape[2], lhs.dtype.itemsize))
     return out[:m] if pad else out
 
 
@@ -1601,6 +1645,12 @@ def _expert_ffn(inputs, attrs, ctx):
     _note(ctx, "experts_held", held)
     _note(ctx, "expert_combine_held_first")
     _note(ctx, "expert_form_" + activation)
+    tile, chunk = _expert_tiling(n_pairs, int(attrs["num_experts"]))
+    _note(ctx, f"expert_tile_{tile}")
+    notes = ctx.get("notes")
+    if notes is not None:
+        notes["expert_chunk_rows"] = min(
+            notes.get("expert_chunk_rows", chunk), chunk)
 
     # the sorted pairs a chunk at a time, for as many chunks as hold a held
     # expert's pair: the work follows the load (a quarter of the pairs where
@@ -1608,28 +1658,29 @@ def _expert_ffn(inputs, attrs, ctx):
     # buffer starts as it is found: what no chunk wrote, and what the kernel
     # left in the rows of a chunk past its last group, is selected away
     # below and never reaches a sum
-    n_chunks = -(-n_pairs // _PAIR_CHUNK)
-    order_padded = jnp.pad(order, (0, n_chunks * _PAIR_CHUNK - n_pairs))
+    n_chunks = -(-n_pairs // chunk)
+    order_padded = jnp.pad(order, (0, n_chunks * chunk - n_pairs))
 
     def one_chunk(i, results):
-        lo = i * _PAIR_CHUNK
-        pairs = lax.dynamic_slice(order_padded, (lo,), (_PAIR_CHUNK,))
-        inside = (jnp.clip(ends, lo, lo + _PAIR_CHUNK)
-                  - jnp.clip(ends - sizes, lo, lo + _PAIR_CHUNK))
+        lo = i * chunk
+        pairs = lax.dynamic_slice(order_padded, (lo,), (chunk,))
+        inside = (jnp.clip(ends, lo, lo + chunk)
+                  - jnp.clip(ends - sizes, lo, lo + chunk))
         rows = tokens[pairs % n_tokens]
-        hidden = _grouped_product(rows, up, inside)
+        hidden = _grouped_product(rows, up, inside, tile)
         if gate is None:
             hidden = jnp.square(jax.nn.relu(hidden))
         else:
-            gated = _grouped_product(rows, gate, inside).astype(jnp.float32)
+            gated = _grouped_product(rows, gate, inside, tile).astype(
+                jnp.float32)
             hidden = (jax.nn.silu(gated) * hidden.astype(jnp.float32)
                       ).astype(x.dtype)
-        out = _grouped_product(hidden, down, inside)
+        out = _grouped_product(hidden, down, inside, tile)
         return lax.dynamic_update_slice(results, out, (lo, 0))
 
     results = lax.fori_loop(
-        0, (ends[-1] + _PAIR_CHUNK - 1) // _PAIR_CHUNK, one_chunk,
-        lax.empty((n_chunks * _PAIR_CHUNK, h), x.dtype))
+        0, (ends[-1] + chunk - 1) // chunk, one_chunk,
+        lax.empty((n_chunks * chunk, h), x.dtype))
     return _held_picks_sum(
         results, ~here.reshape(k, n_tokens),
         # the place of pair p among the sorted is where p sorts among the

@@ -1,0 +1,63 @@
+"""Kernels of the executor's main path compiled at real widths for a v5e that
+is described, not attached: what the Pallas interpreter cannot refuse (a tile
+that does not fit the scoped VMEM, a slice off the tiling) the chip's
+compiler does, here, at no chip time. Nothing runs: no result, no time.
+
+The topology is described inside a fixture and only there: the TPU's library
+belongs to one process at a time, so nothing here may touch it while a module
+is imported, and these tests stay in this one file."""
+
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as error:  # no TPU compiler here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("tokens,tile,chunk", [
+    (512, 128, 4096),      # a generating pass of sdar_30b_a3b.gen64
+    (2048, 256, 12288),    # 128 pairs an expert: the middle tile, two chunks
+])
+def test_expert_ffn_compiles_for_a_v5e_at_each_row_tile(
+        tokens, tile, chunk, one_chip, monkeypatch):
+    """One layer's ``ExpertFFN`` of ``sdar_30b_a3b`` (top-8 of 128 experts,
+    h 2,048, f 768, ``swiglu``) with the megablox kernel on, at the two row
+    tiles ``ops._expert_tiling`` gives under 512 expected pairs an expert:
+    their whole-``k`` weight tiles fit the chip's scoped VMEM. (The 512-row
+    tile's program is every earlier one's and takes 20 s to compile.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.onnx import ops
+
+    h, f, experts, k = 2048, 768, 128, 8
+    assert ops._expert_tiling(tokens * k, experts) == (tile, chunk)
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def expert_ffn(*inputs):
+        return ops._expert_ffn(
+            list(inputs), dict(first_expert=0, num_experts=experts,
+                               activation="swiglu"), {"n_outputs": 1})
+
+    compiled = jax.jit(expert_ffn).lower(
+        shape((1, tokens, h), jnp.bfloat16), shape((1, tokens, k), jnp.int32),
+        shape((1, tokens, k), jnp.float32),
+        shape((experts, h, f), jnp.bfloat16),
+        shape((experts, f, h), jnp.bfloat16),
+        shape((experts, h, f), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3

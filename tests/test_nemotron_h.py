@@ -106,14 +106,16 @@ def _one_node_model(op_type, inputs, attrs, initializers=None, domain="",
 
 
 def _small_chunks(monkeypatch, chunk):
-    """``_PAIR_CHUNK`` of ``chunk`` sorted pairs (None: one chunk holds
-    them all), and no program of an earlier test to borrow."""
+    """``ExpertFFN`` in chunks of ``chunk`` sorted pairs, whatever
+    ``ops._expert_tiling`` would give (None: what it gives, one chunk that
+    holds them all), and no program of an earlier test to borrow."""
     import weakref
 
     from synapseml_tpu.onnx import importer, ops
 
     if chunk:
-        monkeypatch.setattr(ops, "_PAIR_CHUNK", chunk)
+        monkeypatch.setattr(ops, "_expert_tiling",
+                            lambda n_pairs, num_experts: (chunk, chunk))
     # a live model of the same graph would lend its program
     monkeypatch.setattr(importer, "_PROGRAMS", weakref.WeakValueDictionary())
 
@@ -266,8 +268,8 @@ def test_expert_rows_past_a_chunks_last_group_never_reach_the_sum(monkeypatch):
     want = _share(feeds, w, 2, 4)
     plain = ops._grouped_product
 
-    def nan_past_the_groups(lhs, rhs, sizes):
-        out = plain(lhs, rhs, sizes)
+    def nan_past_the_groups(lhs, rhs, sizes, rows):
+        out = plain(lhs, rhs, sizes, rows)
         past = jnp.arange(out.shape[0]) >= jnp.sum(sizes)
         return jnp.where(past[:, None], jnp.nan, out)
 

@@ -31,6 +31,8 @@ CONFIG = {
     "builder_kwargs": {"block": BLOCK, "passes": PASSES},
 }
 PROMPT = 16
+# the row tile of ``ops._expert_tiling`` at its smallest
+SMALL_TILE = 128
 
 
 def _reference(model_bytes):
@@ -568,7 +570,8 @@ def test_expert_ffn_swiglu_against_a_loop_over_experts(chunk, policy, limit,
 
     _fresh_programs(monkeypatch)
     if chunk:
-        monkeypatch.setattr(ops, "_PAIR_CHUNK", chunk)
+        monkeypatch.setattr(ops, "_expert_tiling",
+                            lambda n_pairs, num_experts: (chunk, chunk))
     u, w, feeds = _gated_layer(k=3)
     if policy == "bfloat16":
         import jax.numpy as jnp
@@ -583,6 +586,199 @@ def test_expert_ffn_swiglu_against_a_loop_over_experts(chunk, policy, limit,
     got = _gated_share(feeds, w, 0, 8, policy)
     assert got.dtype == np.float32
     assert _relative(got, want) < limit
+
+
+@pytest.mark.parametrize("n_pairs,num_experts,want", [
+    (65536 * 6, 128, (512, 24576)),   # nemotron3_nano.s4096: 3,072 an expert
+    (128 * 256 * 8, 128, (512, 24576)),  # sdar_30b_a3b.gen64's prompt pass
+    (128 * 4 * 8, 128, (SMALL_TILE, 4096)),  # a generating pass of it: 32
+    (512 * 128, 128, (512, 24576)),   # exactly 512 an expert
+    (511 * 128, 128, None),           # one under: a smaller tile
+    (60, 8, (SMALL_TILE, -(-60 // SMALL_TILE) * SMALL_TILE)),
+])
+def test_tile_and_chunk_follow_the_pairs_an_expert_is_expected_to_get(
+        n_pairs, num_experts, want):
+    from synapseml_tpu.onnx import ops
+
+    tile, chunk = ops._expert_tiling(n_pairs, num_experts)
+    if want is None:
+        assert tile < 512
+    else:
+        assert (tile, chunk) == want
+    assert chunk == min(48 * tile, -(-n_pairs // tile) * tile)
+
+
+def test_the_tile_rule_is_monotone_and_a_multiple_of_sixteen():
+    from synapseml_tpu.onnx import ops
+
+    tiles = [ops._expert_tiling(expected * 128, 128)[0]
+             for expected in range(0, 1100)]
+    assert tiles == sorted(tiles) and tiles[0] == SMALL_TILE
+    assert set(tiles) == {128, 256, 512}  # bfloat16 packs 16 rows a tile
+    assert tiles[512] == tiles[-1] == 512 and tiles[511] < 512
+    for n_pairs in (1, 31, 32, 33, 4095, 4097, 24575, 24577, 10 ** 6):
+        for experts in (1, 8, 128):
+            tile, chunk = ops._expert_tiling(n_pairs, experts)
+            assert tile % 16 == 0 and chunk % tile == 0
+            assert chunk <= 48 * tile and chunk - tile < n_pairs
+
+
+def _few_pairs(router, activation, tokens=12, experts=8, k=2):
+    """A layer whose experts are expected ``tokens * k / experts`` = 3 pairs
+    each, and a router's picks: ``even`` (every expert exactly 3),
+    ``crowded`` (every pick on experts 2 and 5) or ``empty_experts`` (picks
+    among 0, 1, 3 and 6 alone)."""
+    u, w, feeds = _gated_layer(seed=17, shape=(1, tokens), experts=experts,
+                               k=k)
+    at = np.arange(tokens * k).reshape(1, tokens, k)
+    index = {"even": at % experts,
+             "crowded": np.broadcast_to(np.array([2, 5]), at.shape),
+             "empty_experts": np.array([0, 1, 3, 6])[
+                 (at % k) * 2 + (at // k) % 2]}[router]
+    if activation == "relu2":
+        w = {n: v for n, v in w.items() if n != "experts_gate"}
+    return w, dict(feeds, index=index.astype(np.int64))
+
+
+def _by_hand(feeds, w, activation):
+    x = feeds["x"].astype(np.float64)
+    out = np.zeros_like(x)
+    w = {n: v.astype(np.float64) for n, v in w.items()}
+    for at in np.ndindex(*feeds["index"].shape):
+        e = int(feeds["index"][at])
+        hidden = x[at[:-1]] @ w["experts_up"][e]
+        if activation == "relu2":
+            hidden = np.maximum(hidden, 0) ** 2
+        else:
+            gate = x[at[:-1]] @ w["experts_gate"][e]
+            hidden = gate / (1 + np.exp(-gate)) * hidden
+        out[at[:-1]] += feeds["weight"][at] * (hidden @ w["experts_down"][e])
+    return out
+
+
+@pytest.mark.parametrize("router", ["even", "crowded", "empty_experts"])
+@pytest.mark.parametrize("activation", ["relu2", "swiglu"])
+@pytest.mark.parametrize("policy,limit", [("float32", 1e-5),
+                                          ("bfloat16", 0.01)])
+def test_expert_ffn_at_a_few_pairs_an_expert(policy, limit, activation,
+                                             router, monkeypatch):
+    """Three pairs an expert, where the rule gives the small tile and one
+    chunk of the pairs rounded up to it: the loop over experts, whichever
+    way the router leans."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.onnx import ops
+
+    _fresh_programs(monkeypatch)
+    w, feeds = _few_pairs(router, activation)
+    assert ops._expert_tiling(feeds["index"].size, 8) == (SMALL_TILE,
+                                                          SMALL_TILE)
+    names = ["experts_up", "experts_down"] + ["experts_gate"] * (
+        activation == "swiglu")
+    model = _model(
+        [ob.node("ExpertFFN", list(feeds) + names, ["y"],
+                 domain="synapseml_tpu", first_expert=0, num_experts=8,
+                 activation=activation)],
+        feeds, ["y"], {n: w[n] for n in names}, opset=23,
+        domain="synapseml_tpu")
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(OnnxFunction(model, dtype_policy=policy)(feeds)["y"])
+    if policy == "bfloat16":
+        def rounded(a):
+            return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+
+        want = _by_hand(dict(feeds, x=rounded(feeds["x"])),
+                        {n: rounded(v) for n, v in w.items()}, activation)
+    else:
+        want = _by_hand(feeds, w, activation)
+    assert np.abs(want).max() > 0.01 and _relative(got, want) < limit
+
+
+def test_the_grouped_kernel_at_the_small_tile_in_interpret_mode():
+    """megablox ``gmm`` with the tiles ``ops._gmm_tiling`` gives the small
+    row tile (the whole of ``k`` in one), run by the Pallas interpreter,
+    against ``lax.ragged_dot``: groups that share a tile, empty groups, a
+    group over several tiles, rows past the last group."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from synapseml_tpu.onnx import ops
+
+    try:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    except ImportError as error:
+        pytest.skip(f"megablox is not importable here: {error}")
+    rng = np.random.default_rng(5)
+    m, k, n = 4 * SMALL_TILE, 256, 128
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
+    rhs = jnp.asarray(rng.standard_normal((8, k, n)) * k ** -0.5,
+                      jnp.bfloat16)
+    sizes = jnp.asarray([3, 0, SMALL_TILE + 8, 17, 0, 30, 2, 20], jnp.int32)
+    tiling = ops._gmm_tiling(SMALL_TILE, k, n, 2)
+    assert tiling == (SMALL_TILE, k, n)
+    try:
+        got = gmm(lhs, rhs, sizes, preferred_element_type=jnp.bfloat16,
+                  tiling=tiling, interpret=True)
+    except NotImplementedError as error:
+        pytest.skip(f"the Pallas interpreter cannot run gmm here: {error}")
+    want = lax.ragged_dot(lhs, rhs, sizes,
+                          preferred_element_type=jnp.float32)
+    held = int(sizes.sum())
+    np.testing.assert_allclose(
+        np.asarray(got[:held], np.float32), np.asarray(want[:held]),
+        rtol=2 ** -7, atol=2 ** -7)
+
+
+def test_the_trace_counts_each_expert_node_under_its_tile(monkeypatch):
+    """A prompt long enough for 512 pairs an expert and passes of 16: the
+    prompt pass's nodes carry the 512-row tile, the two loop bodies' the
+    small one, one count a node; the gauge holds the smallest chunk."""
+    from synapseml_tpu.onnx import ops
+
+    _fresh_programs(monkeypatch)
+    fn = OnnxFunction(zoo.build_model_bytes("SDARMoETiny", seed=8),
+                      dtype_policy="bfloat16")
+    name, rows, length = fn._fn_name, 16, 128
+    prompt_pairs = rows * length * TINY["top_k"]
+    pass_pairs = rows * BLOCK * TINY["top_k"]
+    assert ops._expert_tiling(prompt_pairs, TINY["experts"])[0] == 512
+    small, chunk = ops._expert_tiling(pass_pairs, TINY["experts"])
+    assert small == SMALL_TILE
+    before = _gauge("smt_onnx_expert_tile_total", fn=name)
+    fn({"input_ids": _prompts(rows, seed=5, length=length)})
+    after = _gauge("smt_onnx_expert_tile_total", fn=name)
+    assert {k: v - before.get(k, 0) for k, v in after.items()} == {
+        (name, "512"): TINY["layers"], (name, str(small)): 2 * TINY["layers"]}
+    assert _gauge("smt_onnx_expert_chunk_rows", fn=name) == {(name,): chunk}
+
+
+def test_the_tile_tool_rehearses_on_the_cpu(capsys):
+    """``tools/expert_tile_forms.py --rehearse-on-cpu``: every form answers
+    as the first one does (the chunk alone applies here), none with a
+    time."""
+    import importlib.util
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "expert_tile_forms", os.path.join(ROOT, "tools",
+                                          "expert_tile_forms.py"))
+    expert_tile_forms = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(expert_tile_forms)
+    forms = "512:24576:512,32:load:whole,64:64:512,shipped"
+    assert expert_tile_forms.main(["--rehearse-on-cpu", "--loads",
+                                   "even,mask_ids", "--forms", forms]) == 0
+    head, *lines = [json.loads(line)
+                    for line in capsys.readouterr().out.splitlines()]
+    assert head["rehearsal"] and head["device"]["platform"] == "cpu"
+    assert [(line["load"], line["form"]) for line in lines] == [
+        (load, form) for load in ("even", "mask_ids")
+        for form in forms.split(",")]
+    for line in lines:
+        assert line["finite"] and line["same_bits_twice"]
+        assert line["max_abs_from_first"] < 0.02 and "ms" not in line
+    assert [line["chunk"] for line in lines[:3]] == [24576, 128, 64]
+    assert lines[5]["largest_expert"] > lines[1]["largest_expert"]  # crowded
 
 
 @pytest.mark.parametrize("shares", [2, 4])

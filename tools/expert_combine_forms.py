@@ -73,9 +73,9 @@ def expert_ffn(form, x, index, weight, up, down, first, num_experts,
     """``ops._expert_ffn`` with the combine of ``form``: its sort, sizes and
     chunk loop copied, so that any form can stand behind them (``held_first``
     and ``shipped`` agreeing in bits and time says the copy is true).
-    ``rows`` (the sorted pairs' product rows, ``[n_chunks * _PAIR_CHUNK,
-    h]``) stands in for the gather of token rows and the two grouped
-    products: the combine alone. ``every`` and ``rest_rows`` stand in for
+    ``rows`` (the sorted pairs' product rows, ``[n_chunks * chunk, h]``)
+    stands in for the gather of token rows and the two grouped products:
+    the combine alone. ``every`` and ``rest_rows`` stand in for
     ``held_first``'s own."""
     import jax
     import jax.numpy as jnp
@@ -83,12 +83,12 @@ def expert_ffn(form, x, index, weight, up, down, first, num_experts,
 
     from synapseml_tpu.onnx import ops
 
-    chunk = ops._PAIR_CHUNK
     held, (h, k) = up.shape[0], (x.shape[-1], index.shape[-1])
     tokens = x.reshape(-1, h)
     n_tokens = tokens.shape[0]
     local = index.reshape(-1, k).T.reshape(-1).astype(jnp.int32) - first
     n_pairs = local.shape[0]
+    tile, chunk = ops._expert_tiling(n_pairs, num_experts)
     here = (local >= 0) & (local < held)
     group = jnp.where(here, local, held)
     order = jnp.argsort(group, stable=True)
@@ -108,9 +108,10 @@ def expert_ffn(form, x, index, weight, up, down, first, num_experts,
             return lo, pairs, lax.dynamic_slice(rows, (lo, 0), (chunk, h))
         inside = (jnp.clip(ends, lo, lo + chunk)
                   - jnp.clip(ends - sizes, lo, lo + chunk))
-        hidden = ops._grouped_product(tokens[pairs % n_tokens], up, inside)
+        hidden = ops._grouped_product(tokens[pairs % n_tokens], up, inside,
+                                      tile)
         return lo, pairs, ops._grouped_product(
-            jnp.square(jax.nn.relu(hidden)), down, inside)
+            jnp.square(jax.nn.relu(hidden)), down, inside, tile)
 
     def sorted_results():
         def one_chunk(i, results):
@@ -324,11 +325,10 @@ def main(argv=None):
         size = dict(rows=16, seq=4096, h=2688, f=1856, k=6)
     else:
         size = dict(rows=2, seq=96, h=32, f=48, k=6)
-        ops._PAIR_CHUNK = 64
+        ops._expert_tiling = lambda n_pairs, num_experts: (64, 64)
     print(json.dumps({"device": {"platform": device.platform,
                                  "kind": device.device_kind},
-                      "size": size, "pair_chunk": ops._PAIR_CHUNK,
-                      "rehearsal": not on_chip}), flush=True)
+                      "size": size, "rehearsal": not on_chip}), flush=True)
 
     for load in args.loads.split(","):
         experts, odds = LOADS[load]
@@ -337,13 +337,14 @@ def main(argv=None):
         held_picks = (np.asarray(index) < HELD).sum(-1)
         held_pairs = int(held_picks.sum())
         every = min(size["k"], -(-size["k"] * HELD // experts) + 1)
+        chunk = ops._expert_tiling(index.size, experts)[1]
         rows = jax.jit(functools.partial(expert_ffn, "sorted_rows", first=0,
                                         num_experts=experts))(
             x, index, weight, up, down)
         answers = {}
         for form in args.forms.split(","):
             line = {"load": load, "form": form, "held_pairs": held_pairs,
-                    "chunks": -(-held_pairs // ops._PAIR_CHUNK),
+                    "pair_chunk": chunk, "chunks": -(-held_pairs // chunk),
                     "rows_beyond": int(np.maximum(held_picks - every, 0).sum())}
             if form == "shipped":
                 whole = jax.jit(functools.partial(
