@@ -340,22 +340,26 @@ def _relu2_ffn(nodes, w: _Weights, p: str, u: str, hidden: int, width: int):
     return p + "_down"
 
 
-def _experts(nodes, w: _Weights, p: str, u: str, hidden: int, experts: int,
-             top_k: int, width: int, shared_width: int, scaling: float,
-             first_expert: int, experts_held: int):
-    add = nodes.append
-    # the router in float32, as wide as published whatever is held here
+def _router(nodes, w: _Weights, p: str, u: str, hidden: int, experts: int,
+            top_k: int, scaling: float, weights: str = None):
+    """The sigmoid router of this family and of ``joyai_flash``'s: scores
+    ``sigmoid(u W_r)`` in float32 over every expert, as wide as published
+    whatever is held here; the ``top_k`` largest of scores + bias are chosen,
+    the chosen SCORES renormalised and scaled. Names the picks and their
+    weights. ``weights`` prefixes the router's two tensors where they are
+    not the nodes' own ``p`` (a graph that runs one layer in several
+    passes); they are drawn on first use."""
+    add, wp = nodes.append, weights or p
+    if wp + "_router_w" not in w.store:
+        w.normal(wp + "_router_w", (hidden, experts), hidden ** -0.5)
+        w.normal(wp + "_router_bias", (experts,), 0.01)
     add(node("MatMul", [_cast(nodes, u, p + "_u_f"),
-                        _cast(nodes, w.normal(p + "_router_w",
-                                              (hidden, experts),
-                                              hidden ** -0.5),
-                              p + "_router_w_f")],
+                        _cast(nodes, wp + "_router_w", p + "_router_w_f")],
              [p + "_router"], name=p + "_moe_route"))
     add(node("Sigmoid", [p + "_router"], [p + "_scores"],
              name=p + "_moe_scores"))
     add(node("Add", [p + "_scores",
-                     _cast(nodes, w.normal(p + "_router_bias", (experts,),
-                                           0.01), p + "_router_bias_f")],
+                     _cast(nodes, wp + "_router_bias", p + "_router_bias_f")],
              [p + "_choice"], name=p + "_moe_choice"))
     add(node("TopK", [p + "_choice", w.ints("top_k", [top_k])],
              [p + "_top_v", p + "_top_i"], name=p + "_moe_topk", axis=-1))
@@ -371,9 +375,17 @@ def _experts(nodes, w: _Weights, p: str, u: str, hidden: int, experts: int,
              name=p + "_moe_norm"))
     add(node("Mul", [p + "_top_n", "routed_scaling"], [p + "_top_w"],
              name=p + "_moe_weight"))
+    return p + "_top_i", p + "_top_w"
+
+
+def _experts(nodes, w: _Weights, p: str, u: str, hidden: int, experts: int,
+             top_k: int, width: int, shared_width: int, scaling: float,
+             first_expert: int, experts_held: int):
+    add = nodes.append
+    top_i, top_w = _router(nodes, w, p, u, hidden, experts, top_k, scaling)
     std_up, std_down = hidden ** -0.5, width ** -0.5
     add(node("ExpertFFN",
-             [u, p + "_top_i", p + "_top_w",
+             [u, top_i, top_w,
               w.normal(p + "_experts_up", (experts_held, hidden, width),
                        std_up),
               w.normal(p + "_experts_down", (experts_held, width, hidden),

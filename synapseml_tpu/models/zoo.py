@@ -12,7 +12,10 @@ Models: ResNet-18/50 (v1.5 bottleneck), a BERT-base-style encoder, ViT-B/16, and
 chip's share of the hybrid Mamba-2 / sparse-expert / grouped-query decoder
 ``nemotron_h`` (``models/nemotron_h.py``: weights as BFLOAT16 initializers), and a
 pipeline stage of the block-diffusion sparse-expert decoder ``sdar_moe``, generation
-included (``models/sdar_moe.py``: a ``Loop`` over blocks carrying a key-value cache).
+included (``models/sdar_moe.py``: a ``Loop`` over blocks carrying a key-value cache),
+and one chip's share of the latent-attention sparse-expert decoder ``joyai_llm_flash``,
+generating greedily (``models/joyai_flash.py``: a prompt pass in the expanded form, then a
+``Loop`` of one token a row against a cache of latents in the absorbed form).
 All emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
 can "cut" the head exactly like the reference's ``cutOutputLayers``
 (``ImageFeaturizer.scala:40-197``).
@@ -293,6 +296,8 @@ MODEL_BUILDERS = {
     "NemotronHTiny": lambda **kw: _nemotron_h(**{**NEMOTRON_H_TINY, **kw}),
     "SDARMoE": lambda **kw: _sdar_moe(**kw),
     "SDARMoETiny": lambda **kw: _sdar_moe(**{**SDAR_MOE_TINY, **kw}),
+    "JoyAIFlash": lambda **kw: _joyai_flash(**kw),
+    "JoyAIFlashTiny": lambda **kw: _joyai_flash(**{**JOYAI_FLASH_TINY, **kw}),
 }
 
 # widths of the CPU tests' nemotron_h: every mechanism of the full graph
@@ -321,6 +326,21 @@ def _sdar_moe(**kw) -> ModelProto:
     from .sdar_moe import sdar_moe
 
     return sdar_moe(**kw)
+
+
+# widths of the CPU tests' joyai_flash: every mechanism of the full graph
+# (three different head widths, a latent narrower than the heads' keys, one
+# dense layer and two expert layers under a router wider than its top-k)
+JOYAI_FLASH_TINY = dict(
+    layers=3, hidden=64, vocab=256, heads=4, q_lora_rank=48, kv_lora_rank=32,
+    nope=16, rope=8, v_dim=16, dense_width=96, experts=8, top_k=2,
+    expert_width=32, shared_width=32, experts_held=8, generate=8)
+
+
+def _joyai_flash(**kw) -> ModelProto:
+    from .joyai_flash import joyai_flash
+
+    return joyai_flash(**kw)
 
 
 def build_model_bytes(name: str, **kw) -> bytes:

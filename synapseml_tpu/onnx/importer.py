@@ -554,9 +554,10 @@ class OnnxFunction:
 
     def _record_notes(self, notes: Dict[str, int]) -> None:
         """Once a traced program: how its ``Attention`` and ``Gelu`` nodes
-        were lowered, what its ``ExpertFFN`` nodes are sized for, their form,
-        their row tile and chunk and how they combine, how often its ``Loop``
-        bodies run and what they carry."""
+        were lowered, the widths its ``Attention`` nodes saw, what its
+        ``ExpertFFN`` nodes are sized for, their form, their row tile and
+        chunk and how they combine, how often its ``Loop`` bodies run and
+        what they carry."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
@@ -603,8 +604,17 @@ class OnnxFunction:
             "times a call of the newest traced program runs the body of "
             "each Loop node, outer loops multiplied in",
             ("fn", "loop"), merge="max")
+        widths = reg.counter(
+            "smt_onnx_attention_widths_total",
+            "Attention nodes of a traced program by the width of a head's "
+            "queries and keys, of its values, and the key-value heads: which "
+            "form of attention ran (latent attention expanded has values "
+            "narrower than its keys; absorbed, one key-value head of latents)",
+            ("fn", "qk", "v", "kv_heads"))
         for key, count in notes.items():
-            if key.startswith("expert_combine_"):
+            if key.startswith("attention_widths."):
+                widths.labels(fn, *key.split(".")[1:]).inc(count)
+            elif key.startswith("expert_combine_"):
                 combine.labels(fn, key[len("expert_combine_"):]).inc(count)
             elif key.startswith("expert_form_"):
                 form.labels(fn, key[len("expert_form_"):]).inc(count)

@@ -1444,8 +1444,12 @@ def _attention(inputs, attrs, ctx):
     ``q_num_heads`` / ``kv_num_heads``) or ``[batch, heads, seq, size]``,
     grouped-query (the key-value heads divide the query heads), ``is_causal``,
     ``scale``, and a BOOLEAN ``attn_mask`` broadcastable to ``[batch, heads,
-    q, kv]``. No float mask, past, softcap, second output or
-    ``softmax_precision``: they raise.
+    q, kv]``. V may have a width of its own: the result is ``[batch, seq,
+    heads * v]`` (or ``[batch, heads, seq, v]``), so latent attention is
+    served in both of its forms: queries and keys of 192 against values of
+    128 a head, and every query head against ONE head of cached latents
+    whose values are a slice of the keys. No float mask, past, softcap,
+    second output or ``softmax_precision``: they raise.
 
     The scores are never written where ``parallel.flash.flash_attention``
     runs its kernel (a TPU, sequence lengths that tile) and the mask is one
@@ -1478,9 +1482,9 @@ def _attention(inputs, attrs, ctx):
     else:  # [batch, heads, seq, size] -> the kernel's [batch, seq, heads, size]
         q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
     b, s_q, h, d = q.shape
-    s_k, h_kv = k.shape[1], k.shape[2]
-    if attrs.get("scale") is not None:  # the kernel's own scale is 1/sqrt(d)
-        q = q * jnp.asarray(attrs["scale"] * np.sqrt(d), q.dtype)
+    s_k, h_kv, d_v = k.shape[1], k.shape[2], v.shape[3]
+    scale = attrs.get("scale")  # None: 1 / sqrt(d), on the float32 scores
+    _note(ctx, f"attention_widths.{d}.{d_v}.{h_kv}")
     causal, block = bool(attrs.get("is_causal", 0)), 1
     if isinstance(mask, np.ndarray) and not causal:
         # a constant mask may be one of the kernel's own
@@ -1489,19 +1493,20 @@ def _attention(inputs, attrs, ctx):
             mask, causal, block = None, found > 0, max(found, 1)
     if mask is not None:
         _note(ctx, "attention_masked")
-        out = flash.masked_attention(q, k, v, mask, causal=causal)
+        out = flash.masked_attention(q, k, v, mask, causal=causal,
+                                     scale=scale)
     elif _kernels_on() and flash.auto_blocks_tile(b * h, s_q, s_k, block):
         _note(ctx, "attention_flash")
         out = flash.flash_attention(q, k, v, causal=causal,
-                                    causal_block=block)
+                                    causal_block=block, scale=scale)
     else:
         _note(ctx, "attention_dense")
         if h != h_kv:
             k, v = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
         out = flash.dense_attention(q, k, v, causal=causal,
-                                    causal_block=block)
+                                    causal_block=block, scale=scale)
     if rank == 3:
-        return out.reshape(b, s_q, h * d)
+        return out.reshape(b, s_q, h * d_v)
     return jnp.transpose(out, (0, 2, 1, 3))
 
 

@@ -30,8 +30,10 @@ _NEG = -1e30
 
 
 def dense_attention(q, k, v, causal: bool = False, pv_dtype=None,
-                    causal_block: int = 1):
-    """Reference dense attention, (B, S, H, D) layout, f32 accumulation.
+                    causal_block: int = 1, scale: float = None):
+    """Reference dense attention, (B, S, H, D) layout, f32 accumulation;
+    ``v`` may have a width of its own (the result has it), ``scale``
+    defaults to ``1 / sqrt(D)`` of the queries and keys.
 
     ``pv_dtype`` casts the probabilities for the P@V matmul (e.g. bf16 —
     the performant-XLA baseline bench.py compares flash against; the flash
@@ -40,7 +42,8 @@ def dense_attention(q, k, v, causal: bool = False, pv_dtype=None,
     :func:`flash_attention`."""
     import jax.numpy as jnp
 
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bqhk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
@@ -60,21 +63,25 @@ def dense_attention(q, k, v, causal: bool = False, pv_dtype=None,
     return out.astype(q.dtype)
 
 
-def masked_attention(q, k, v, mask, causal: bool = False):
+def masked_attention(q, k, v, mask, causal: bool = False,
+                     scale: float = None):
     """Dense attention under a boolean ``mask`` (true: visible) that
-    broadcasts to ``[B, H, S_q, S_k]``; (B, S, H, D) layout. The form for a
-    mask only the run knows, as a few queries against a cache filled so far:
-    grouped key-value heads are read as they lie (never repeated in HBM), the
-    two products run in the inputs' type with float32 accumulation, the
-    softmax in float32; the probabilities take the values' type for the
-    second product, as in the kernel."""
+    broadcasts to ``[B, H, S_q, S_k]``; (B, S, H, D) layout, ``v`` ``(B, S_k,
+    H_kv, D_v)`` with a width of its own. The form for a mask only the run
+    knows, as a few queries against a cache filled so far: grouped key-value
+    heads are read as they lie (never repeated in HBM; one head of latents
+    serves every query head), the two products run in the inputs' type with
+    float32 accumulation, the softmax in float32; the probabilities take the
+    values' type for the second product, as in the kernel. ``scale`` (default
+    ``1 / sqrt(D)``) multiplies the float32 scores."""
     import jax.numpy as jnp
 
     b, s_q, h, d = q.shape
     s_k, h_kv = k.shape[1], k.shape[2]
     rep = h // h_kv
     s = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, s_q, h_kv, rep, d), k,
-                   preferred_element_type=jnp.float32) / math.sqrt(d)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(d) if scale is None else s * scale
     mask = jnp.asarray(mask)
     mask = mask.reshape((1,) * (4 - mask.ndim) + mask.shape)
     if causal:
@@ -89,7 +96,7 @@ def masked_attention(q, k, v, mask, causal: bool = False):
     p = p / p.sum(-1, keepdims=True)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, s_q, h, d).astype(q.dtype)
+    return out.reshape(b, s_q, h, v.shape[-1]).astype(q.dtype)
 
 
 @functools.lru_cache(maxsize=256)
@@ -150,10 +157,13 @@ def auto_blocks_tile(bh: int, s_q: int, s_k: int,
 
 def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
                     block_k: int = None, interpret: bool = False,
-                    causal_block: int = 1):
+                    causal_block: int = 1, scale: float = None):
     """Blockwise-online-softmax attention as ONE Pallas kernel.
 
-    ``q`` (B, S_q, H, D), ``k``/``v`` (B, S_k, H_kv, D) -> (B, S_q, H, D).
+    ``q`` (B, S_q, H, D), ``k`` (B, S_k, H_kv, D), ``v`` (B, S_k, H_kv, D_v)
+    -> (B, S_q, H, D_v): the values may have a width of their own (latent
+    attention's expanded form: 192-wide queries and keys, 128-wide values).
+    ``scale`` multiplies the float32 scores (default ``1 / sqrt(D)``).
     ``H_kv`` may divide ``H`` (grouped-query attention): the kernel maps
     each query head's grid step onto its K/V group IN-KERNEL via the block
     index map, so grouped K/V are never expanded in HBM (Llama/Mistral
@@ -175,8 +185,8 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
 
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    h_kv = k.shape[2]
-    if k.shape != (b, s_k, h_kv, d) or v.shape != (b, s_k, h_kv, d):
+    h_kv, d_v = k.shape[2], v.shape[-1]
+    if k.shape != (b, s_k, h_kv, d) or v.shape != (b, s_k, h_kv, d_v):
         raise ValueError(f"shape mismatch: q {q.shape}, k {k.shape}, "
                          f"v {v.shape}")
     if h % h_kv:
@@ -238,17 +248,18 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         return dense_attention(q, k, v, causal=causal,
-                               causal_block=causal_block)
+                               causal_block=causal_block, scale=scale)
 
     # (B, S, H, D) -> (B*H, S, D): batch*head is the embarrassing grid axis.
     # K/V keep their GROUPED head count; the kernel's index map divides.
     def to_bh(x):
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(
-            b * x.shape[2], x.shape[1], d)
+            b * x.shape[2], x.shape[1], x.shape[3])
 
     out = _flash_bh(to_bh(q), to_bh(k), to_bh(v), bool(causal), int(block_q),
-                    int(block_k), int(rep), bool(interpret), causal_block)
-    return (out.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)).astype(q.dtype)
+                    int(block_k), int(rep), bool(interpret), causal_block,
+                    None if scale is None else float(scale))
+    return (out.reshape(b, h, s_q, d_v).transpose(0, 2, 1, 3)).astype(q.dtype)
 
 
 @functools.lru_cache(maxsize=1)
@@ -263,26 +274,28 @@ def _flash_bh_jit():
 
     return profiled_jit(_flash_bh_impl, name="flash.attention",
                         static_argnames=("causal", "block_q", "block_k",
-                                         "rep", "interpret", "causal_block"))
+                                         "rep", "interpret", "causal_block",
+                                         "scale"))
 
 
 def _flash_bh(q, k, v, causal, block_q, block_k, rep, interpret,
-              causal_block=1):
+              causal_block=1, scale=None):
     return _flash_bh_jit()(q, k, v, causal=causal, block_q=block_q,
                            block_k=block_k, rep=rep, interpret=interpret,
-                           causal_block=causal_block)
+                           causal_block=causal_block, scale=scale)
 
 
 def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret,
-                   causal_block=1):
+                   causal_block=1, scale=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    s_k, d_v = k.shape[1], v.shape[2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     nk = s_k // block_k
     # causal diagonal sits at the END of the key axis (ring/decode layout)
     diag_off = s_k - s_q
@@ -364,18 +377,18 @@ def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bhi, i, j: (bhi, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d_v), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d),
+        out_specs=pl.BlockSpec((1, block_q, d_v),
                                lambda bhi, i, j: (bhi, i, 0)),
         # output in the INPUT dtype: the caller casts to q.dtype anyway, and
         # an f32 out block doubles what the (2048, 1024) class keeps in
         # scoped VMEM
-        out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, s_q, d_v), q.dtype),
         scratch_shapes=[
             # running max (lane 0) + denominator (lane 1)
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),    # running numerator
+            pltpu.VMEM((block_q, d_v), jnp.float32),  # running numerator
         ],
         compiler_params=None if interpret else pltpu.CompilerParams(
             # bh/q-block steps are independent; only the key-block walk

@@ -61,3 +61,24 @@ def test_expert_ffn_compiles_for_a_v5e_at_each_row_tile(
         shape((experts, f, h), jnp.bfloat16),
         shape((experts, h, f), jnp.bfloat16)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(one_chip):
+    """The prompt pass's attention of ``joyai_llm_flash``: queries and keys
+    of 192 = 128 + 64 numbers a head as they lie (a block's last dimension
+    is the array's own, not a multiple of 128), values and result of 128,
+    ``[16, 4096, 32]`` causal, at the blocks ``flash._pick_blocks`` gives:
+    Mosaic takes the contraction and the blocks fit the scoped VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import flash
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((16, 4096, 32, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(lambda q, k, v: flash.flash_attention(
+        q, k, v, causal=True)).lower(shape(192), shape(192),
+                                     shape(128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1

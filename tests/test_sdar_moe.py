@@ -352,16 +352,16 @@ def test_rotary_embedding_turns_pairs_by_their_position(layout, interleaved,
     assert _relative(narrow, want) < 0.01
 
 
-def _attention_by_hand(q, k, v, visible):
-    """``q [b, sq, h, d]``, ``k``/``v [b, sk, hkv, d]``, ``visible [b, h, sq,
-    sk]``; float64."""
+def _attention_by_hand(q, k, v, visible, scale=None):
+    """``q [b, sq, h, d]``, ``k [b, sk, hkv, d]``, ``v [b, sk, hkv, dv]``,
+    ``visible [b, h, sq, sk]``; float64 ``[b, sq, h, dv]``."""
     b, sq, h, d = q.shape
     rep = h // k.shape[2]
-    out = np.zeros_like(q, dtype=np.float64)
+    out = np.zeros((b, sq, h, v.shape[-1]), dtype=np.float64)
     for n in range(b):
         for head in range(h):
             s = q[n, :, head].astype(np.float64) @ k[n, :, head // rep].T \
-                / np.sqrt(d)
+                * (scale or 1 / np.sqrt(d))
             s = np.where(visible[n, head], s, -np.inf)
             p = np.exp(s - s.max(-1, keepdims=True))
             out[n, :, head] = (p / p.sum(-1, keepdims=True)) @ v[n, :,

@@ -13,7 +13,8 @@ compiled program's own text, whose ``metadata={op_name=...}`` holds the scope
 ``<op_type>.<node name>`` the executor traced the instruction under. A fusion
 carries its root's scope. Layer indices fold (``l3_`` -> ``l#_``); a node
 name's first letters keep the pass apart where a builder names them so
-(``p_`` prompt, ``b_`` a loop body, ``c_`` a commit pass). Container
+(``p_`` prompt, ``b_`` a loop body, ``c_`` a commit pass, ``d_`` a decode
+pass). Container
 operations (``while``, ``conditional``, ``call``) span their bodies' own
 events and are left out of the sums. One JSON line: ms a call by node,
 largest first, the sum, and the union (``busy_ms``). Needs a TPU.
