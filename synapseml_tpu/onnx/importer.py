@@ -566,10 +566,14 @@ class OnnxFunction:
             "Attention nodes of a traced program by lowering: flash (the "
             "Pallas kernel, scores never written), dense (materialised "
             "scores where the kernel could have served: not a TPU, or "
-            "lengths that do not tile) or masked (a mask only the run "
-            "knows: the grouped dense form is the lowering)",
+            "lengths that do not tile), cached (a mask over key positions "
+            "that only the run knows, one head size of a multiple of 128: "
+            "the Pallas kernel for a few queries against a cache, which "
+            "reads it once as it lies and keeps the scores in VMEM) or "
+            "masked (any other mask only the run knows, or no TPU: the "
+            "grouped dense form is the lowering)",
             ("fn", "kind"))
-        for kind in ("flash", "dense", "masked"):
+        for kind in ("flash", "dense", "cached", "masked"):
             if "attention_" + kind in notes:
                 lowering.labels(fn, kind).inc(notes["attention_" + kind])
         gelu = reg.counter(

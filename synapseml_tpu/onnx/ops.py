@@ -1456,9 +1456,17 @@ def _attention(inputs, attrs, ctx):
     the kernel has: none, causal, or a constant that reads as causal at a
     granularity of a block of positions. Where the kernel could have served
     and did not, the dense form runs and the program's ``attention_dense``
-    note counts it. Under any other mask (one computed at run time: a few
-    queries against a cache filled so far) the grouped dense form is the
-    lowering, counted as ``attention_masked``."""
+    note counts it. Any other mask is one only the run knows (a few queries
+    against a cache filled so far). Where it spans key positions alone
+    (``[1, kv]``, ``[batch, 1, 1, kv]`` and what broadcasts from them), the
+    kernels are on, queries, keys and values have ONE head size that is a
+    multiple of 128 and a key-value head serves at most 128 query rows (``q``
+    x the query heads that share it, whole sublane tiles of them),
+    ``parallel.flash.cached_attention`` reads the cache once and as it lies
+    and keeps the scores in VMEM: ``attention_cached``. Every other case (a
+    mask a head or a query has to itself, latent attention's one head of
+    576-wide keys over 512-wide values, ``is_causal`` beside a mask, a CPU)
+    takes the grouped dense form, ``attention_masked``."""
     from ..parallel import flash
 
     q, k, v = inputs[:3]
@@ -1492,9 +1500,14 @@ def _attention(inputs, attrs, ctx):
         if found is not None:
             mask, causal, block = None, found > 0, max(found, 1)
     if mask is not None:
-        _note(ctx, "attention_masked")
-        out = flash.masked_attention(q, k, v, mask, causal=causal,
-                                     scale=scale)
+        if not causal and _kernels_on() and flash.cached_attention_takes(
+                q.shape, k.shape, v.shape, mask.shape, q.dtype):
+            _note(ctx, "attention_cached")
+            out = flash.cached_attention(q, k, v, mask, scale=scale)
+        else:
+            _note(ctx, "attention_masked")
+            out = flash.masked_attention(q, k, v, mask, causal=causal,
+                                         scale=scale)
     elif _kernels_on() and flash.auto_blocks_tile(b * h, s_q, s_k, block):
         _note(ctx, "attention_flash")
         out = flash.flash_attention(q, k, v, causal=causal,

@@ -24,7 +24,8 @@ from __future__ import annotations
 import functools
 import math
 
-__all__ = ["flash_attention", "dense_attention", "masked_attention"]
+__all__ = ["flash_attention", "dense_attention", "masked_attention",
+           "cached_attention", "cached_attention_takes"]
 
 _NEG = -1e30
 
@@ -396,3 +397,191 @@ def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
+
+
+# what a grid step of the cached kernel may hold in VMEM: its blocks of keys
+# and values, each fetched while the one before is used, and its float32
+# scores (half of the 16 MiB a v5e kernel has by default)
+_CACHED_VMEM = 8 << 20
+# rows a step takes before the key axis is kept whole: under 8 a step's
+# fixed cost shows (4 rows a step read 9 % slower than 8 in the table)
+_CACHED_ROWS = 8
+# query rows a key-value head may bring (positions x the query heads that
+# share it) for the kernel to be the form: the matrix unit's height (at 128
+# the table reads 3.4 times the dense form's speed, at the cell's 32 it reads
+# 4.1; beyond, the products are no small ones and nothing is measured)
+_CACHED_QUERY_ROWS = 128
+
+
+def _key_mask_rows(mask_shape, b: int, s_k: int):
+    """1 or ``b`` where a mask of that shape spans key positions alone
+    (``[1, S_k]``, ``[B, 1, 1, S_k]`` and what broadcasts from them), else
+    None: a mask a head or a query has to itself."""
+    shape = (1,) * (4 - len(mask_shape)) + tuple(mask_shape)
+    if len(shape) != 4 or shape[1:] != (1, 1, s_k) or shape[0] not in (1, b):
+        return None
+    return shape[0]
+
+
+def cached_attention_takes(q_shape, k_shape, v_shape, mask_shape,
+                           dtype) -> bool:
+    """Whether :func:`cached_attention` compiles for these shapes ((B, S, H,
+    D) layout) and is the form for them: ONE head size, a multiple of the 128
+    lanes, for queries, keys and values (a key-value head is then a column
+    block of the cache as it lies); key-value heads that divide the query
+    heads; a few query rows a key-value head, whole sublane tiles of them; a
+    mask over key positions alone."""
+    import numpy as np
+
+    b, s_q, h, d = q_shape
+    s_k, h_kv = k_shape[1], k_shape[2]
+    sublanes = 32 // np.dtype(dtype).itemsize
+    if tuple(k_shape) != (b, s_k, h_kv, d) or tuple(v_shape) != tuple(k_shape):
+        return False
+    if d % 128 or h % h_kv or s_k % sublanes:
+        return False
+    n = s_q * (h // h_kv)
+    return n % sublanes == 0 and n <= _CACHED_QUERY_ROWS \
+        and _key_mask_rows(mask_shape, b, s_k) is not None
+
+
+def _cached_blocks(rows: int, n: int, s_k: int, d: int, itemsize: int):
+    """Rows and key positions a grid step of the cached kernel takes, from
+    the VMEM it may use. A (row, key position) pair costs its key and its
+    value twice (the next block is fetched meanwhile) and three float32
+    numbers a query row (the score, its exponential, the rounded copy). The
+    whole key axis is one block where 8 rows of it fit (no running maximum,
+    no rescaling: at 320 positions key blocks of 128 take half as long
+    again), else blocks of the multiple of 128 keys that 8 rows fit; then as
+    many rows as fit, a divisor of ``rows`` (8 to 32 rows a step read within
+    2 % of each other). ``tools/cached_attention_forms.py`` has the table
+    (PERF.md section 6, PR 35)."""
+    fit = _CACHED_VMEM // (4 * d * itemsize + 12 * n)
+    few = min(rows, _CACHED_ROWS)
+    block_k = s_k if few * s_k <= fit else max(128, fit // few // 128 * 128)
+    most = max(1, min(rows, fit // block_k))
+    return next(r for r in range(most, 0, -1) if rows % r == 0), block_k
+
+
+def cached_attention(q, k, v, mask, scale: float = None,
+                     interpret: bool = False):
+    """A few queries against a key-value cache under a boolean ``mask`` over
+    key positions, as ONE Pallas kernel: ``q`` (B, S_q, H, D), ``k`` and
+    ``v`` (B, S_k, H_kv, D), ``mask`` (true: visible) ``[1, S_k]``, ``[B, 1,
+    1, S_k]`` or what broadcasts from them; -> (B, S_q, H, D). The
+    mathematics is :func:`masked_attention`'s (products in the inputs' type
+    with float32 accumulation, ``scale`` on the float32 scores, the finite
+    ``-1e30`` where the mask is false, the softmax in float32, the
+    probabilities rounded to the values' type before the second product);
+    what differs is where the numbers go:
+
+    - the cache is read ONCE and as it lies: a grid step takes a block of
+      rows and one key-value head as a 128-lane-multiple column block of
+      ``[B, S_k, H_kv x D]`` (the reshape below undoes the caller's and
+      moves nothing), so no ``[B, S_k, H_kv, D]`` copy is made;
+    - the ``S_q x H / H_kv`` query rows that share the head go against it in
+      one product, and the scores live and die in VMEM;
+    - where the key axis is beyond what a step can hold it goes in blocks
+      under the online softmax of the flash kernel (``m``, ``l``, ``acc``).
+
+    The queries (small) are laid out ``[B, H_kv, S_q x rep, D]`` outside the
+    kernel and the result back. Rows and keys a step: :func:`_cached_blocks`.
+    :func:`cached_attention_takes` says which shapes Mosaic compiles; the
+    interpreter takes any."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s_q, h, d = q.shape
+    s_k, h_kv = k.shape[1], k.shape[2]
+    mask = jnp.asarray(mask)
+    mask_rows = _key_mask_rows(mask.shape, b, s_k)
+    if k.shape != (b, s_k, h_kv, d) or v.shape != k.shape or h % h_kv \
+            or mask_rows is None:
+        raise ValueError(f"cached_attention: q {q.shape}, k {k.shape}, v "
+                         f"{v.shape}, mask {mask.shape}: one head size, "
+                         f"grouped heads and a mask over key positions")
+    rep = h // h_kv
+    n = s_q * rep
+    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
+    rows, block_k = _cached_blocks(b, n, s_k, d, q.dtype.itemsize)
+    n_k = -(-s_k // block_k)
+    ragged = s_k % block_k != 0
+
+    def kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, *carried):
+        j = pl.program_id(2)
+        vb = v_ref[...]                                       # (rows, bk, d)
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask_ref[...] != 0, s, _NEG)            # (rows, n, bk)
+        if ragged:
+            # past the cache's end a block holds whatever lay there: those
+            # keys weigh exactly nothing and their values are zeros
+            def inside(shape, axis):
+                return j * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, shape, axis) < s_k
+
+            s = jnp.where(inside((1, 1, block_k), 2), s, -jnp.inf)
+            vb = jnp.where(inside((1, block_k, 1), 1), vb,
+                           jnp.zeros_like(vb))
+
+        def context(p):
+            return jax.lax.dot_general(p.astype(vb.dtype), vb,
+                                       (((2,), (1,)), ((0,), (0,))),
+                                       preferred_element_type=jnp.float32)
+
+        if n_k == 1:  # the dense form's own order: normalise, round, multiply
+            p = jnp.exp(s - s.max(-1, keepdims=True))
+            o_ref[...] = context(p * (1.0 / p.sum(-1, keepdims=True))
+                                 ).astype(o_ref.dtype)
+            return
+        ml_s, acc_s = carried  # running maximum in lane 0, sum in lane 1
+
+        @pl.when(j == 0)
+        def _():
+            ml_s[:, :, 0:1] = jnp.full((rows, n, 1), _NEG, jnp.float32)
+            ml_s[:, :, 1:2] = jnp.zeros((rows, n, 1), jnp.float32)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        m = ml_s[:, :, 0:1]
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        ml_s[:, :, 1:2] = ml_s[:, :, 1:2] * corr + p.sum(-1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + context(p)
+        ml_s[:, :, 0:1] = m_new
+
+        @pl.when(j == n_k - 1)
+        def _():
+            o_ref[...] = (acc_s[...] / ml_s[:, :, 1:2]).astype(o_ref.dtype)
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(b // rows, h_kv, n_k),
+        in_specs=[
+            # one mask for every row, or a row's own
+            pl.BlockSpec((rows if mask_rows > 1 else 1, 1, block_k),
+                         lambda i, g, j: (i if mask_rows > 1 else 0, 0, j)),
+            pl.BlockSpec((rows, None, n, d), lambda i, g, j: (i, g, 0, 0)),
+            pl.BlockSpec((rows, block_k, d), lambda i, g, j: (i, j, g)),
+            pl.BlockSpec((rows, block_k, d), lambda i, g, j: (i, j, g)),
+        ],
+        out_specs=pl.BlockSpec((rows, None, n, d),
+                               lambda i, g, j: (i, g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h_kv, n, d), q.dtype),
+        scratch_shapes=[] if n_k == 1 else [
+            pltpu.VMEM((rows, n, 128), jnp.float32),
+            pltpu.VMEM((rows, n, d), jnp.float32)],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="cached_attention",
+    )(mask.reshape(mask_rows, 1, s_k).astype(jnp.int32),
+      # [b, s_q, kv, rep, d] -> [b, kv, s_q x rep, d]
+      jnp.transpose(q.reshape(b, s_q, h_kv, rep, d),
+                    (0, 2, 1, 3, 4)).reshape(b, h_kv, n, d),
+      k.reshape(b, s_k, h_kv * d), v.reshape(b, s_k, h_kv * d))
+    return jnp.transpose(out.reshape(b, h_kv, s_q, rep, d),
+                         (0, 2, 1, 3, 4)).reshape(b, s_q, h, d)

@@ -82,3 +82,45 @@ def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(one_chip):
         q, k, v, causal=True)).lower(shape(192), shape(192),
                                      shape(128)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("rows,length,whole", [
+    (128, 320, True),     # a generating pass of sdar_30b_a3b.gen64
+    (16, 4224, False),    # a cache beyond one key block: the online softmax
+])
+def test_cached_attention_compiles_for_a_v5e(rows, length, whole, one_chip,
+                                             monkeypatch):
+    """A layer's cached ``Attention`` of ``sdar_30b_a3b`` (4 queries a row,
+    32 / 4 heads of 128, bfloat16, the mask over key positions) through
+    ``ops._attention`` with the kernels on: Mosaic takes a key-value head as
+    a column block of the rank-3 cache, the batched products and the rows
+    and keys a step ``flash._cached_blocks`` gives, and the cache reaches
+    the kernel as it lies (no copy of it in the compiled program)."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.onnx import ops
+    from synapseml_tpu.parallel import flash
+
+    assert (flash._cached_blocks(rows, 32, length, 128, 2)[1]
+            == length) is whole
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+    notes = {}
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def attention(*inputs):
+        return ops._attention(list(inputs),
+                              dict(q_num_heads=32, kv_num_heads=4),
+                              {"n_outputs": 1, "notes": notes})
+
+    text = jax.jit(attention).lower(
+        shape((rows, 4, 4096)), shape((rows, length, 512)),
+        shape((rows, length, 512)), shape((1, length), jnp.bool_)
+    ).compile().as_text()
+    assert notes["attention_cached"] == 1 and "attention_masked" not in notes
+    assert text.count("tpu_custom_call") == 1
+    cache = f"bf16[{rows},{length},"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and cache in line.split(" copy(")[0]]

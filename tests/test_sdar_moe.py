@@ -446,6 +446,215 @@ def test_attention_of_a_few_queries_against_a_longer_key_axis(policy, limit):
     assert _relative(np.asarray(got).reshape(b, sq, heads, d), want) < limit
 
 
+# heads / key-value heads, queries, cache length, (rows, keys) a step or None
+# for what the kernel picks, scale, a fill of its own a row
+_CACHED_CASES = {
+    "rep_1": (4, 4, 4, 24, None, None, False),
+    "rep_2": (4, 2, 4, 24, None, None, False),
+    "rep_8_one_query": (8, 1, 1, 24, None, None, False),
+    # 17 and the rows' own fills end inside a block of 8 keys
+    "fill_inside_a_key_block": (4, 2, 4, 24, (3, 8), None, False),
+    # 40 = two blocks of 16 and one that hangs over the cache's end
+    "longer_than_a_key_block": (4, 2, 4, 40, (1, 16), None, True),
+    "a_fill_a_row_and_scale": (8, 2, 1, 32, (3, 16), 0.3, True),
+    "scale": (4, 1, 4, 24, None, 0.21, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_CACHED_CASES))
+@pytest.mark.parametrize("policy,limit", [("float32", 1e-5),
+                                          ("bfloat16", 0.02)])
+def test_the_cached_kernel_in_interpret_mode(policy, limit, case,
+                                             monkeypatch):
+    """``flash.cached_attention`` run by the Pallas interpreter against the
+    form written out by hand: grouped heads, one query and four, a fill that
+    ends inside a key block, a cache of several key blocks (the online
+    softmax, the last block hanging over the end), a fill of its own a row,
+    ``scale``."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import flash
+
+    heads, kv, sq, sk, blocks, scale, per_row = _CACHED_CASES[case]
+    rng = np.random.default_rng(11)
+    b, d = 3, 8
+    dtype = jnp.float32 if policy == "float32" else jnp.bfloat16
+    q, k, v = (np.asarray(jnp.asarray(x, dtype).astype(jnp.float32))
+               for x in _qkv(rng, b, sq, sk, heads, kv, d))
+    if per_row:
+        filled = np.asarray([sk, 5, sk - 9])[:, None, None, None]
+    else:
+        filled = np.asarray(17).reshape(1, 1)
+    mask = np.arange(sk) < filled            # [b, 1, 1, sk] or [1, sk]
+    if blocks is not None:
+        monkeypatch.setattr(flash, "_cached_blocks", lambda *a: blocks)
+    with jax.default_matmul_precision("highest"):
+        got = flash.cached_attention(
+            *(jnp.asarray(x, dtype) for x in (q, k, v)), jnp.asarray(mask),
+            scale=scale, interpret=True)
+    assert got.shape == q.shape and got.dtype == dtype
+    want = _attention_by_hand(
+        q, k, v, np.broadcast_to(mask.reshape(-1, 1, 1, sk),
+                                 (b, heads, sq, sk)), scale=scale)
+    assert _relative(np.asarray(got.astype(jnp.float32)), want) < limit
+
+
+def test_the_cached_kernel_refuses_a_mask_a_query_has_to_itself():
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import flash
+
+    q, k, v = (jnp.asarray(x) for x in _qkv(np.random.default_rng(3),
+                                            2, 4, 16, 4, 2, 8))
+    with pytest.raises(ValueError, match="mask over key positions"):
+        flash.cached_attention(q, k, v, jnp.ones((4, 16), bool),
+                               interpret=True)
+
+
+@pytest.mark.parametrize("mask_kind,lowering", [
+    ("key_only", "cached"), ("key_only_a_row", "cached"),
+    ("per_head", "masked"), ("per_query", "masked"),
+    ("latent_576_512_1", "masked"), ("size_64", "masked")])
+def test_attention_picks_the_cached_kernel_by_what_it_sees(
+        mask_kind, lowering, monkeypatch):
+    """With the kernels on, a run-time mask over key positions alone and one
+    head size of a multiple of 128 count ``cached`` and run the kernel; a
+    mask a head or a query has to itself, latent attention's absorbed form
+    (576-wide keys, 512-wide values, one key-value head) and a head size of
+    64 count ``masked`` and trace to ``masked_attention``."""
+    import functools
+
+    import jax
+
+    from synapseml_tpu.onnx import ops
+    from synapseml_tpu.parallel import flash
+
+    _fresh_programs(monkeypatch)
+    rng = np.random.default_rng(12)
+    b, sq, sk, heads, kv, d, dv = 2, 4, 16, 4, 2, 128, 128
+    if mask_kind == "latent_576_512_1":
+        kv, d, dv = 1, 576, 512
+    elif mask_kind == "size_64":
+        d = dv = 64
+    q = rng.standard_normal((b, sq, heads, d), dtype=np.float32)
+    k = rng.standard_normal((b, sk, kv, d), dtype=np.float32)
+    v = k[..., :dv] if dv != d else \
+        rng.standard_normal((b, sk, kv, dv), dtype=np.float32)
+    shape = {"key_only_a_row": (b, 1, 1, sk), "per_head": (1, heads, 1, sk),
+             "per_query": (sq, sk)}.get(mask_kind, (1, sk))
+    mask = rng.random(shape) < 0.6
+    mask[..., 0] = True
+    ran = []
+
+    def counting(name, fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            ran.append(name)
+            return fn(*args, **kwargs)
+        return run
+
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+    monkeypatch.setattr(flash, "cached_attention", counting(
+        "cached", functools.partial(flash.cached_attention, interpret=True)))
+    monkeypatch.setattr(flash, "masked_attention",
+                        counting("masked", flash.masked_attention))
+    feeds = {"q": q.reshape(b, sq, -1), "k": k.reshape(b, sk, -1),
+             "v": v.reshape(b, sk, -1), "mask": mask}
+    fn = OnnxFunction(_model(
+        [ob.node("Attention", ["q", "k", "v", "mask"], ["y"], name="att",
+                 q_num_heads=heads, kv_num_heads=kv)], feeds, ["y"]))
+    before = _gauge("smt_onnx_attention_lowering_total", fn=fn._fn_name)
+    with jax.default_matmul_precision("highest"):
+        got = fn(feeds)["y"]
+    after = _gauge("smt_onnx_attention_lowering_total", fn=fn._fn_name)
+    assert {key: n - before.get(key, 0) for key, n in after.items()
+            if n != before.get(key, 0)} == {(fn._fn_name, lowering): 1}
+    assert ran == [lowering]
+    want = _attention_by_hand(
+        q, k, v, np.broadcast_to(mask.reshape((1,) * (4 - mask.ndim)
+                                              + mask.shape),
+                                 (b, heads, sq, sk)))
+    np.testing.assert_allclose(np.asarray(got).reshape(b, sq, heads, dv),
+                               want, rtol=1e-5, atol=1e-5)
+
+
+def test_the_cached_kernel_takes_whole_tiles_and_fits_its_memory():
+    """What the dispatch asks of the shapes, and the rows and keys a step
+    that follow from the VMEM: the cell's (128 rows, 32 query rows a head, a
+    cache of 320: the whole key axis, a divisor of the rows) and a cache
+    beyond one block (blocks of a multiple of 128 keys)."""
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import flash
+
+    def takes(q, k, v=None, mask=(1, 320), dtype=jnp.bfloat16):
+        return flash.cached_attention_takes(q, k, v or k, mask, dtype)
+
+    cell = (128, 4, 32, 128), (128, 320, 4, 128)
+    assert takes(*cell) and takes(*cell, mask=(128, 1, 1, 320))
+    assert takes((16, 1, 32, 128), (16, 320, 1, 128))     # 32 heads on one
+    assert not takes(*cell, mask=(4, 320))                # a mask a query
+    assert not takes(*cell, mask=(1, 32, 1, 320))         # a mask a head
+    assert not takes((16, 1, 32, 576), (16, 320, 1, 576),
+                     (16, 320, 1, 512))                   # latent, absorbed
+    assert not takes((128, 4, 32, 64), (128, 320, 4, 64))
+    assert not takes((128, 1, 4, 128), (128, 320, 4, 128))    # 1 query row
+    assert not takes((128, 64, 32, 128), (128, 320, 4, 128))  # 512 of them
+    assert takes((128, 1, 32, 128), (128, 320, 4, 128), dtype=jnp.float32)
+    assert not takes((128, 1, 32, 128), (128, 320, 4, 128))   # half a tile
+    rows, keys = flash._cached_blocks(128, 32, 320, 128, 2)
+    assert keys == 320 and 128 % rows == 0 and rows >= 8
+    rows, keys = flash._cached_blocks(16, 32, 4224, 128, 2)
+    assert keys % 128 == 0 and 128 <= keys < 4224 and rows == 8
+    assert flash._cached_blocks(3, 32, 320, 128, 2) == (3, 320)
+    for n, length in ((32, 320), (128, 320), (32, 65536)):
+        rows, keys = flash._cached_blocks(128, n, length, 128, 2)
+        assert rows * keys * (4 * 128 * 2 + 12 * n) <= flash._CACHED_VMEM
+
+
+def test_the_cached_attention_tool_rehearses_on_the_cpu(capsys):
+    """``tools/cached_attention_forms.py --rehearse-on-cpu``: every form of
+    the kernel answers within a bfloat16 step of the dense form, the kernel's
+    forms count ``cached`` and the dense one ``masked``, none with a time."""
+    import importlib.util
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "cached_attention_forms",
+        os.path.join(ROOT, "tools", "cached_attention_forms.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.main(["--rehearse-on-cpu", "--loads", "cell,long"]) == 0
+    head, *lines = [json.loads(line)
+                    for line in capsys.readouterr().out.splitlines()]
+    assert head["rehearsal"] and head["device"]["platform"] == "cpu"
+    assert [(line["load"], line["form"]) for line in lines] == [
+        (load, form) for load in ("cell", "long")
+        for form in tool.TOY_FORMS[load].split(",")]
+    for line in lines:
+        assert line["finite"] and line["same_bits_twice"]
+        assert line["max_abs_from_dense"] < 0.02 and "ms_a_layer_and_pass" \
+            not in line
+        assert line["lowering"] == {
+            "masked" if line["form"] == "dense" else "cached": head["layers"]}
+    assert [line["rows_keys"] for line in lines[1:5]] == [
+        [2, 48], [4, 48], [2, 16], [4, 32]]
+    # the reader of a compiled loop body: one copy of a cache, one of less
+    text = """
+%body.1 (p: (s32[], bf16[4,48,256])) -> (s32[], bf16[4,48,256]) {
+  %copy.3 = bf16[4,48,2,128]{3,2,1,0} copy(%x)
+  %copy.4 = bf16[4,4,256]{2,1,0} copy(%y)
+  %fusion.2 = bf16[4,48,256]{2,1,0} fusion(%z), kind=kLoop
+}
+ENTRY %main (a: bf16[4,48,256]) -> bf16[4,48,256] {
+  %copy.9 = bf16[4,48,256]{2,1,0} copy(%a)
+  %while.1 = (s32[], bf16[4,48,256]) while(%t), condition=%cond.1, body=%body.1
+}
+"""
+    assert tool.cache_copies_in_loop(text, 4 * 48 * 256) == 1
+
+
 @pytest.mark.parametrize("block", [2, 4, 8])
 @pytest.mark.parametrize("s_q,s_k", [(16, 16), (8, 24)])
 def test_flash_block_granular_causal_mask_in_interpret_mode(block, s_q, s_k):
