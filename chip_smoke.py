@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import logging
 import os
 import shutil
 import signal
@@ -126,18 +125,16 @@ class SmokeDecodeImages(Transformer):
         return table.with_column("data", data)
 
 
-# names of the profiled jits that left the profiled path in this process:
-# ``ProfiledJit`` says so once, as a warning on the package's logger
-LEFT_PROFILED_PATH = set()
+def _left_profiled_path(prefix: str = "") -> list:
+    """Names of the profiled jits that left the profiled path in this
+    process: ``ProfiledJit`` counts each once in
+    ``smt_profiled_jit_fallback_total{fn,why}``."""
+    from synapseml_tpu.observability.metrics import get_registry
 
-
-def _note_who_left(record: logging.LogRecord) -> bool:
-    if "left the profiled path" in str(record.msg):
-        LEFT_PROFILED_PATH.add(record.args[0])
-    return True  # a filter that lets every record through
-
-
-logging.getLogger("synapseml_tpu").addFilter(_note_who_left)
+    fam = get_registry().snapshot()["families"].get(
+        "smt_profiled_jit_fallback_total") or {}
+    return sorted({s["labels"][0] for s in fam.get("series", [])
+                   if s["value"] and s["labels"][0].startswith(prefix)})
 
 
 class SmokeLogitsReply(Transformer):
@@ -151,7 +148,7 @@ class SmokeLogitsReply(Transformer):
         dev = jax.devices()[0]
         stamp = {"platform": dev.platform, "device_kind": dev.device_kind,
                  "device_count": len(jax.devices()), "pid": os.getpid(),
-                 "left_profiled_path": sorted(LEFT_PROFILED_PATH)}
+                 "left_profiled_path": _left_profiled_path()}
         replies = np.empty(table.num_rows, dtype=object)
         replies[:] = [dict(stamp, logits=row.tolist())
                       for row in np.asarray(table["logits"])]
@@ -201,8 +198,7 @@ def _profiled(prefix: str) -> dict:
     from synapseml_tpu.observability.metrics import get_registry
 
     out = _compile_account(get_registry().snapshot()["families"], prefix)
-    out["left_profiled_path"] = sorted(
-        name for name in LEFT_PROFILED_PATH if name.startswith(prefix))
+    out["left_profiled_path"] = _left_profiled_path(prefix)
     return out
 
 

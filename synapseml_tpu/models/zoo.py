@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..observability import spans
 from ..onnx.builder import make_graph, make_model, node, value_info
 from ..onnx.wire import ModelProto, serialize_model
 
@@ -348,4 +349,6 @@ def build_model_bytes(name: str, **kw) -> bytes:
         builder = MODEL_BUILDERS[name]
     except KeyError:
         raise KeyError(f"unknown zoo model {name!r}; available: {sorted(MODEL_BUILDERS)}") from None
-    return serialize_model(builder(**kw))
+    # a model of billions of seeded parameters takes seconds of a set-up
+    with spans.span("ModelZoo", "build_model_bytes"):
+        return serialize_model(builder(**kw))

@@ -11,7 +11,14 @@ fleet-wide through the ordinary snapshot path and exposed at ``/metrics``:
   (``first`` / ``shape`` / ``dtype`` / ``structure`` / ``static`` /
   ``weak_type`` / ``placement``). The compiled executable's ``cost_analysis()`` FLOPs and
   bytes are cached per signature, so every subsequent call is attributed
-  at zero cost.
+  at zero cost. The two halves of a compile are phase spans of their own,
+  ``ProfiledJit.lower`` (the trace of this repo's Python and its lowering)
+  and ``ProfiledJit.compile`` (XLA's compile, or the read of jax's
+  persistent cache); which of the two it was is
+  ``smt_compile_cache_total{fn,result=hit|miss}``, fed by jax's own
+  monitoring events; what the loaded program holds on the device is
+  ``smt_program_memory_bytes{fn,kind}``; and an entry point that gave the
+  accounting up counts in ``smt_profiled_jit_fallback_total{fn,why}``.
 - **FLOPs / bytes per stage**: calls through profiled entry points
   accumulate their executable's FLOPs/bytes into a thread-local; the
   stage-span hook (installed into ``observability.spans``) reads the
@@ -55,7 +62,8 @@ import os
 import sys
 import threading
 from time import perf_counter as _perf_counter
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 from . import spans as _spans
 from .metrics import MetricsRegistry, get_registry
@@ -446,6 +454,84 @@ class _CompiledEntry:
 _BOUND = "__bound_arguments__"  # marks ``ProfiledJit.bind``'s signature entry
 
 
+class _Compiling(threading.local):
+    """The ``ProfiledJit`` whose ``lowered.compile()`` runs on this thread."""
+    jit = None
+
+
+_COMPILING = _Compiling()
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_listening = False
+_listening_lock = threading.Lock()
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    """jax's persistent compile cache says what a compile was: a ``hit``
+    (the program was read from the cache) or a ``miss`` (it was compiled
+    and written there). Counted for the ``ProfiledJit`` that is compiling
+    on this thread; any other compile, and every other event, is not
+    this accounting's."""
+    result = _CACHE_EVENTS.get(event)
+    pj = _COMPILING.jit
+    if result is not None and pj is not None:
+        _families(get_registry()).cache.labels(pj.name, result).inc()
+
+
+def _listen_to_jax() -> None:
+    """Register the listener once a process; called where jax is known
+    loaded (a compile is about to run)."""
+    global _listening
+    if _listening:
+        return
+    with _listening_lock:
+        if not _listening:
+            from jax import monitoring
+
+            monitoring.register_event_listener(_on_jax_event)
+            _listening = True
+
+
+_MEMORY_KINDS = (("arguments", "argument_size_in_bytes"),
+                 ("outputs", "output_size_in_bytes"),
+                 ("temporaries", "temp_size_in_bytes"),
+                 ("code", "generated_code_size_in_bytes"))
+
+
+class _LoadFamilies(NamedTuple):
+    cache: Any     # smt_compile_cache_total{fn,result}
+    fallback: Any  # smt_profiled_jit_fallback_total{fn,why}
+    memory: Any    # smt_program_memory_bytes{fn,kind}
+
+
+def _families(reg: MetricsRegistry) -> _LoadFamilies:
+    """The three families of ``reg`` that say what a load was, which entry
+    points left the accounting, and what a loaded program holds. Declared
+    together at an entry point's first compile, so
+    a registry that has seen a compile has all three and a healthy zero
+    reads as a family with no series, not as a missing family."""
+    cache = _series_cache(reg)
+    got = cache.get("load")
+    if got is None:
+        got = cache["load"] = _LoadFamilies(
+            reg.counter(
+                "smt_compile_cache_total",
+                "compiles of profiled jit entry points by what jax's "
+                "persistent cache did: hit (read from it) or miss "
+                "(compiled, then written to it)", ("fn", "result")),
+            reg.counter(
+                "smt_profiled_jit_fallback_total",
+                "profiled jit entry points that left the profiled path for "
+                "plain jax.jit: their compiles go unrecorded from then on",
+                ("fn", "why")),
+            reg.gauge(
+                "smt_program_memory_bytes",
+                "device bytes of a compiled program by kind, from "
+                "memory_analysis(): the largest of an entry point's "
+                "signatures", ("fn", "kind"), merge="max"))
+    return got
+
+
 class ProfiledJit:
     """``jax.jit`` with compile/cost accounting.
 
@@ -561,7 +647,8 @@ class ProfiledJit:
                 # for this fn — stop retrying it (accounting is optional,
                 # the computation is not).
                 out = self._plain_jit()(*args, *bound, **kwargs)
-                self._leave_profiled_path("lower/compile failed")
+                self._leave_profiled_path("compile_failed",
+                                          "lower/compile failed")
                 return out
         try:
             # the runtime's part of a dispatch: enqueue, and as much of
@@ -574,24 +661,29 @@ class ProfiledJit:
             # did not capture (donation, exotic shardings): permanent
             # plain fallback for this fn — plain jit handles these by
             # recompiling, and accounting is optional
-            self._leave_profiled_path(f"compiled call refused: {e}")
+            self._leave_profiled_path("call_refused",
+                                      f"compiled call refused: {e}")
             return self._plain_jit()(*args, *bound, **kwargs)
         acc = _ACC
         acc.flops += entry.flops
         acc.bytes += entry.bytes
         return out
 
-    def _leave_profiled_path(self, why: str) -> None:
+    def _leave_profiled_path(self, why: str, detail: str) -> None:
         """This entry point runs through plain ``jax.jit`` from here on:
         the computation is unaffected, its compiles and costs go
-        unrecorded. Said once, as a warning — every FLOPs and compile-time
-        figure for ``name`` is missing from then on."""
+        unrecorded. Said once, as a warning and as one count of
+        ``smt_profiled_jit_fallback_total{fn,why}`` — every FLOPs and
+        compile-time figure for ``name`` is missing from then on, so a
+        window with no recorded compile is healthy only where this
+        counter stands at zero."""
         import logging
 
         self._left_profiled_path = True
+        _families(get_registry()).fallback.labels(self.name, why).inc()
         logging.getLogger("synapseml_tpu").warning(
             "profiled jit %r left the profiled path (%s); compile and cost "
-            "accounting for it stop here", self.name, why)
+            "accounting for it stop here", self.name, detail)
 
     def _compile(self, sig, args, full_kwargs):
         # the lock is deliberately NOT held across lower/compile (lint
@@ -603,13 +695,23 @@ class ProfiledJit:
         # dropped, so compiles are still recorded exactly once.
         import jax
 
+        _listen_to_jax()
+        _families(get_registry())  # a healthy zero needs its family
         t0 = _perf_counter()
         try:
-            lowered = jax.jit(
-                self._fn,
-                static_argnames=self._static_argnames or None,
-            ).lower(*args, **full_kwargs)
-            compiled = lowered.compile()
+            # this repo's part: the function's Python traced, then lowered
+            with _spans.span("ProfiledJit", "lower"):
+                lowered = jax.jit(
+                    self._fn,
+                    static_argnames=self._static_argnames or None,
+                ).lower(*args, **full_kwargs)
+            # XLA's part, or the cache's: the listener says which it was
+            _COMPILING.jit = self
+            try:
+                with _spans.span("ProfiledJit", "compile"):
+                    compiled = lowered.compile()
+            finally:
+                _COMPILING.jit = None
         except Exception:
             import logging
 
@@ -629,7 +731,25 @@ class ProfiledJit:
         cause = _classify_recompile(self._last_sig, sig)
         self._last_sig = sig
         self._record_compile(dt, cause, flops)
+        self._record_memory(compiled)
         return entry
+
+    def _record_memory(self, compiled) -> None:
+        """What the loaded program holds on the device, by kind; silent
+        where the runtime gives no ``memory_analysis()``."""
+        try:
+            stats = compiled.memory_analysis()
+        except Exception:
+            return
+        if isinstance(stats, (list, tuple)):  # one a partition under SPMD
+            stats = stats[0] if stats else None
+        if stats is None:
+            return
+        gauge = _families(get_registry()).memory
+        for kind, attr in _MEMORY_KINDS:
+            value = getattr(stats, attr, None)
+            if value is not None:
+                gauge.labels(self.name, kind).set_max(float(value))
 
     def _record_compile(self, dt: float, cause: str, flops: float) -> None:
         jax = _jax_if_loaded()

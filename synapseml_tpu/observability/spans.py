@@ -9,7 +9,12 @@ span into the process-default :class:`~.metrics.MetricsRegistry`:
   ``cold`` label carries the compile-vs-execute split: ``cold="1"`` marks
   the first call of that method on that stage *instance* — for jitted
   stages that is the call paying trace + XLA compile, so warm-path latency
-  (``cold="0"``) is queryable separately from compile spikes.
+  (``cold="0"``) is queryable separately from compile spikes. A phase
+  span INHERITS it: opened on the thread of a stage span that is
+  ``cold="1"``, it records under ``cold="1"`` too, so the first call's
+  phases (the model's import, the program's load, the first execution)
+  are one account beside the call they are part of, and no warm series
+  holds a set-up sample.
 - ``smt_stage_rows_total{stage,method}`` — row throughput counter (rows =
   output rows for ``transform``, input rows for ``fit``). Call counts are
   the histogram's own ``_count`` (summed over ``cold``) — no separate
@@ -21,7 +26,8 @@ Two kinds of span share the machinery. A **stage span** (``stage_span``:
 one ``transform``/``fit`` of a stage instance) also runs the
 device-profiling hook, which attributes FLOPs and samples device memory. A
 **phase span** (``span``: a step inside a stage, such as
-``ONNXModel.dispatch``) is plain: duration, rows, errors, nothing else.
+``ONNXModel.dispatch``) is plain: duration, rows, errors, nothing else;
+outside every stage span it is ``cold="0"`` unless its caller says otherwise.
 
 Every enabled span is also mirrored, for its duration, in two other views:
 
@@ -93,6 +99,15 @@ def is_enabled() -> bool:
 _cache_lock = threading.Lock()
 
 
+class _Coldness(threading.local):
+    """Whether the innermost stage span open on this thread is its
+    instance's first call: what a phase span inherits."""
+    cold = False
+
+
+_COLD = _Coldness()
+
+
 def _series_for(reg: MetricsRegistry, stage: str, method: str):
     """(duration_cold, duration_warm, rows, errors) series, then the
     span's name pair and its profiler label, cached ON the registry —
@@ -131,13 +146,15 @@ class Span:
     keep the hot path at two clock reads + one histogram observe."""
 
     __slots__ = ("_dur", "_rows_c", "_errors", "_t0", "rows", "_name",
-                 "_label", "_device", "_trace_span", "_prof0", "_annotation")
+                 "_label", "_device", "_trace_span", "_prof0", "_annotation",
+                 "_cold", "_cold_outside")
 
     def __init__(self, series, cold: bool, rows: Optional[int] = None,
                  device: bool = False):
         (dur_cold, dur_warm, self._rows_c, self._errors, self._name,
          self._label) = series
         self._dur = dur_cold if cold else dur_warm
+        self._cold = cold
         self._device = device  # stage spans run the device-profiling hook
         self.rows = rows
 
@@ -162,11 +179,15 @@ class Span:
         else:
             self._prof0 = prof.enter() if self._device else None
             self._annotation = prof.annotate(self._label, self.rows)
+        if self._device:  # a stage span: its phases inherit its coldness
+            self._cold_outside, _COLD.cold = _COLD.cold, self._cold
         self._t0 = _now_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         elapsed_s = (_now_ns() - self._t0) * 1e-9
+        if self._device:
+            _COLD.cold = self._cold_outside
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
         ts = self._trace_span
@@ -222,15 +243,16 @@ def span(stage: str, method: str = "call", cold: bool = False,
          rows: Optional[int] = None):
     """Record a phase span named (``stage``, ``method``) into ``registry``
     (the process default when omitted). ``rows`` known at entry also label
-    the span's annotation in a profiler trace.
+    the span's annotation in a profiler trace. Inside a stage span that is
+    its instance's first call the span is ``cold`` whatever ``cold`` says.
 
     >>> with span("ingest", "decode") as sp:
     ...     sp.set_rows(128)
     """
     if not _enabled:
         return _NOOP
-    return Span(_series_for(registry or get_registry(), stage, method), cold,
-                rows)
+    return Span(_series_for(registry or get_registry(), stage, method),
+                cold or _COLD.cold, rows)
 
 
 def stage_span(stage_obj: Any, method: str):

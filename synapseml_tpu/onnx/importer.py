@@ -143,27 +143,40 @@ class OnnxFunction:
     def __init__(self, model: "ModelProto | bytes", dtype_policy: str = "float32",
                  external_data_dir: "str | None" = None,
                  layout=None):
-        import jax
+        from ..observability import spans
 
+        if dtype_policy not in ("float32", "bfloat16"):
+            raise ValueError(f"unknown dtype_policy {dtype_policy!r}")
+        self.dtype_policy = dtype_policy
+        self._external_dir = external_data_dir
+        self.layout = layout
+        # three phases of a set-up, a span each: the model's bytes read into
+        # host arrays and checked, the weights cast and uploaded, the
+        # program found among those already traced (or entered there)
+        with spans.span("ONNXModel", "parse"):
+            self._parse(model)
+        self._place_weights()
+        with spans.span("ONNXModel", "register_program"):
+            self._register_program()
+
+    def _parse(self, model: "ModelProto | bytes") -> None:
+        """Everything before a byte goes to the device: the protobuf, every
+        initializer as a numpy array, the ops checked, the sharding plan."""
         if isinstance(model, (bytes, bytearray, memoryview)):
             model = parse_model(bytes(model))
         self.model = model
         self.graph = model.graph
         self.opset = model.opset_version
-        if dtype_policy not in ("float32", "bfloat16"):
-            raise ValueError(f"unknown dtype_policy {dtype_policy!r}")
-        self.dtype_policy = dtype_policy
-        self._external_dir = external_data_dir
         # model-local functions: nodes whose (domain, op_type) matches expand
         # to the function body (real exporters emit e.g. LayerNormalization
         # or custom ops this way from IR 8 on)
         self.functions = {(f.domain, f.name): f
                           for f in getattr(model, "functions", [])}
         self.constants: Dict[str, np.ndarray] = {
-            t.name: tensor_to_numpy(t, external_dir=external_data_dir)
+            t.name: tensor_to_numpy(t, external_dir=self._external_dir)
             for t in self.graph.initializer
         }
-        if dtype_policy == "float32":
+        if self.dtype_policy == "float32":
             # a bfloat16 checkpoint computes in float32 under this policy
             # (exact: every bfloat16 is a float32)
             for name, const in self.constants.items():
@@ -186,21 +199,23 @@ class OnnxFunction:
         # sharded placement from __init__ (device_put) and the traced program
         # re-pins it (with_sharding_constraint), so the intent survives
         # however jit stages the closure constants.
-        self.layout = layout
+        layout = self.layout
         self._const_plan: List[Dict[str, Any]] = []
         self._const_specs: Dict[str, Any] = (
             self._plan_const_specs() if layout is not None
             and (getattr(layout, "model_size", 1) > 1
                  or getattr(layout, "fsdp_size", 1) > 1) else {})
         self._fn_name = "onnx." + (getattr(self.graph, "name", "") or "graph")
-        self._place_weights()
+
+    def _register_program(self) -> None:
+        """One entry point for every live ``OnnxFunction`` of this program:
+        two checkpoints of one graph differ in arguments only."""
         policy_and_placement = f"dtype={self.dtype_policy}"
         if self._const_specs:
-            policy_and_placement += ";layout=" + str(layout.describe()) + ";" + \
+            policy_and_placement += ";layout=" + \
+                str(self.layout.describe()) + ";" + \
                 ",".join(f"{n}:{self._const_specs[n]}"
                          for n in sorted(self._const_specs))
-        # one entry point for every live OnnxFunction of this program: two
-        # checkpoints of one graph differ in arguments only
         digest = self._program_digest(policy_and_placement)
         with _PROGRAMS_LOCK:
             program = _PROGRAMS.get(digest)

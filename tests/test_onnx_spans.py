@@ -71,10 +71,19 @@ def one_call(model, table):
         set_registry(prev)
 
 
-def _samples(families, stage, method):
+def _samples(families, stage, method, cold=None):
+    """Samples of one span, under one value of ``cold`` or under both."""
     fam = families.get("smt_stage_duration_seconds", {"series": []})
     return sum(s["count"] for s in fam["series"]
-               if s["labels"][:2] == [stage, method])
+               if s["labels"][:2] == [stage, method]
+               and cold in (None, s["labels"][2]))
+
+
+# what runs once a model (``parse``, ``place_weights``,
+# ``register_program``) or once a program (``lower``, ``compile``): ISSUE 36
+SET_UP = (("ONNXModel", "parse"), ("ONNXModel", "place_weights"),
+          ("ONNXModel", "register_program"), ("ProfiledJit", "lower"),
+          ("ProfiledJit", "compile"))
 
 
 @pytest.mark.parametrize("stage,method,expected", [
@@ -85,10 +94,91 @@ def _samples(families, stage, method):
     ("ProfiledJit", "execute", BUCKETS),
     ("ONNXModel", "fetch", BUCKETS),
     ("ONNXModel", "assemble", 1),
+    *[(stage, method, 0) for stage, method in SET_UP],  # a warm call: none
 ])
 def test_each_span_samples_once_a_bucket_or_once_a_call(one_call, stage,
                                                         method, expected):
     assert _samples(one_call, stage, method) == expected
+    assert _samples(one_call, stage, method, "1") == 0  # all of it warm
+
+
+@pytest.fixture(scope="module")
+def first_calls(table):
+    """Snapshots of a registry of its own after: the first ``transform`` of
+    a fresh model of a graph no other test compiles (three classes), its
+    second, and the first of a second model of the same graph (another
+    seed: the two share one ``_SharedProgram``)."""
+    def fresh(seed):
+        return ONNXModel(
+            model_bytes=build_model_bytes("BERTTiny", seed=seed,
+                                          num_classes=3),
+            feed_dict={"input_ids": "input_ids"},
+            fetch_dict={"logits": "logits", "pooled": "pooled"},
+            batch_size=BUCKET, dtype_policy="bfloat16")
+
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        models, snaps = [], []
+        for seed in (1, None, 2):  # None: the first model again
+            models.append(models[0] if seed is None else fresh(seed))
+            assert models[-1].transform(table)["logits"].shape == (ROWS, 3)
+            snaps.append(reg.snapshot()["families"])
+        assert models[2].fn._program is models[0].fn._program
+        return snaps
+    finally:
+        set_registry(prev)
+
+
+@pytest.mark.parametrize("stage,method,first,second,second_model", [
+    ("ModelZoo", "build_model_bytes", 1, 0, 1),
+    ("ONNXModel", "parse", 1, 0, 1),
+    ("ONNXModel", "place_weights", 1, 0, 1),
+    ("ONNXModel", "register_program", 1, 0, 1),
+    ("ProfiledJit", "lower", 1, 0, 0),
+    ("ProfiledJit", "compile", 1, 0, 0),
+    ("ONNXModel", "transform", 1, 1, 1),
+    ("ONNXModel", "gather", 1, 1, 1),
+    ("ONNXModel", "pad", BUCKETS, BUCKETS, BUCKETS),
+    ("ONNXModel", "dispatch", BUCKETS, BUCKETS, BUCKETS),
+    ("ProfiledJit", "execute", BUCKETS, BUCKETS, BUCKETS),
+    ("ONNXModel", "fetch", BUCKETS, BUCKETS, BUCKETS),
+    ("ONNXModel", "assemble", 1, 1, 1),
+])
+def test_a_first_call_is_cold_with_its_phases_and_holds_the_set_up(
+        first_calls, stage, method, first, second, second_model):
+    """What each of three calls ADDS: a model's first call under
+    ``cold="1"`` with every phase inside it, its second under ``cold="0"``
+    and nothing of a set-up, a second model of the same graph its own import
+    and upload and no second load of the program."""
+    a, b, c = first_calls
+    # the model file is built outside every stage span: never cold
+    cold = "0" if stage == "ModelZoo" else "1"
+    other = "1" if cold == "0" else "0"
+    assert _samples(a, stage, method, cold) == first
+    assert _samples(a, stage, method, other) == 0
+    assert _samples(b, stage, method, "1") == _samples(a, stage, method, "1")
+    assert _samples(b, stage, method, "0") - _samples(a, stage, method, "0") \
+        == second
+    assert _samples(c, stage, method, cold) - _samples(b, stage, method, cold) \
+        == second_model
+    assert _samples(c, stage, method, other) == _samples(b, stage, method,
+                                                         other)
+
+
+def test_the_first_calls_phases_tile_it(first_calls):
+    """The cold series are one account: the five phases of the first call,
+    and inside ``gather`` and ``dispatch`` the five of the set-up, leave of
+    the call only what lies between spans."""
+    dur = {tuple(s["labels"]): s["sum"] for s in
+           first_calls[0]["smt_stage_duration_seconds"]["series"]}
+    call = dur[("ONNXModel", "transform", "1")]
+    phases = sum(dur[("ONNXModel", p, "1")] for p in PHASES)
+    assert phases <= call and call - phases < 0.05
+    set_up = sum(dur[(*pair, "1")] for pair in SET_UP)
+    inside = dur[("ONNXModel", "gather", "1")] + \
+        dur[("ONNXModel", "dispatch", "1")]
+    assert set_up <= inside
 
 
 @pytest.mark.parametrize("family,expected", [

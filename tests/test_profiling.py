@@ -112,6 +112,126 @@ def test_compile_event_lands_in_telemetry_ring(fresh_registry):
 
 
 # ---------------------------------------------------------------------------
+# what a load was (ISSUE 36): trace and compile apart, hit or miss, the
+# program's bytes, an entry point that gave the accounting up
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def own_compile_cache(tmp_path):
+    """jax's persistent cache on, in a directory of this test's own, with
+    every entry kept; the session's settings (``conftest.py``: off) are put
+    back afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    wanted = {"jax_enable_compilation_cache": True,
+              "jax_compilation_cache_dir": str(tmp_path),
+              "jax_persistent_cache_min_compile_time_secs": 0.0,
+              "jax_persistent_cache_min_entry_size_bytes": -1}
+    before = {k: getattr(jax.config, k) for k in wanted}
+    for k, v in wanted.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+    try:
+        yield tmp_path
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+def _same_function():
+    """A new function object of one source: jax traces it anew, and XLA's
+    program for it is the one the cache holds."""
+    def cached_fn(x):
+        return (x * 3.0).sum()
+    return cached_fn
+
+
+def test_the_cache_counter_says_miss_then_hit(fresh_registry,
+                                              own_compile_cache):
+    import jax
+
+    x = np.ones((8,), np.float32)
+    first = profiling.profiled_jit(_same_function(), name="t.cache")
+    assert float(first(x)) == 24.0
+    assert {k: s["value"] for k, s in _series(
+        fresh_registry.snapshot(), "smt_compile_cache_total").items()} == \
+        {("t.cache", "miss"): 1}
+    # a compile that is not a ProfiledJit's, on the same thread: not counted
+    assert float(jax.jit(lambda v: v + 1.0)(x).sum()) == 16.0
+    second = profiling.profiled_jit(_same_function(), name="t.cache")
+    assert float(second(x)) == 24.0
+    assert float(second(x)) == 24.0  # warm: no load at all
+    snap = fresh_registry.snapshot()
+    assert {k: s["value"] for k, s in
+            _series(snap, "smt_compile_cache_total").items()} == \
+        {("t.cache", "miss"): 1, ("t.cache", "hit"): 1}
+    # a load is one sample of the compile's seconds, hit or miss, and one
+    # of each of its two spans
+    assert _series(snap, "smt_compile_seconds")[("t.cache", "cpu")][
+        "count"] == 2
+    spans_ = _series(snap, "smt_stage_duration_seconds")
+    for method in ("lower", "compile"):
+        assert spans_[("ProfiledJit", method, "0")]["count"] == 2
+    lowered_and_compiled = sum(
+        spans_[("ProfiledJit", m, "0")]["sum"] for m in ("lower", "compile"))
+    whole = _series(snap, "smt_compile_seconds")[("t.cache", "cpu")]["sum"]
+    assert lowered_and_compiled <= whole < lowered_and_compiled + 0.05
+
+
+def test_with_no_cache_a_compile_counts_neither_hit_nor_miss(fresh_registry):
+    """The session's cache is off: the family is there (a healthy zero is
+    a family with no series) and holds nothing."""
+    pj = profiling.profiled_jit(lambda x: x - 1.0, name="t.nocache")
+    pj(np.ones((4,), np.float32))
+    families = fresh_registry.snapshot()["families"]
+    assert families["smt_compile_cache_total"]["series"] == []
+    assert families["smt_profiled_jit_fallback_total"]["series"] == []
+
+
+def test_a_compiled_program_says_what_it_holds(fresh_registry):
+    pj = profiling.profiled_jit(lambda a, b: a @ b, name="t.mem")
+    pj(np.ones((16, 32), np.float32), np.ones((32, 8), np.float32))
+    held = {k: s["value"] for k, s in _series(
+        fresh_registry.snapshot(), "smt_program_memory_bytes").items()}
+    assert held[("t.mem", "arguments")] == (16 * 32 + 32 * 8) * 4
+    assert held[("t.mem", "outputs")] == 16 * 8 * 4
+    assert set(k for _, k in held) == {"arguments", "outputs",
+                                       "temporaries", "code"}
+    # a larger signature of the same entry point: the gauge keeps the larger
+    pj(np.ones((32, 32), np.float32), np.ones((32, 8), np.float32))
+    held = _series(fresh_registry.snapshot(), "smt_program_memory_bytes")
+    assert held[("t.mem", "arguments")]["value"] == (32 * 32 + 32 * 8) * 4
+
+
+def test_a_failed_aot_compile_counts_one_fallback_and_still_answers(
+        fresh_registry, monkeypatch, caplog):
+    """The AOT machinery breaks for this function (here: ``compile`` is made
+    to raise); plain ``jax.jit`` answers, the counter says so once, and no
+    compile of it is recorded, now or on a new shape."""
+    import jax
+
+    def broken(self, *a, **kw):
+        raise RuntimeError("no AOT for you")
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", broken)
+    pj = profiling.profiled_jit(lambda x: x * 2.0 + 1.0, name="t.fallback")
+    with caplog.at_level("WARNING", logger="synapseml_tpu"):
+        out = pj(np.ones((4,), np.float32))
+    monkeypatch.undo()
+    assert np.asarray(out).tolist() == [3.0] * 4
+    assert np.asarray(pj(np.ones((6,), np.float32))).tolist() == [3.0] * 6
+    families = fresh_registry.snapshot()["families"]
+    assert {tuple(s["labels"]): s["value"] for s in families[
+        "smt_profiled_jit_fallback_total"]["series"]} == \
+        {("t.fallback", "compile_failed"): 1}
+    assert "smt_compile_seconds" not in families
+    assert sum("left the profiled path" in r.getMessage()
+               for r in caplog.records) == 1
+
+
+# ---------------------------------------------------------------------------
 # per-stage FLOPs / bytes via the span hook (stage spans only)
 # ---------------------------------------------------------------------------
 
