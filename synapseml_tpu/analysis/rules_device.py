@@ -450,25 +450,26 @@ class WeakTypeChurn(DeviceRule):
 # ---------------------------------------------------------------------------
 
 def _build_flash_entry() -> Dict[str, Any]:
-    """``flash.attention`` (``parallel/flash._flash_bh_impl``) under a
-    shrunk ``flash_attention_gqa`` bench-lane signature: (B*H, S, D) bf16
-    with the statics bound the way ``flash_attention`` binds them."""
+    """``flash.attention`` (``parallel/flash._flash_impl``, what
+    ``flash_attention`` jits) under a shrunk grouped-query signature at the
+    head size the executor's cells bring: (B, S, H, D) bf16 with 4 / 2 heads
+    of 128, so the kernel reads its operands where they lie, with the
+    statics bound the way ``flash_attention`` binds them."""
     import functools
 
     import numpy as np
 
     from ..parallel import flash
 
-    q = np.zeros((4, 256, 64), np.dtype("bfloat16"))
-    k = np.zeros((4, 256, 64), np.dtype("bfloat16"))
-    v = np.zeros((4, 256, 64), np.dtype("bfloat16"))
+    q = np.zeros((1, 256, 4, 128), np.dtype("bfloat16"))
+    k = np.zeros((1, 256, 2, 128), np.dtype("bfloat16"))
+    v = np.zeros((1, 256, 2, 128), np.dtype("bfloat16"))
     # interpret=True: the kernel body traces identically, and the Mosaic
     # compiler-params path needs TPU plugin versions the lint host may
     # not have — tracing is the point here, not lowering
-    fn = functools.partial(flash._flash_bh_impl, causal=True, block_q=128,
-                           block_k=128, rep=1, interpret=True)
-    return {"fn": fn, "args": (q, k, v),
-            "anchor_obj": flash._flash_bh_impl}
+    fn = functools.partial(flash._flash_impl, causal=True, block_q=128,
+                           block_k=128, interpret=True)
+    return {"fn": fn, "args": (q, k, v), "anchor_obj": flash._flash_impl}
 
 
 def _tiny_mlp_bytes():

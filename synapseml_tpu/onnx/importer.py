@@ -569,10 +569,10 @@ class OnnxFunction:
 
     def _record_notes(self, notes: Dict[str, int]) -> None:
         """Once a traced program: how its ``Attention`` and ``Gelu`` nodes
-        were lowered, the widths its ``Attention`` nodes saw, what its
-        ``ExpertFFN`` nodes are sized for, their form, their row tile and
-        chunk and how they combine, how often its ``Loop`` bodies run and
-        what they carry."""
+        were lowered, the widths its ``Attention`` nodes saw, where the
+        flash kernel reads their operands, what its ``ExpertFFN`` nodes are
+        sized for, their form, their row tile and chunk and how they
+        combine, how often its ``Loop`` bodies run and what they carry."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
@@ -630,9 +630,20 @@ class OnnxFunction:
             "form of attention ran (latent attention expanded has values "
             "narrower than its keys; absorbed, one key-value head of latents)",
             ("fn", "qk", "v", "kv_heads"))
+        flash_form = reg.counter(
+            "smt_onnx_attention_flash_form_total",
+            "Attention nodes of a traced program that run the flash kernel, "
+            "by where it reads its operands: in_place ([batch, seq, heads x "
+            "size] as the node got them, a head picked by the block index "
+            "map) or heads_first (copies transposed to [batch x heads, seq, "
+            "size] in HBM, and the result back: value heads that are no "
+            "whole 128-lane blocks)",
+            ("fn", "form"))
         for key, count in notes.items():
             if key.startswith("attention_widths."):
                 widths.labels(fn, *key.split(".")[1:]).inc(count)
+            elif key.startswith("attention_flash_form."):
+                flash_form.labels(fn, key.split(".")[1]).inc(count)
             elif key.startswith("expert_combine_"):
                 combine.labels(fn, key[len("expert_combine_"):]).inc(count)
             elif key.startswith("expert_form_"):

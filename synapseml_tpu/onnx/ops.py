@@ -1510,6 +1510,11 @@ def _attention(inputs, attrs, ctx):
                                          scale=scale)
     elif _kernels_on() and flash.auto_blocks_tile(b * h, s_q, s_k, block):
         _note(ctx, "attention_flash")
+        # where the kernel reads its operands: as they lie here, or from
+        # copies laid out heads first (widths that are no blocks of lanes)
+        _note(ctx, "attention_flash_form." + (
+            "in_place" if flash.reads_in_place(d, d_v, q.dtype.itemsize)
+            else "heads_first"))
         out = flash.flash_attention(q, k, v, causal=causal,
                                     causal_block=block, scale=scale)
     else:

@@ -9,11 +9,17 @@ B * 12 * 8k * 8k * 4 bytes = 3 GB HBM traffic per batch element; the flash
 kernel streams K/V blocks through VMEM instead (the standard
 memory-bound-to-compute-bound move).
 
-Layout: inputs (B, S, H, D) like ``ring.py``; the kernel runs per (batch,
-head) over query blocks, with a ``lax.fori_loop`` over key blocks carrying
-the (m, l, acc) online-softmax state as register values. Masking uses a
-finite ``-1e30`` (an actual ``-inf`` makes ``exp(m - m_new)`` produce NaN
-for fully-masked leading causal rows).
+Layout: inputs (B, S, H, D) like ``ring.py``, which the kernel reads WHERE
+THEY LIE as ``[B, S, H x D]`` (a free reshape): the grid is (batch, group of
+heads, query block, key block), the block index maps pick a group's columns,
+and the key-block walk carries the (m, l, acc) online-softmax state in VMEM
+scratch; widths that are no blocks of 128 lanes go through copies laid out
+heads first (:func:`reads_in_place`). Masking uses a finite ``-1e30`` (an
+actual ``-inf`` makes ``exp(m - m_new)`` produce NaN for fully-masked leading
+causal rows), and only where the causal diagonal crosses: a key block above
+it is neither fetched nor multiplied, one below it is multiplied whole, and
+the tile it crosses goes in sub-tiles that leave out what the mask would
+discard (:func:`_diag_rows`).
 
 ``interpret=True`` runs the same kernel through the Pallas interpreter on
 CPU — the parity tests exercise the kernel logic without TPU hardware.
@@ -28,6 +34,10 @@ __all__ = ["flash_attention", "dense_attention", "masked_attention",
            "cached_attention", "cached_attention_takes"]
 
 _NEG = -1e30
+# what a grid step's blocks and carries may take of VMEM: half of the 16 MiB
+# a v5e kernel has by default, the rest being a tile's float32 scores, their
+# exponentials and the rounded copy
+_STEP_VMEM = 8 << 20
 
 
 def dense_attention(q, k, v, causal: bool = False, pv_dtype=None,
@@ -137,8 +147,14 @@ def _pick_blocks(bh: int, s_q: int, s_k: int):
     - bigger grids (serving batches, B*H >= 32) take (1024, 1024);
     - everything clamps to power-of-2 divisors of the sequence lengths.
 
-    Which class is faster where has not been measured on this toolchain
-    (see PERF.md).
+    Measured on a v5e (``tools/flash_width_forms.py``, PERF.md section 6, PR
+    37) is the second class where the benchmark's cells run it: (1024, 1024)
+    at S = 4,096, where the kernel on operands as they lie reads 51.5 % of
+    the MXU peak at 192 / 128-wide heads and 54.4 % at 128-wide grouped
+    ones, and one (256, 256) tile a head at S = 256. A tile the causal
+    diagonal crosses is cut in two there (:func:`_diag_rows`: halves beat
+    quarters and eighths). The (2048, 1024) class is not measured on this
+    toolchain, and its diagonal's tiles stay whole.
     """
     bq_target = 2048 if (bh < 32 and s_q >= 16384) else 1024
     return (_pow2_divisor(s_q, bq_target), _pow2_divisor(s_k, 1024))
@@ -178,6 +194,11 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
     positions: a query sees every earlier block and all of its own, ``kpos
     <= qpos | (B - 1)``. Only the diagonal tiles' comparison changes; which
     tiles are skipped does not, since B divides the tiles.
+
+    The operands are read where they lie wherever a head's columns are
+    blocks of 128 lanes (:func:`reads_in_place`; no transposed copy in HBM),
+    and the mask costs products only inside the tile it crosses
+    (:func:`_diag_rows`): both follow from the shapes.
 
     This is the long-sequence path: dense attention at S=32k would need
     ~34 GB for the score tensor alone.
@@ -251,20 +272,15 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = None,
         return dense_attention(q, k, v, causal=causal,
                                causal_block=causal_block, scale=scale)
 
-    # (B, S, H, D) -> (B*H, S, D): batch*head is the embarrassing grid axis.
-    # K/V keep their GROUPED head count; the kernel's index map divides.
-    def to_bh(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
-            b * x.shape[2], x.shape[1], x.shape[3])
-
-    out = _flash_bh(to_bh(q), to_bh(k), to_bh(v), bool(causal), int(block_q),
-                    int(block_k), int(rep), bool(interpret), causal_block,
-                    None if scale is None else float(scale))
-    return (out.reshape(b, h, s_q, d_v).transpose(0, 2, 1, 3)).astype(q.dtype)
+    out = _flash_jit()(q, k, v, causal=bool(causal), block_q=int(block_q),
+                       block_k=int(block_k), interpret=bool(interpret),
+                       causal_block=causal_block,
+                       scale=None if scale is None else float(scale))
+    return out.astype(q.dtype)
 
 
 @functools.lru_cache(maxsize=1)
-def _flash_bh_jit():
+def _flash_jit():
     """Profiled jit entry point, applied lazily so importing the package
     never imports jax. ``observability.profiling`` times every compile
     (``smt_compile_seconds{fn="flash.attention"}``), counts recompiles by
@@ -273,33 +289,166 @@ def _flash_bh_jit():
     report the kernel's achieved MFU."""
     from ..observability.profiling import profiled_jit
 
-    return profiled_jit(_flash_bh_impl, name="flash.attention",
+    return profiled_jit(_flash_impl, name="flash.attention",
                         static_argnames=("causal", "block_q", "block_k",
-                                         "rep", "interpret", "causal_block",
+                                         "interpret", "causal_block",
                                          "scale"))
 
 
-def _flash_bh(q, k, v, causal, block_q, block_k, rep, interpret,
-              causal_block=1, scale=None):
-    return _flash_bh_jit()(q, k, v, causal=causal, block_q=block_q,
-                           block_k=block_k, rep=rep, interpret=interpret,
-                           causal_block=causal_block, scale=scale)
+def reads_in_place(d: int, d_v: int, itemsize: int) -> bool:
+    """Whether the kernel reads ``[B, S, H x D]`` operands where they lie. A
+    head of values, and of the result, has to be a block of 128 lanes. A
+    head of queries or keys is a block of lanes too where ``D`` is a
+    multiple of 128; any other ``D`` of whole sublane tiles (latent
+    attention's 192) is read with the POSITIONS minor, ``[B, H x D, S]``,
+    where a head is rows of a block: that is how the compiler lays such
+    heads out anyway (it will not pad 192 lanes to 256 in HBM), so the
+    transpose in front of the kernel is no operation, where row-major
+    operands cost a copy of ``q`` and of ``k`` a layer (2.5 ms each at ``[16,
+    4096, 6144]``; PERF.md section 6, PR 37), and a matrix product writes
+    either layout at the same price. Any other width (64-wide values) is
+    laid out heads first in HBM before the kernel and the result back after
+    it: no cell of the benchmark has one."""
+    return d_v % 128 == 0 and d % (32 // itemsize) == 0
 
 
-def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret,
-                   causal_block=1, scale=None):
+def _diag_rows(block_q: int, block_k: int, diag_off: int,
+               causal_block: int) -> int:
+    """Query rows of a sub-tile of a tile the causal diagonal crosses. Where
+    the tiles are square and the diagonal runs through their corners every
+    such tile looks the same, and rows ``[r x n, (r + 1) x n)`` of it see its
+    first ``(r + 1) x n`` keys, a static slice: ``n`` is half the tile (the
+    upper half leaves the keys of the right half out: 12 / 16 of the tile's
+    products), whole 128-lane tiles of scores and whole blocks of the mask's
+    granularity. Halves read as fast as quarters at 192-wide heads and 5 %
+    faster at 128-wide ones, eighths lose to both
+    (``tools/flash_width_forms.py``, PERF.md section 6, PR 37). Elsewhere
+    (the (2048, 1024) class, an offset off the tiles) the tile is computed
+    whole under the mask: ``block_q``."""
+    rows = block_q // 2
+    if block_q != block_k or diag_off % block_k or rows % 128 \
+            or rows % causal_block:
+        return block_q
+    return rows
+
+
+def _heads_a_step(h: int, h_kv: int, d: int, d_v: int, block_q: int,
+                  block_k: int, itemsize: int) -> int:
+    """Query heads a grid step takes: as many as _STEP_VMEM holds blocks and
+    carries for, a divisor of the heads that share a key-value head (they
+    share its blocks of keys and values, fetched once a step) or, where each
+    has its own, of all heads (fewer, longer steps). A head brings its
+    blocks of queries and result, each fetched or written while the one
+    before is used, and its float32 carries. Measured (PERF.md section 6, PR
+    37): two heads of 192 / 128 a step read 3 % faster than one, two of 16
+    that share a key-value head at (1,024, 1,024) tiles 5 %, eight of eight
+    at (256, 256) tiles 45 %; four at (1,024, 1,024) tiles do not compile
+    for a v5e."""
+    a_head = 2 * block_q * (d + d_v) * itemsize + 4 * block_q * (128 + d_v)
+    keys_values = 2 * block_k * (d + d_v) * itemsize
+    among = h // h_kv
+    if among == 1:  # every head its own keys and values
+        among, a_head, keys_values = h, a_head + keys_values, 0
+    fit = max(1, (_STEP_VMEM - keys_values) // a_head)
+    return max(g for g in range(1, among + 1) if among % g == 0 and g <= fit)
+
+
+def _flash_impl(q, k, v, causal, block_q, block_k, interpret,
+                causal_block=1, scale=None):
+    """``flash_attention`` behind its checks, (B, S, H, D) in and out: the
+    kernel on the operands where they lie (free reshapes to ``[B, S, H x
+    D]``, for heads off the lanes a transpose that is none) where
+    :func:`reads_in_place` says so, else on copies laid out heads first."""
+    import jax.numpy as jnp
+
+    b, s_q, h, d = q.shape
+    s_k, h_kv, d_v = k.shape[1], k.shape[2], v.shape[3]
+    diag_rows = _diag_rows(block_q, block_k, s_k - s_q, causal_block)
+    if reads_in_place(d, d_v, q.dtype.itemsize):
+        q, k = q.reshape(b, s_q, h * d), k.reshape(b, s_k, h_kv * d)
+        minor = d % 128 != 0
+        if minor:  # no operation: the layout such heads have (reads_in_place)
+            q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
+        out = _flash_call(
+            q, k, v.reshape(b, s_k, h_kv * d_v), heads=h, kv_heads=h_kv,
+            group=_heads_a_step(h, h_kv, d, d_v, block_q, block_k,
+                                q.dtype.itemsize),
+            batch_rep=1, causal=causal, block_q=block_q, block_k=block_k,
+            interpret=interpret, causal_block=causal_block,
+            diag_rows=diag_rows, scale=scale, positions_minor=minor)
+        return out.reshape(b, s_q, h, d_v)
+
+    # (B, S, H, D) -> (B*H, S, D): a head is a row of the leading axis, so
+    # its columns are the array's own and any width is a block. K/V keep
+    # their GROUPED head count: query row ``bhi`` reads key-value row ``bhi
+    # // rep`` (since h = rep x h_kv, (batch x h + head) // rep == batch x
+    # h_kv + head // rep)
+    def to_bh(x):
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+            b * x.shape[2], x.shape[1], x.shape[3])
+
+    out = _flash_call(
+        to_bh(q), to_bh(k), to_bh(v), heads=1, kv_heads=1, group=1,
+        batch_rep=h // h_kv, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, causal_block=causal_block, diag_rows=diag_rows,
+        scale=scale)
+    return out.reshape(b, h, s_q, d_v).transpose(0, 2, 1, 3)
+
+
+def _flash_call(q, k, v, *, heads, kv_heads, group, batch_rep, causal,
+                block_q, block_k, diag_rows, interpret, causal_block=1,
+                scale=None, positions_minor=False):
+    """ONE ``pallas_call``: ``q`` (N, S_q, heads x D), ``k`` (N // batch_rep,
+    S_k, kv_heads x D), ``v`` (.., kv_heads x D_v) -> (N, S_q, heads x D_v);
+    ``positions_minor`` takes ``q`` and ``k`` as (.., heads x D, S), a head
+    then being rows of a block (any ``D`` of whole sublane tiles) and the
+    scores a contraction over the leading axis of both. The grid is (row,
+    group of ``group`` query heads, query block, key block); the block index
+    maps pick the group's heads, and for keys and values those of its own
+    ``group`` heads or the ONE head the group shares (``head // rep``), so no
+    operand is laid out again in HBM.
+
+    Key blocks wholly above the causal diagonal are neither fetched (the
+    index map stays at the row of tiles' last block) nor multiplied; blocks
+    wholly below it are multiplied with no mask; a block the diagonal
+    crosses goes in sub-tiles of ``diag_rows`` query rows, each against the
+    keys its rows can see (static slices of the blocks in VMEM), with the
+    comparison and select on the sub-tile the diagonal crosses alone: as two
+    halves 12 / 16 of the tile's products, where the whole tile under the
+    mask (``diag_rows`` = ``block_q``) makes 16 / 16 and throws 6 away."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, s_q, d = q.shape
-    s_k, d_v = k.shape[1], v.shape[2]
+    # queries and keys as (.., S, heads x D) or, positions minor, (.., heads x
+    # D, S): a head's numbers are then rows of a block, not lanes
+    seq, cols = (2, 1) if positions_minor else (1, 2)
+    n, s_q, s_k = q.shape[0], q.shape[seq], k.shape[seq]
+    d, d_v = q.shape[cols] // heads, v.shape[2] // kv_heads
+    rep = heads // kv_heads
+    # key-value heads in a step's blocks: the group's own, or the ONE its
+    # query heads share
+    kv_group = group if rep == 1 else 1
+    if rep % group and rep != 1:
+        raise ValueError(f"{group} query heads a step do not share one of "
+                         f"{kv_heads} key-value heads ({heads} query heads)")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     nk = s_k // block_k
     # causal diagonal sits at the END of the key axis (ring/decode layout)
     diag_off = s_k - s_q
+    if diag_rows != block_q and (block_q != block_k or diag_off % block_k
+                                 or block_q % diag_rows
+                                 or diag_rows % causal_block):
+        raise ValueError(
+            f"sub-tiles of {diag_rows} rows need square tiles the diagonal "
+            f"runs corner to corner through (blocks ({block_q}, {block_k}), "
+            f"offset {diag_off}, causal_block {causal_block})")
+    # a crossing tile as (first row, rows, keys they see, keys under the mask)
+    crossing = [(r, diag_rows, r + diag_rows, diag_rows)
+                for r in range(0, block_q, diag_rows)] \
+        if diag_rows != block_q else [(0, block_q, block_k, block_k)]
 
     # bf16 inputs run the two dots at the MXU's native rate with f32
     # accumulation (p is cast to the value dtype for the PV dot — the
@@ -310,92 +459,139 @@ def _flash_bh_impl(q, k, v, causal, block_q, block_k, rep, interpret,
     in_dt = q.dtype
 
     def kernel(q_ref, k_ref, v_ref, o_ref, ml_s, acc_s):
-        # one (block_q, 128) scratch holds BOTH online-softmax carries (m in
-        # lane 0, l in lane 1): each needs a single lane, and the saved
+        # one (block_q, 128) scratch a head holds BOTH online-softmax carries
+        # (m in lane 0, l in lane 1): each needs a single lane, and the saved
         # block_q x 128 f32 buffer is what lets 2k-wide blocks fit scoped
         # VMEM
-        iq = pl.program_id(1)
-        jk = pl.program_id(2)
+        iq = pl.program_id(2)
+        jk = pl.program_id(3)
 
         @pl.when(jk == 0)
         def _():
-            ml_s[:, 0:1] = jnp.full((block_q, 1), _NEG, jnp.float32)
-            ml_s[:, 1:2] = jnp.zeros((block_q, 1), jnp.float32)
-            acc_s[:] = jnp.zeros_like(acc_s)
+            ml_s[:, :, 0:1] = jnp.full((group, block_q, 1), _NEG, jnp.float32)
+            ml_s[:, :, 1:2] = jnp.zeros((group, block_q, 1), jnp.float32)
+            acc_s[...] = jnp.zeros_like(acc_s)
 
-        def compute():
-            qb = q_ref[0]                                    # (bq, d)
-            kb = k_ref[0]
-            vb = v_ref[0]
-            s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        def update(t, r0, n_rows, n_keys, n_masked):
+            """Rows ``[r0, r0 + n_rows)`` of head ``t`` against the block's
+            first ``n_keys`` keys, the last ``n_masked`` of them under the
+            causal mask."""
+            rows = slice(r0, r0 + n_rows)
+            t_kv = t if rep == 1 else 0
+            vb = v_ref[0, 0:n_keys, t_kv * d_v:(t_kv + 1) * d_v]
+            of_q, of_k = slice(t * d, (t + 1) * d), \
+                slice(t_kv * d, (t_kv + 1) * d)
+            if positions_minor:
+                s = jax.lax.dot_general(
+                    q_ref[0, of_q, rows], k_ref[0, of_k, 0:n_keys],
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            else:
+                s = jax.lax.dot_general(
+                    q_ref[0, rows, of_q], k_ref[0, 0:n_keys, of_k],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
             s = s * scale
-            if causal:
-                qpos = iq * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0) + diag_off
+            if n_masked:
+                seen = n_keys - n_masked
+                qpos = iq * block_q + r0 + diag_off + \
+                    jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_masked), 0)
                 if causal_block > 1:  # the whole block of positions
                     qpos = qpos | (causal_block - 1)
-                kpos = jk * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(qpos >= kpos, s, _NEG)
-            m = ml_s[:, 0:1]
+                kpos = jk * block_k + seen + jax.lax.broadcasted_iota(
+                    jnp.int32, (n_rows, n_masked), 1)
+                cut = jnp.where(qpos >= kpos, s[:, seen:], _NEG)
+                s = jnp.concatenate([s[:, :seen], cut], axis=1) if seen \
+                    else cut
+            m = ml_s[t, rows, 0:1]
             m_new = jnp.maximum(m, s.max(-1, keepdims=True))
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m - m_new)
-            ml_s[:, 1:2] = ml_s[:, 1:2] * corr + p.sum(-1, keepdims=True)
-            acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
+            ml_s[t, rows, 1:2] = ml_s[t, rows, 1:2] * corr \
+                + p.sum(-1, keepdims=True)
+            acc_s[t, rows, :] = acc_s[t, rows, :] * corr + jax.lax.dot_general(
                 p.astype(in_dt), vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            ml_s[:, 0:1] = m_new
+            ml_s[t, rows, 0:1] = m_new
 
+        def tile(pieces):
+            def run():
+                for t in range(group):
+                    for piece in pieces:
+                        update(t, *piece)
+            return run
+
+        whole = tile([(0, block_q, block_k, 0)])
         if causal:
-            # key blocks strictly above the diagonal contribute nothing
+            # key blocks before this one hold no key any row cannot see;
+            # blocks from first_masked on hold none any row can
+            first_crossing = (iq * block_q + diag_off + causal_block) \
+                // block_k
             first_masked = ((iq + 1) * block_q + diag_off
                             + block_k - 1) // block_k
-            pl.when(jk < first_masked)(compute)
+            pl.when(jk < first_crossing)(whole)
+            pl.when((jk >= first_crossing) & (jk < first_masked))(
+                tile(crossing))
         else:
-            compute()
+            whole()
 
-        @pl.when(jk == pl.num_programs(2) - 1)
+        @pl.when(jk == pl.num_programs(3) - 1)
         def _():
-            o_ref[0] = (acc_s[:] / jnp.maximum(ml_s[:, 1:2], 1e-30)
-                        ).astype(o_ref.dtype)
+            for t in range(group):
+                o_ref[0, :, t * d_v:(t + 1) * d_v] = (
+                    acc_s[t] / jnp.maximum(ml_s[t, :, 1:2], 1e-30)
+                ).astype(o_ref.dtype)
 
-    grid = (bh, s_q // block_q, nk)
-    # GQA: query-head grid step bhi reads K/V group bhi // rep — since
-    # h = rep * h_kv, (batch*h + head) // rep == batch*h_kv + head//rep,
-    # so one integer divide maps flattened (b, h) onto flattened (b, h_kv);
-    # the grouped K/V are never expanded in HBM. rep == 1 keeps the plain
-    # identity map (a division in the index map can pessimize Mosaic's
-    # block-revisit analysis).
-    if rep == 1:
-        kv_map = lambda bhi, i, j: (bhi, j, 0)
-    else:
-        kv_map = lambda bhi, i, j: (bhi // rep, j, 0)
+    # GQA: a group of query heads reads K/V head ``head // rep``, a row of
+    # the heads-first layout K/V row ``row // batch_rep``: the grouped K/V
+    # are never expanded in HBM. rep == 1 keeps the plain identity map (a
+    # division in the index map can pessimize Mosaic's block-revisit
+    # analysis).
+    def kv_map(row, hg, i, j):
+        if causal:
+            # a block wholly above the diagonal is not fetched: the index
+            # stays at the last block this row of tiles needs
+            j = jnp.minimum(j, ((i + 1) * block_q + diag_off + block_k - 1)
+                            // block_k - 1)
+        return (row if batch_rep == 1 else row // batch_rep, j,
+                hg if rep == 1 else hg // (rep // group))
+
+    def of_q_or_k(n_seq, n_cols, index_map):
+        """A block of ``n_seq`` positions and ``n_cols`` of the heads' numbers
+        and its (row, position, heads) index map, as the operand lies."""
+        if not positions_minor:
+            return pl.BlockSpec((1, n_seq, n_cols), index_map)
+        return pl.BlockSpec(
+            (1, n_cols, n_seq),
+            lambda *step: tuple(index_map(*step)[i] for i in (0, 2, 1)))
+
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n, heads // group, s_q // block_q, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bhi, i, j: (bhi, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d_v), kv_map),
+            of_q_or_k(block_q, group * d, lambda row, hg, i, j: (row, i, hg)),
+            of_q_or_k(block_k, kv_group * d, kv_map),
+            pl.BlockSpec((1, block_k, kv_group * d_v), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d_v),
-                               lambda bhi, i, j: (bhi, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, group * d_v),
+                               lambda row, hg, i, j: (row, i, hg)),
         # output in the INPUT dtype: the caller casts to q.dtype anyway, and
         # an f32 out block doubles what the (2048, 1024) class keeps in
         # scoped VMEM
-        out_shape=jax.ShapeDtypeStruct((bh, s_q, d_v), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, s_q, heads * d_v), q.dtype),
         scratch_shapes=[
-            # running max (lane 0) + denominator (lane 1)
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d_v), jnp.float32),  # running numerator
+            # a head's running max (lane 0) + denominator (lane 1)
+            pltpu.VMEM((group, block_q, 128), jnp.float32),
+            pltpu.VMEM((group, block_q, d_v), jnp.float32),  # numerator
         ],
         compiler_params=None if interpret else pltpu.CompilerParams(
-            # bh/q-block steps are independent; only the key-block walk
-            # carries state -> Mosaic can pipeline block DMAs across steps
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            # row/head/q-block steps are independent; only the key-block
+            # walk carries state -> Mosaic can pipeline block DMAs across
+            # steps
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 

@@ -63,25 +63,83 @@ def test_expert_ffn_compiles_for_a_v5e_at_each_row_tile(
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
-def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(one_chip):
-    """The prompt pass's attention of ``joyai_llm_flash``: queries and keys
-    of 192 = 128 + 64 numbers a head as they lie (a block's last dimension
-    is the array's own, not a multiple of 128), values and result of 128,
-    ``[16, 4096, 32]`` causal, at the blocks ``flash._pick_blocks`` gives:
-    Mosaic takes the contraction and the blocks fit the scoped VMEM."""
+@pytest.mark.parametrize("rows,length,heads,kv,d,causal_block,group", [
+    (16, 4096, 32, 32, 192, 1, 2),   # joyai_llm_flash's prompt pass
+    (16, 4096, 32, 2, 128, 1, 2),    # nemotron3_nano's attention block
+    (128, 256, 32, 4, 128, 4, 8),    # sdar_30b_a3b's prompt pass
+])
+def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(
+        rows, length, heads, kv, d, causal_block, group, one_chip,
+        monkeypatch):
+    """A causal ``Attention`` node at the three loads that run the flash
+    kernel in a cell, through ``ops._attention`` with the kernels on, at the
+    blocks ``flash._pick_blocks`` gives and the heads a step
+    ``flash._heads_a_step`` gives: Mosaic takes a head as a block of ``[B,
+    S, H x D]`` where it lies (grouped heads through the index map, two and
+    eight query heads a step sharing a key-value head's blocks; 192-wide
+    heads as rows of ``[B, H x D, S]``, the layout the compiler gives them
+    behind the products and the ``Concat`` that make them), the diagonal's
+    tile as two halves, and the blocks fit the scoped VMEM; the compiled
+    program is ONE custom call with no transpose and no copy of ``q``,
+    ``k``, ``v`` or the result at all."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
+    from synapseml_tpu.onnx import ops
     from synapseml_tpu.parallel import flash
 
-    def shape(width):
-        return jax.ShapeDtypeStruct((16, 4096, 32, width), jnp.bfloat16,
-                                    sharding=one_chip)
+    block = min(length, 1024)
+    assert flash._pick_blocks(rows * heads, length, length) == (block, block)
+    assert flash._diag_rows(block, block, 0, causal_block) == block // 2
+    assert flash._heads_a_step(heads, kv, d, 128, block, block, 2) == group
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+    notes = {}
 
-    compiled = jax.jit(lambda q, k, v: flash.flash_attention(
-        q, k, v, causal=True)).lower(shape(192), shape(192),
-                                     shape(128)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    mask = None if causal_block == 1 else (
+        np.arange(length)[None, :]
+        <= (np.arange(length)[:, None] | (causal_block - 1)))
+
+    def attention(q, k, v):
+        return ops._attention(
+            [q, k, v, mask], dict(q_num_heads=heads, kv_num_heads=kv,
+                                  is_causal=int(causal_block == 1)),
+            {"n_outputs": 1, "notes": notes})
+
+    def product(x, w):
+        return jnp.dot(x, w, preferred_element_type=jnp.float32
+                       ).astype(jnp.bfloat16)
+
+    def expanded_latent_attention(q_latent, w_uq, kv_latent, w_uk, w_uv,
+                                  k_rope):
+        # as 192-wide heads reach the node: heads of 128 + 64 numbers, the
+        # 64 of a key shared by every head (models/joyai_flash.py)
+        k = jnp.concatenate(
+            [product(kv_latent, w_uk).reshape(rows, length, heads, 128),
+             jnp.broadcast_to(k_rope[:, :, None, :],
+                              (rows, length, heads, 64))], axis=-1)
+        return attention(product(q_latent, w_uq),
+                         k.reshape(rows, length, heads * d),
+                         product(kv_latent, w_uv))
+
+    if d == 192:
+        lowered = jax.jit(expanded_latent_attention).lower(
+            shape(rows, length, 1536), shape(1536, heads * d),
+            shape(rows, length, 512), shape(512, heads * 128),
+            shape(512, heads * 128), shape(rows, length, 64))
+    else:
+        lowered = jax.jit(attention).lower(
+            shape(rows, length, heads * d), shape(rows, length, kv * d),
+            shape(rows, length, kv * 128))
+    text = lowered.compile().as_text()
+    assert notes["attention_flash"] == 1 \
+        and notes["attention_flash_form.in_place"] == 1
+    assert text.count("tpu_custom_call") == 1
+    assert not [line for line in text.splitlines()
+                if " copy(" in line or " transpose(" in line]
 
 
 @pytest.mark.parametrize("rows,length,whole", [

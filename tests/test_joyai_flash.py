@@ -298,33 +298,153 @@ def test_attention_takes_a_value_width_of_its_own(layout, case, kv, lowering,
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("kv", [4, 2])
-def test_flash_kernel_at_192_and_128_in_interpret_mode(causal, kv):
+@pytest.mark.parametrize("heads,kv", [(4, 4), (4, 2), (32, 32)])
+def test_flash_kernel_at_192_and_128_in_interpret_mode(causal, heads, kv):
     """The kernel's own arithmetic with queries and keys of 192 and values of
     128 a head (the published widths of the expanded form), through the
-    Pallas interpreter, against dense attention; grouped key-value heads and
-    an explicit scale too."""
+    Pallas interpreter, against dense attention: on the operands where they
+    lie, read with the positions minor (4 / 4, the cell's 32 / 32, grouped
+    key-value heads); an explicit scale too."""
     import jax
     import jax.numpy as jnp
 
     from synapseml_tpu.parallel import flash
 
+    assert flash.reads_in_place(192, 128, 4) and flash.reads_in_place(192, 128, 2)
     rng = np.random.default_rng(12)
-    q = jnp.asarray(rng.standard_normal((2, 256, 4, 192), dtype=np.float32))
-    k = jnp.asarray(rng.standard_normal((2, 256, kv, 192), dtype=np.float32))
-    v = jnp.asarray(rng.standard_normal((2, 256, kv, 128), dtype=np.float32))
-    scale = None if kv == 4 else 0.05
+    rows = 2 if heads == 4 else 1
+    q = jnp.asarray(rng.standard_normal((rows, 256, heads, 192),
+                                        dtype=np.float32))
+    k = jnp.asarray(rng.standard_normal((rows, 256, kv, 192),
+                                        dtype=np.float32))
+    v = jnp.asarray(rng.standard_normal((rows, 256, kv, 128),
+                                        dtype=np.float32))
+    scale = None if kv == heads else 0.05
     with jax.default_matmul_precision("highest"):
         got = flash.flash_attention(q, k, v, causal=causal, block_q=128,
                                     block_k=128, interpret=True, scale=scale)
         want = flash.dense_attention(
-            q, jnp.repeat(k, 4 // kv, axis=2), jnp.repeat(v, 4 // kv, axis=2),
-            causal=causal, scale=scale)
-    assert got.shape == (2, 256, 4, 128)
+            q, jnp.repeat(k, heads // kv, axis=2),
+            jnp.repeat(v, heads // kv, axis=2), causal=causal, scale=scale)
+    assert got.shape == (rows, 256, heads, 128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-5)
     with pytest.raises(ValueError, match="shape mismatch"):
         flash.flash_attention(q, k, v[:, :128], interpret=True)
+
+
+@pytest.mark.parametrize("diag_rows", [128, 64, 32, 16])
+@pytest.mark.parametrize("d,heads,kv,group,causal_block,s_q", [
+    (192, 4, 4, 2, 1, 256),    # positions minor, two heads a step
+    (192, 4, 2, 1, 1, 256),    # positions minor, grouped heads
+    (128, 8, 2, 1, 1, 256),    # grouped heads: the index map divides
+    (128, 8, 2, 4, 4, 128),    # four heads share a step's keys and values;
+                               # block-causal, the diagonal's offset a tile
+])
+def test_a_tile_the_diagonal_crosses_goes_in_sub_tiles(
+        diag_rows, d, heads, kv, group, causal_block, s_q):
+    """The kernel on ``[B, S, H x D]`` operands with a tile the diagonal
+    crosses cut into sub-tiles of every height (128 is the whole tile under
+    the mask): the same answer as dense attention, and as the whole tile."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import flash
+
+    rng = np.random.default_rng(37)
+    s_k = 256
+    q, k, v = (jnp.asarray(rng.standard_normal(shape, dtype=np.float32))
+               for shape in ((2, s_q, heads, d), (2, s_k, kv, d),
+                             (2, s_k, kv, 128)))
+
+    minor = d % 128 != 0
+    q3, k3 = q.reshape(2, s_q, -1), k.reshape(2, s_k, -1)
+    if minor:
+        q3, k3 = jnp.swapaxes(q3, 1, 2), jnp.swapaxes(k3, 1, 2)
+
+    def kernel(rows):
+        return flash._flash_call(
+            q3, k3, v.reshape(2, s_k, -1), heads=heads, kv_heads=kv,
+            batch_rep=1, group=group, causal=True, block_q=128, block_k=128,
+            diag_rows=rows, interpret=True, causal_block=causal_block,
+            positions_minor=minor).reshape(2, s_q, heads, 128)
+
+    with jax.default_matmul_precision("highest"):
+        got, whole = kernel(diag_rows), kernel(128)
+        want = flash.dense_attention(
+            q, jnp.repeat(k, heads // kv, axis=2),
+            jnp.repeat(v, heads // kv, axis=2), causal=True,
+            causal_block=causal_block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(whole), rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_sub_tiles_need_square_tiles_on_the_diagonal():
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import flash
+
+    # half the tile, whole lane tiles of scores and whole blocks of the
+    # mask's granularity; the whole tile where the diagonal misses the corners
+    assert flash._diag_rows(1024, 1024, 0, 1) == 512
+    assert flash._diag_rows(1024, 1024, 3072, 4) == 512
+    assert flash._diag_rows(256, 256, 0, 4) == 128
+    assert flash._diag_rows(256, 256, 0, 256) == 256
+    assert flash._diag_rows(128, 128, 0, 1) == 128
+    assert flash._diag_rows(2048, 1024, 0, 1) == 2048
+    assert flash._diag_rows(1024, 1024, 512, 1) == 1024
+    x = jnp.zeros((1, 256, 128))
+    with pytest.raises(ValueError, match="sub-tiles of 64 rows"):
+        flash._flash_call(x, jnp.zeros((1, 320, 128)),
+                          jnp.zeros((1, 320, 128)), heads=1, kv_heads=1,
+                          group=1, batch_rep=1, causal=True, block_q=128,
+                          block_k=64, diag_rows=64, interpret=True)
+
+
+@pytest.mark.parametrize("d,dv,heads,kv,form", [
+    (192, 128, 4, 4, "in_place"),       # latent attention expanded
+    (128, 128, 4, 2, "in_place"),       # grouped heads of 128
+    (192, 128, 4, 2, "in_place"),       # 192 with grouped heads
+    (64, 64, 2, 2, "heads_first"),      # a value head under the 128 lanes
+])
+def test_attention_says_where_the_flash_kernel_reads_its_operands(
+        d, dv, heads, kv, form, monkeypatch):
+    """With the kernels on (here through the interpreter) a causal
+    ``Attention`` node at kernel widths runs the flash kernel, and
+    ``smt_onnx_attention_flash_form_total`` says whether on the operands
+    where they lie or on copies laid out heads first."""
+    import functools
+
+    import jax
+
+    from synapseml_tpu.onnx import ops
+    from synapseml_tpu.parallel import flash
+
+    _fresh_programs(monkeypatch)
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+    monkeypatch.setattr(flash, "flash_attention", functools.partial(
+        flash.flash_attention, interpret=True))
+    b, s = 1, 128
+    q, k, v, _, attrs, want = _latent_case(
+        np.random.default_rng(37), b, s, s, heads, kv, d, dv, "causal")
+    feeds = {n: x.reshape(b, s, -1) for n, x in zip("qkv", (q, k, v))}
+    attrs.update(q_num_heads=heads, kv_num_heads=kv)
+    fn = OnnxFunction(_model([ob.node("Attention", list(feeds), ["y"],
+                                      name="att", **attrs)], feeds, ["y"]))
+    families = ("smt_onnx_attention_lowering_total",
+                "smt_onnx_attention_flash_form_total")
+    before = {f: _gauge(f, fn=fn._fn_name) for f in families}
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(fn(feeds)["y"]).reshape(b, s, heads, dv)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    gained = {f: {key: n - before[f].get(key, 0)
+                  for key, n in _gauge(f, fn=fn._fn_name).items()
+                  if n != before[f].get(key, 0)} for f in families}
+    assert gained == {
+        "smt_onnx_attention_lowering_total": {(fn._fn_name, "flash"): 1},
+        "smt_onnx_attention_flash_form_total": {(fn._fn_name, form): 1}}
 
 
 # ------------------------------------------------- the program and its notes
@@ -368,6 +488,7 @@ def test_the_trace_says_which_form_of_attention_ran_and_what_the_loop_holds(
     name, layers = fn._fn_name, TINY["layers"]
     families = ("smt_onnx_attention_lowering_total",
                 "smt_onnx_attention_widths_total",
+                "smt_onnx_attention_flash_form_total",
                 "smt_onnx_expert_tile_total", "smt_onnx_expert_form_total")
     before = {f: _gauge(f, fn=name) for f in families}
     rows = 2
@@ -381,6 +502,9 @@ def test_the_trace_says_which_form_of_attention_ran_and_what_the_loop_holds(
     # pass is masked by the run
     assert since("smt_onnx_attention_lowering_total") == {
         (name, "dense"): layers, (name, "masked"): layers}
+    # no node ran the flash kernel, so none says where it read its operands
+    # (on the chip: 9 ``flash``, all of them ``in_place``, + 9 ``masked``)
+    assert since("smt_onnx_attention_flash_form_total") == {}
     qk = TINY["nope"] + TINY["rope"]
     assert since("smt_onnx_attention_widths_total") == {
         (name, str(qk), str(TINY["v_dim"]), str(TINY["heads"])): layers,
@@ -416,6 +540,10 @@ def test_the_flash_width_tool_rehearses_on_the_cpu(capsys):
     assert flash_width_forms.main(["--rehearse-on-cpu"]) == 0
     lines = [json.loads(line) for line in
              capsys.readouterr().out.strip().splitlines()]
-    assert [line["form"] for line in lines] == ["as_lies", "padded", "d128"]
+    forms = ["heads_first", "in_place", "in_place:64", "in_place:32",
+             "in_place:64:g2", "shipped"]
+    assert [(line["load"], line["form"]) for line in lines] == [
+        (load, form) for load in ("joyai", "nemotron", "sdar")
+        for form in forms]
     assert all(line["max_abs_diff"] < 0.05 and "ms" not in line
-               for line in lines)
+               and line["max_abs_from_first_form"] < 0.01 for line in lines)

@@ -490,6 +490,9 @@ def test_the_trace_says_how_attention_was_lowered_and_what_experts_hold(
     lowered = series("smt_onnx_attention_lowering_total")
     assert lowered.get((fn._jit.name, "dense"), 0) >= 1
     assert (fn._jit.name, "flash") not in lowered
+    # so no node says where the kernel read its operands (on the chip: 1
+    # ``flash``, ``in_place``)
+    assert series("smt_onnx_attention_flash_form_total") == {}
     # two E blocks: 3 x 16 tokens x top-2 pairs each, 8 experts held each
     assert series("smt_onnx_expert_pairs")[(fn._jit.name,)] == 2 * 3 * 16 * 2
     assert series("smt_onnx_experts_held")[(fn._jit.name,)] == 2 * 8

@@ -188,6 +188,30 @@ def test_flash_attention_matches_dense(causal, sq, sk):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("sq,sk,kv", [(256, 256, 32), (256, 256, 2),
+                                      (128, 384, 2)])
+def test_flash_attention_in_place_at_heads_of_128(causal, sq, sk, kv):
+    """A head size of 128: the kernel reads ``(B, S, H x D)`` where it lies
+    (grouped 32 / 2 as ``nemotron3_nano`` has it: the block index map
+    divides), fewer queries than keys, with and without the mask."""
+    from synapseml_tpu.parallel import dense_attention, flash_attention
+    from synapseml_tpu.parallel.flash import reads_in_place
+
+    assert reads_in_place(128, 128, 4) and not reads_in_place(64, 64, 4)
+    rng = np.random.default_rng(37)
+    q = jnp.asarray(rng.normal(size=(1, sq, 32, 128)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, sk, kv, 128)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, sk, kv, 128)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        ref = dense_attention(q, jnp.repeat(k, 32 // kv, axis=2),
+                              jnp.repeat(v, 32 // kv, axis=2), causal=causal)
+        out = flash_attention(q, k, v, causal=causal, block_q=128,
+                              block_k=128, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_flash_attention_bf16():
     from synapseml_tpu.parallel import dense_attention, flash_attention
 

@@ -202,7 +202,8 @@ def test_loop_gauges_count_the_passes_of_a_call(monkeypatch):
     fn = OnnxFunction(model_bytes, dtype_policy="bfloat16")
     name = fn._fn_name
     before = {family: _gauge(family, fn=name) for family in (
-        "smt_onnx_attention_lowering_total", "smt_onnx_expert_form_total")}
+        "smt_onnx_attention_lowering_total", "smt_onnx_expert_form_total",
+        "smt_onnx_attention_flash_form_total")}
 
     def since(family):  # counters add up over a process's traces
         return {k: v - before[family].get(k, 0)
@@ -225,6 +226,9 @@ def test_loop_gauges_count_the_passes_of_a_call(monkeypatch):
     # against the cache is masked by the run: a kind of its own
     assert lowering == {(name, "dense"): TINY["layers"],
                         (name, "masked"): 2 * TINY["layers"]}
+    # no node ran the flash kernel, so none says where it read its operands
+    # (on the chip: 6 ``flash``, all of them ``in_place``, + 12 ``cached``)
+    assert since("smt_onnx_attention_flash_form_total") == {}
     assert since("smt_onnx_expert_form_total") == {
         (name, "swiglu"): 3 * TINY["layers"]}
 
@@ -680,6 +684,43 @@ def test_flash_block_granular_causal_mask_in_interpret_mode(block, s_q, s_k):
                                 causal=True, causal_block=block)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(dense), want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal_block", [1, 4])
+@pytest.mark.parametrize("s_q,s_k,diag_rows", [
+    (256, 256, 64),     # the prompt's pass: two sub-tiles a diagonal tile
+    (128, 384, 32),     # fewer queries than keys, the offset whole tiles
+    (128, 320, 128),    # an offset off the tiles: the tile whole, masked
+])
+def test_flash_on_operands_where_they_lie_at_32_over_4_heads_of_128(
+        causal_block, s_q, s_k, diag_rows):
+    """``sdar_30b_a3b``'s prompt attention (32 query heads over 4 key-value
+    heads of 128, causal at a granularity of 4 positions) through the kernel
+    on ``[B, S, H x D]`` operands, in the interpreter, against dense
+    attention."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import flash
+
+    rng = np.random.default_rng(13)
+    q, k, v = (jnp.asarray(x) for x in _qkv(rng, 1, s_q, s_k, 32, 4, 128))
+    # as many of the eight heads that share a key-value head a step as fit
+    assert flash._heads_a_step(32, 4, 128, 128, 256, 256, 2) == 8
+    assert flash._heads_a_step(32, 2, 128, 128, 1024, 1024, 2) == 2
+    assert flash._heads_a_step(32, 32, 192, 128, 1024, 1024, 2) == 2
+    with jax.default_matmul_precision("highest"):
+        got = flash._flash_call(
+            q.reshape(1, s_q, -1), k.reshape(1, s_k, -1),
+            v.reshape(1, s_k, -1), heads=32, kv_heads=4, group=8,
+            batch_rep=1, causal=True, block_q=128,
+            block_k=128 if s_k % 128 == 0 else 64, diag_rows=diag_rows,
+            interpret=True, causal_block=causal_block)
+        want = flash.dense_attention(q, jnp.repeat(k, 8, axis=2),
+                                     jnp.repeat(v, 8, axis=2), causal=True,
+                                     causal_block=causal_block)
+    np.testing.assert_allclose(np.asarray(got).reshape(1, s_q, 32, 128),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 def test_flash_refuses_a_block_its_tiles_do_not_hold():
