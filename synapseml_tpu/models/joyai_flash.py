@@ -250,6 +250,12 @@ def _choose(add, c: str, final: str):
     """The head over ``final [N, 1, hidden]``, float32 logits, the greedy
     choice: names the id ``[N, 1]`` and its log-softmax ``[N, 1]``."""
     add(node("MatMul", [final, "lm_head"], [c + "_logits"], name=c + "_head"))
+    return _greedy(add, c)
+
+
+def _greedy(add, c: str):
+    """The greedy choice over the float32 widening of ``c_logits [N, 1,
+    vocab]``: names the id ``[N, 1]`` and its log-softmax ``[N, 1]``."""
     add(node("Cast", [c + "_logits"], [c + "_logits_f"], name=c + "_logits_f",
              to=_FLOAT))
     add(node("ArgMax", [c + "_logits_f"], [c + "_id"], name=c + "_id",

@@ -15,7 +15,11 @@ pipeline stage of the block-diffusion sparse-expert decoder ``sdar_moe``, genera
 included (``models/sdar_moe.py``: a ``Loop`` over blocks carrying a key-value cache),
 and one chip's share of the latent-attention sparse-expert decoder ``joyai_llm_flash``,
 generating greedily (``models/joyai_flash.py``: a prompt pass in the expanded form, then a
-``Loop`` of one token a row against a cache of latents in the absorbed form).
+``Loop`` of one token a row against a cache of latents in the absorbed form),
+and the hybrid Mamba-1 / attention decoder ``jamba``, whole and generating greedily
+(``models/jamba.py``: the selective scan as one ``synapseml_tpu::SelectiveScan`` node,
+a prompt pass, then a ``Loop`` that carries each Mamba layer's state and convolution
+rows beside the attention layers' key-value caches).
 All emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
 can "cut" the head exactly like the reference's ``cutOutputLayers``
 (``ImageFeaturizer.scala:40-197``).
@@ -299,6 +303,8 @@ MODEL_BUILDERS = {
     "SDARMoETiny": lambda **kw: _sdar_moe(**{**SDAR_MOE_TINY, **kw}),
     "JoyAIFlash": lambda **kw: _joyai_flash(**kw),
     "JoyAIFlashTiny": lambda **kw: _joyai_flash(**{**JOYAI_FLASH_TINY, **kw}),
+    "Jamba": lambda **kw: _jamba(**kw),
+    "JambaTiny": lambda **kw: _jamba(**{**JAMBA_TINY, **kw}),
 }
 
 # widths of the CPU tests' nemotron_h: every mechanism of the full graph
@@ -342,6 +348,21 @@ def _joyai_flash(**kw) -> ModelProto:
     from .joyai_flash import joyai_flash
 
     return joyai_flash(**kw)
+
+
+# widths of the CPU tests' jamba: every mechanism of the full graph (a Mamba
+# layer before and after the attention layer, four query heads on one
+# key-value head, a step's rank under the state's width)
+JAMBA_TINY = dict(
+    layers=4, hidden=64, vocab=512, heads=4, kv_heads=1, head_dim=16,
+    attn_period=4, attn_offset=2, expand=2, state=16, dt_rank=8, width=96,
+    generate=8)
+
+
+def _jamba(**kw) -> ModelProto:
+    from .jamba import jamba
+
+    return jamba(**kw)
 
 
 def build_model_bytes(name: str, **kw) -> bytes:

@@ -572,7 +572,9 @@ class OnnxFunction:
         were lowered, the widths its ``Attention`` nodes saw, where the
         flash kernel reads their operands, what its ``ExpertFFN`` nodes are
         sized for, their form, their row tile and chunk and how they
-        combine, how often its ``Loop`` bodies run and what they carry."""
+        combine, how often its ``Loop`` bodies run and what they carry, how
+        its ``SelectiveScan`` nodes were lowered and the state their single
+        steps take in."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
@@ -639,8 +641,19 @@ class OnnxFunction:
             "size] in HBM, and the result back: value heads that are no "
             "whole 128-lane blocks)",
             ("fn", "form"))
+        scan = reg.counter(
+            "smt_onnx_selective_scan_lowering_total",
+            "SelectiveScan nodes of a traced program by lowering: kernel "
+            "(the Pallas kernel: the state stays in VMEM across positions), "
+            "step (one position, a generating loop's body: plain jax.numpy "
+            "over the state) or scan (lax.scan over positions, the state "
+            "crossing HBM every position: not a TPU, or shapes that do not "
+            "tile)",
+            ("fn", "form"))
         for key, count in notes.items():
-            if key.startswith("attention_widths."):
+            if key.startswith("selective_scan_"):
+                scan.labels(fn, key[len("selective_scan_"):]).inc(count)
+            elif key.startswith("attention_widths."):
                 widths.labels(fn, *key.split(".")[1:]).inc(count)
             elif key.startswith("attention_flash_form."):
                 flash_form.labels(fn, key.split(".")[1]).inc(count)
@@ -659,6 +672,14 @@ class OnnxFunction:
                 "program carry from trip to trip (a key-value cache)",
                 ("fn",), merge="max").labels(fn).set(
                     notes["loop_state_bytes"])
+        if "recurrent_state_bytes" in notes:
+            reg.gauge(
+                "smt_onnx_recurrent_state_bytes",
+                "bytes of state the single-position SelectiveScan nodes of "
+                "the newest traced program take in (and hand on as many): "
+                "what a generating pass streams beside the weights",
+                ("fn",), merge="max").labels(fn).set(
+                    notes["recurrent_state_bytes"])
         if "expert_pairs" in notes:
             reg.gauge(
                 "smt_onnx_expert_pairs",

@@ -1754,3 +1754,55 @@ def _held_picks_sum(results, absent, place, weight, every):
 
     return lax.fori_loop(0, (n_rest + _REST_ROWS - 1) // _REST_ROWS,
                          some_rows, total)
+
+
+@op("SelectiveScan")
+def _selective_scan(inputs, attrs, ctx):
+    """``synapseml_tpu::SelectiveScan(u, delta, A, B, C, D, z, delta_bias[,
+    state_in]) -> (out, state_out)``: Mamba-1's recurrence with the signature
+    of the published ``selective_scan_fn``, positions before channels.
+
+    ``u``, ``delta``, ``z`` ``[rows, S, d]``; ``A [d, n]``; ``B``, ``C``
+    ``[rows, S, n]``; ``D``, ``delta_bias`` ``[d]``. With ``delta_softplus``
+    (default 1) ``delta <- softplus(delta + delta_bias)``; then for every
+    position in order ``s <- exp(delta A) s + delta B u``, ``y = C s + D u``,
+    ``out = y silu(z)``: float32 throughout, ``out`` rounded once to ``u``'s
+    type (``parallel/selective_scan.py`` has the equations). The state is
+    ``[rows, n, d]`` float32, channels minor; ``state_in`` absent means zero,
+    ``state_out`` is the state after the last position: ONE operator is a
+    prompt pass's scan and a generating loop's single step.
+
+    Three lowerings, from shapes and the backend alone: ``kernel`` (the
+    Pallas kernel, the state in VMEM across positions: the kernels on, more
+    than one position, whole groups of 8 positions and blocks of 128
+    channels), ``step`` (one position: plain ``jax.numpy`` over the state),
+    ``scan`` (``lax.scan`` over positions: everything else). The program's
+    notes count each, and the bytes of state the ``step`` nodes take in."""
+    from ..parallel import selective_scan as scan
+
+    u, delta, a, b, c, skip, z, bias = inputs[:8]
+    state_in = inputs[8] if len(inputs) > 8 else None
+    rows, s, d = u.shape
+    n = a.shape[-1]
+    if tuple(a.shape) != (d, n) or tuple(delta.shape) != tuple(u.shape) \
+            or tuple(b.shape) != (rows, s, n) or tuple(c.shape) != b.shape \
+            or (state_in is not None
+                and tuple(state_in.shape) != (rows, n, d)):
+        raise ValueError(
+            f"SelectiveScan: u {list(u.shape)}, delta {list(delta.shape)}, "
+            f"A {list(a.shape)}, B {list(b.shape)}, C {list(c.shape)}, "
+            f"state_in {state_in is not None and list(state_in.shape)}: "
+            f"[rows, S, d] twice, [d, n], [rows, S, n] twice, [rows, n, d]")
+    if s == 1:
+        form = "step"
+        if state_in is not None:
+            _note(ctx, "recurrent_state_bytes", rows * n * d * 4)
+    elif _kernels_on() and scan.kernel_takes(s, d):
+        form = "kernel"
+    else:
+        form = "scan"
+    _note(ctx, "selective_scan_" + form)
+    out, state = getattr(scan, form + "_form")(
+        u, delta, a, b, c, skip, z, bias, state_in,
+        delta_softplus=bool(attrs.get("delta_softplus", 1)))
+    return (out, state) if ctx["n_outputs"] > 1 else out

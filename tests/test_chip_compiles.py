@@ -67,6 +67,7 @@ def test_expert_ffn_compiles_for_a_v5e_at_each_row_tile(
     (16, 4096, 32, 32, 192, 1, 2),   # joyai_llm_flash's prompt pass
     (16, 4096, 32, 2, 128, 1, 2),    # nemotron3_nano's attention block
     (128, 256, 32, 4, 128, 4, 8),    # sdar_30b_a3b's prompt pass
+    (128, 128, 20, 1, 128, 1, 20),   # jamba2_3b's: 20 heads on ONE
 ])
 def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(
         rows, length, heads, kv, d, causal_block, group, one_chip,
@@ -91,7 +92,9 @@ def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(
 
     block = min(length, 1024)
     assert flash._pick_blocks(rows * heads, length, length) == (block, block)
-    assert flash._diag_rows(block, block, 0, causal_block) == block // 2
+    # a tile of 128 has no half of whole 128-lane tiles: it goes whole
+    assert flash._diag_rows(block, block, 0, causal_block) == (
+        block // 2 if block > 128 else block)
     assert flash._heads_a_step(heads, kv, d, 128, block, block, 2) == group
     monkeypatch.setattr(ops, "_kernels_on", lambda: True)
     notes = {}
@@ -182,3 +185,47 @@ def test_cached_attention_compiles_for_a_v5e(rows, length, whole, one_chip,
     cache = f"bf16[{rows},{length},"
     assert not [line for line in text.splitlines()
                 if " copy(" in line and cache in line.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("rows,length,entering", [
+    (128, 128, False),    # the prompt pass of jamba2_3b.s128_gen128
+    (16, 4096, True),     # a long prompt: eight blocks of positions a row
+])
+def test_selective_scan_compiles_for_a_v5e(rows, length, entering, one_chip,
+                                           monkeypatch):
+    """A Mamba layer's ``SelectiveScan`` of ``jamba2_3b`` (5,120 channels, 16
+    states; bfloat16 ``u``, ``delta``, ``z``, float32 ``A``, ``B``, ``C``,
+    ``D``, bias and state) through ``ops._selective_scan`` with the kernels
+    on: Mosaic takes the blocks ``selective_scan._blocks`` gives (eight
+    positions of bfloat16 at a time among them), the program is ONE custom
+    call, and the state is made and read ``[rows, 16, 5120]``, channels
+    minor: nothing lays it out ``[rows, 5120, 16]``."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.onnx import ops
+    from synapseml_tpu.parallel import selective_scan as scan
+
+    d, n = 5120, 16
+    assert scan._blocks(length, d, 2) == (1024, min(length, 512))
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+    notes = {}
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def selective_scan(*inputs):
+        return ops._selective_scan(list(inputs), {},
+                                   {"n_outputs": 2, "notes": notes})
+
+    wide = shape((rows, length, d), jnp.bfloat16)
+    narrow = shape((rows, length, n))
+    operands = [wide, wide, shape((d, n)), narrow, narrow, shape((d,)), wide,
+                shape((d,))] + [shape((rows, n, d))] * entering
+    text = jax.jit(selective_scan).lower(*operands).compile().as_text()
+    assert notes == {"selective_scan_kernel": 1}
+    assert text.count("tpu_custom_call") == 1
+    assert f"[{rows},{d},{n}]" not in text
+    out, state = jax.eval_shape(selective_scan, *operands)
+    assert out.shape == (rows, length, d) and out.dtype == jnp.bfloat16
+    assert state.shape == (rows, n, d) and state.dtype == jnp.float32
