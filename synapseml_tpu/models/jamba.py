@@ -31,12 +31,16 @@ the ids were chosen from, of the final norm's output).
 - The prompt pass (nodes ``p_l#_...``) runs every position. A Mamba layer
   leaves the state after the last position (``SelectiveScan``'s second
   output, ``[N, state, d]`` float32, channels minor) and the last
-  ``conv_kernel - 1`` rows of step 1's ``x``; an attention layer its keys
+  ``conv_kernel - 1`` rows of step 1's ``x``, positions major (``[conv_kernel
+  - 1, N, d]``: one ``Transpose`` a call); an attention layer its keys
   and values, padded once to a cache ``[N, S + generate, kv_heads x
   head_dim]``. The final norm and the head at the last position give id 0.
 - ``Loop`` ``decode`` (``generate - 1`` trips; nodes ``d_l#_...``) embeds
   the last id, ONE position a row, and runs the same layers: the
-  convolution over the kept rows and the new one, ``SelectiveScan`` at one
+  convolution as the published single step (the window ``[conv_kernel, N,
+  d]`` of the kept rows and the new one times the taps, summed over its
+  leading axis, in float32; the window's last ``conv_kernel - 1`` rows are
+  the next pass's), ``SelectiveScan`` at one
   position from the carried state, ``TensorScatter`` of the position's key
   and value and ``Attention`` against the cache under a mask computed from
   the trip counter. It carries two kinds of state side by side: a Mamba
@@ -114,29 +118,46 @@ def _mamba(add, z: _Sizes, p: str, wp: str, u: str, conv_rows: str = None,
            state_in: str = None):
     """The Mamba mixer over ``u [N, s, hidden]``. The prompt pass (no
     ``conv_rows``) pads the convolution with zeros and starts the state from
-    zero; a decode pass convolves ``conv_rows [N, conv - 1, d]`` and its one
+    zero; a decode pass convolves ``conv_rows [conv - 1, N, d]`` and its one
     new row and starts from ``state_in``. Names the mix, the state after the
     last position and the rows the next pass's convolution reads."""
     add(node("MatMul", [u, wp + "_in_w"], [p + "_xz"], name=p + "_in_proj"))
     add(node("Split", [p + "_xz"], [p + "_x_raw", p + "_z"],
              name=p + "_in_split", axis=-1, num_outputs=2))
     if conv_rows is None:
-        window, pads = p + "_x_raw", [z.conv - 1, 0]
-        add(node("Slice", [window, "conv_keep_from", "huge_1d", "axes_1"],
-                 [p + "_conv_rows"], name=p + "_conv_rows"))
+        add(node("Slice", [p + "_x_raw", "conv_keep_from", "huge_1d",
+                           "axes_1"], [p + "_conv_kept"],
+                 name=p + "_conv_kept"))
+        add(node("Transpose", [p + "_conv_kept"], [p + "_conv_rows"],
+                 name=p + "_conv_rows", perm=[1, 0, 2]))
+        add(node("Transpose", [p + "_x_raw"], [p + "_conv_in"],
+                 name=p + "_conv_in", perm=[0, 2, 1]))
+        add(node("Conv", [p + "_conv_in", wp + "_conv_w", wp + "_conv_b"],
+                 [p + "_conv_t"], name=p + "_conv", group=z.d,
+                 kernel_shape=[z.conv], pads=[z.conv - 1, 0]))
+        add(node("Transpose", [p + "_conv_t"], [p + "_conv_out"],
+                 name=p + "_conv_out", perm=[0, 2, 1]))
     else:
-        window, pads = p + "_window", [0, 0]
-        add(node("Concat", [conv_rows, p + "_x_raw"], [window],
-                 name=p + "_window", axis=1))
-        add(node("Slice", [window, "index1", "huge_1d", "axes_1"],
+        # the published single step over a window [conv, N, d]: positions
+        # lead, so every join, slice and product is of whole [N, d] rows
+        add(node("Transpose", [p + "_x_raw"], [p + "_x_new"],
+                 name=p + "_x_new", perm=[1, 0, 2]))
+        add(node("Concat", [conv_rows, p + "_x_new"], [p + "_window"],
+                 name=p + "_window", axis=0))
+        add(node("Slice", [p + "_window", "index1", "huge_1d", "axes_0"],
                  [p + "_conv_rows"], name=p + "_conv_rows"))
-    add(node("Transpose", [window], [p + "_conv_in"], name=p + "_conv_in",
-             perm=[0, 2, 1]))
-    add(node("Conv", [p + "_conv_in", wp + "_conv_w", wp + "_conv_b"],
-             [p + "_conv_t"], name=p + "_conv", group=z.d,
-             kernel_shape=[z.conv], pads=pads))
-    add(node("Transpose", [p + "_conv_t"], [p + "_conv_out"],
-             name=p + "_conv_out", perm=[0, 2, 1]))
+        add(node("Cast", [p + "_window"], [p + "_window_f"],
+                 name=p + "_window_f", to=_FLOAT))
+        add(node("Mul", [p + "_window_f", wp + "_conv_taps"],
+                 [p + "_conv_terms"], name=p + "_conv_terms"))
+        add(node("ReduceSum", [p + "_conv_terms", "axes_0"],
+                 [p + "_conv_sum"], name=p + "_conv_sum", keepdims=0))
+        add(node("Add", [p + "_conv_sum", wp + "_conv_b_f"],
+                 [p + "_conv_f"], name=p + "_conv_f"))
+        add(node("Unsqueeze", [p + "_conv_f", "axes_1"], [p + "_conv_row"],
+                 name=p + "_conv_row"))
+        add(node("CastLike", [p + "_conv_row", p + "_x_raw"],
+                 [p + "_conv_out"], name=p + "_conv_out"))
     add(node("Sigmoid", [p + "_conv_out"], [p + "_conv_s"],
              name=p + "_silu_s"))
     add(node("Mul", [p + "_conv_out", p + "_conv_s"], [p + "_x"],
@@ -254,6 +275,13 @@ def jamba(layers: int = 28, hidden: int = 2560, vocab: int = 65536,
                  to=_FLOAT))
         add(node("Cast", [p + "_dt_b"], [p + "_dt_b_f"], name=p + "_dt_b_f",
                  to=_FLOAT))
+        # the decode pass's convolution taps [conv, 1, d] and bias, float32
+        add(node("Transpose", [p + "_conv_w"], [p + "_conv_taps_t"],
+                 name=p + "_conv_taps_t", perm=[2, 1, 0]))
+        add(node("Cast", [p + "_conv_taps_t"], [p + "_conv_taps"],
+                 name=p + "_conv_taps", to=_FLOAT))
+        add(node("Cast", [p + "_conv_b"], [p + "_conv_b_f"],
+                 name=p + "_conv_b_f", to=_FLOAT))
     # sizes from the feed's shape (constants of a trace): N, S, L = S + G
     add(node("Shape", ["input_ids"], ["ids_shape"], name="ids_shape"))
     add(node("Gather", ["ids_shape", "index0"], ["n_1d"], name="n_1d"))

@@ -94,18 +94,21 @@ def test_transform_agrees_with_the_reference_teacher_forced(policy, limit,
                                       replayed["logits"].argmax(-1))
 
 
+@pytest.mark.parametrize("conv_kernel", [4, 2, 3])
 def test_decoding_from_the_carried_state_agrees_with_one_full_forward(
-        monkeypatch):
+        conv_kernel, monkeypatch):
     """Prompt pass + loop = one forward, inside the program: the ids a call
     decodes one at a time from its carried states, convolution rows and
     cache are the ids the PROMPT pass (every position at once) gives for
-    the same prefix."""
+    the same prefix. Windows of 2, 3 and 4 positions: one kept row, and
+    more than one."""
     _fresh_programs(monkeypatch)
     prompts = _prompts(3, seed=2)
-    whole = _transform(zoo.build_model_bytes("JambaTiny", seed=4), prompts,
-                       "float32")
+    whole = _transform(zoo.build_model_bytes(
+        "JambaTiny", seed=4, conv_kernel=conv_kernel), prompts, "float32")
     # the same weights generating 2 ids: id 0 is the prompt pass's
-    short = zoo.build_model_bytes("JambaTiny", seed=4, generate=2)
+    short = zoo.build_model_bytes("JambaTiny", seed=4, generate=2,
+                                  conv_kernel=conv_kernel)
     for t in (1, 4, GENERATE - 1):
         prefix = np.concatenate([prompts, whole["tokens"][:, :t]], axis=1)
         again = _transform(short, prefix, "float32")
@@ -140,6 +143,54 @@ def test_the_graph_is_standard_operators_and_one_selective_scan_a_pass():
     assert len(loop.input) == 2 + 4 + 2 * TINY["layers"]
     with pytest.raises(ValueError, match="generate"):
         jamba(**{**TINY, "generate": 1})
+
+
+@pytest.mark.parametrize("conv_kernel", [2, 4])
+def test_the_loop_carries_convolution_rows_positions_major(conv_kernel,
+                                                           monkeypatch):
+    """Each Mamba layer's kept rows go into and come out of the loop as
+    ``[conv - 1, N, d]``, the window is joined and sliced on its leading
+    axis, and the decode body holds no ``Conv``, no ``Transpose`` round a
+    convolution and no ``Concat`` or ``Slice`` on axis 1."""
+    from synapseml_tpu.models.jamba import jamba
+    from synapseml_tpu.onnx.importer import OPS
+
+    model = jamba(**{**TINY, "conv_kernel": conv_kernel, "seed": 0})
+    (loop,) = [n for n in model.graph.node if n.op_type == "Loop"]
+    body = loop.attrs()["body"]
+    made_by = {o: n for n in body.node for o in n.output}
+    assert "Conv" not in {n.op_type for n in body.node}
+    for n in body.node:
+        if n.op_type == "Concat":
+            assert n.attrs()["axis"] == 0, n.name
+        if n.op_type == "Slice":  # the kept rows; the rest split on -1
+            assert n.input[3] == "axes_0", n.name
+        if n.op_type == "Transpose":  # the new row [N, 1, d] -> [1, N, d]
+            assert n.name.endswith("_x_new") and list(
+                n.attrs()["perm"]) == [1, 0, 2], n.name
+    outputs = [o.name for o in body.output]
+    for i in (0, 1, 3):
+        window = made_by[f"d_l{i}_window"]
+        assert list(window.input) == [f"d_carried{i}_1", f"d_l{i}_x_new"]
+        assert made_by[f"d_l{i}_conv_rows"].input[0] == f"d_l{i}_window"
+        assert outputs[5 + 2 * i + 1] == f"d_l{i}_conv_rows"
+    # the shapes the program traces, by node
+    _fresh_programs(monkeypatch)
+    shapes = {}
+    for op in ("Transpose", "Concat", "Slice", "CastLike"):
+        def recorded(inputs, attrs, ctx, _run=OPS[op]):
+            out = _run(inputs, attrs, ctx)
+            shapes[ctx["node_name"]] = tuple(out.shape)
+            return out
+        monkeypatch.setitem(OPS, op, recorded)
+    rows = 2
+    OnnxFunction(model)({"input_ids": _prompts(rows)})
+    kept = (conv_kernel - 1, rows, INNER)
+    for i in (0, 1, 3):
+        assert shapes[f"p_l{i}_conv_rows"] == kept
+        assert shapes[f"d_l{i}_window"] == (conv_kernel, rows, INNER)
+        assert shapes[f"d_l{i}_conv_rows"] == kept
+        assert shapes[f"d_l{i}_conv_out"] == (rows, 1, INNER)
 
 
 # ---- the operator and its three lowerings
@@ -357,10 +408,10 @@ def test_the_trace_says_how_the_scans_ran_and_what_the_loop_carries(
     assert _gauge("smt_onnx_recurrent_state_bytes", fn=name) == {
         (name,): state}
     # the loop carries both kinds: the states and three convolution rows of
-    # the policy's type a Mamba layer, keys and values of the attention
-    # layer, and what it fills (the last id, tokens, chosen_logprob, the
-    # pooled sum)
-    conv_rows = mamba_layers * rows * 3 * INNER * 2
+    # the policy's type a Mamba layer ([3, N, d], positions major), keys and
+    # values of the attention layer, and what it fills (the last id, tokens,
+    # chosen_logprob, the pooled sum)
+    conv_rows = mamba_layers * 3 * rows * INNER * 2
     cache = 2 * rows * (PROMPT + GENERATE) * TINY["head_dim"] * 2
     outputs = rows * (1 + GENERATE) * 4 + rows * (GENERATE
                                                   + TINY["hidden"]) * 4
