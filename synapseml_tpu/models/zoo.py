@@ -19,7 +19,11 @@ generating greedily (``models/joyai_flash.py``: a prompt pass in the expanded fo
 and the hybrid Mamba-1 / attention decoder ``jamba``, whole and generating greedily
 (``models/jamba.py``: the selective scan as one ``synapseml_tpu::SelectiveScan`` node,
 a prompt pass, then a ``Loop`` that carries each Mamba layer's state and convolution
-rows beside the attention layers' key-value caches).
+rows beside the attention layers' key-value caches),
+and the hybrid Gated DeltaNet / attention decoder ``olmo_hybrid``, generating greedily
+(``models/olmo_hybrid.py``: the gated delta rule as one ``synapseml_tpu::GatedDeltaRule``
+node, a prompt pass, then a ``Loop`` that carries each delta rule layer's matrix state and
+convolution rows beside the full attention layers' key-value caches).
 All emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
 can "cut" the head exactly like the reference's ``cutOutputLayers``
 (``ImageFeaturizer.scala:40-197``).
@@ -305,6 +309,8 @@ MODEL_BUILDERS = {
     "JoyAIFlashTiny": lambda **kw: _joyai_flash(**{**JOYAI_FLASH_TINY, **kw}),
     "Jamba": lambda **kw: _jamba(**kw),
     "JambaTiny": lambda **kw: _jamba(**{**JAMBA_TINY, **kw}),
+    "OlmoHybrid": lambda **kw: _olmo_hybrid(**kw),
+    "OlmoHybridTiny": lambda **kw: _olmo_hybrid(**{**OLMO_HYBRID_TINY, **kw}),
 }
 
 # widths of the CPU tests' nemotron_h: every mechanism of the full graph
@@ -363,6 +369,21 @@ def _jamba(**kw) -> ModelProto:
     from .jamba import jamba
 
     return jamba(**kw)
+
+
+# widths of the CPU tests' olmo_hybrid: every mechanism of the full graph (a
+# delta rule layer before and after the attention layer; 4 heads of 16 / 32,
+# whose 128 lanes the decode step's kernel takes as one group)
+OLMO_HYBRID_TINY = dict(
+    layers=4, hidden=64, vocab=512, heads=4, head_dim=16, linear_heads=4,
+    key_dim=16, value_dim=32, width=96, attn_period=4, attn_offset=1,
+    generate=8)
+
+
+def _olmo_hybrid(**kw) -> ModelProto:
+    from .olmo_hybrid import olmo_hybrid
+
+    return olmo_hybrid(**kw)
 
 
 def build_model_bytes(name: str, **kw) -> bytes:

@@ -573,8 +573,8 @@ class OnnxFunction:
         flash kernel reads their operands, what its ``ExpertFFN`` nodes are
         sized for, their form, their row tile and chunk and how they
         combine, how often its ``Loop`` bodies run and what they carry, how
-        its ``SelectiveScan`` nodes were lowered and the state their single
-        steps take in."""
+        its ``SelectiveScan`` and ``GatedDeltaRule`` nodes were lowered and
+        the state their single steps take in."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
@@ -650,9 +650,19 @@ class OnnxFunction:
             "crossing HBM every position: not a TPU, or shapes that do not "
             "tile)",
             ("fn", "form"))
+        delta = reg.counter(
+            "smt_onnx_gated_delta_lowering_total",
+            "GatedDeltaRule nodes of a traced program by lowering: chunked "
+            "(more than one position: the WY form, matrix products over "
+            "chunks), kernel (one position: the Pallas kernel reads and "
+            "writes the state once, in place) or step (one position, plain "
+            "jax.numpy: not a TPU, or shapes the kernel does not take)",
+            ("fn", "form"))
         for key, count in notes.items():
             if key.startswith("selective_scan_"):
                 scan.labels(fn, key[len("selective_scan_"):]).inc(count)
+            elif key.startswith("gated_delta_"):
+                delta.labels(fn, key[len("gated_delta_"):]).inc(count)
             elif key.startswith("attention_widths."):
                 widths.labels(fn, *key.split(".")[1:]).inc(count)
             elif key.startswith("attention_flash_form."):
@@ -675,9 +685,10 @@ class OnnxFunction:
         if "recurrent_state_bytes" in notes:
             reg.gauge(
                 "smt_onnx_recurrent_state_bytes",
-                "bytes of state the single-position SelectiveScan nodes of "
-                "the newest traced program take in (and hand on as many): "
-                "what a generating pass streams beside the weights",
+                "bytes of state the single-position SelectiveScan and "
+                "GatedDeltaRule nodes of the newest traced program take in "
+                "(and hand on as many): what a generating pass streams "
+                "beside the weights",
                 ("fn",), merge="max").labels(fn).set(
                     notes["recurrent_state_bytes"])
         if "expert_pairs" in notes:

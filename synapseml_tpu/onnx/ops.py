@@ -1806,3 +1806,56 @@ def _selective_scan(inputs, attrs, ctx):
         u, delta, a, b, c, skip, z, bias, state_in,
         delta_softplus=bool(attrs.get("delta_softplus", 1)))
     return (out, state) if ctx["n_outputs"] > 1 else out
+
+
+@op("GatedDeltaRule")
+def _gated_delta_rule(inputs, attrs, ctx):
+    """``synapseml_tpu::GatedDeltaRule(q, k, v, g, beta[, state_in]) -> (out,
+    state_out)``: Gated DeltaNet's recurrence as the published
+    ``chunk_gated_delta_rule`` computes it with ``use_qk_l2norm_in_kernel``
+    and its default scale, positions before heads.
+
+    ``q``, ``k`` ``[rows, S, H, dk]``; ``v`` ``[rows, S, H, dv]``; ``g`` (the
+    log of a position's decay) and ``beta`` ``[rows, S, H]``. ``q`` and
+    ``k`` are divided by their norms a head and ``q`` by ``sqrt(dk)``; then
+    for every position in order ``S <- exp(g) S``, ``u = beta (v - S^T k)``,
+    ``S <- S + k u^T``, ``out = S^T q``: float32 throughout, ``out`` rounded
+    once to ``v``'s type (``parallel/gated_delta.py`` has the equations). The
+    state is ``[rows, dk, H x dv]`` float32, a head's ``[dk, dv]`` in its own
+    lanes; ``state_in`` absent means zero, ``state_out`` is the state after
+    the last position: ONE operator is a prompt pass's rule and a generating
+    loop's single step.
+
+    Three lowerings, from shapes and the backend alone: ``chunked`` (more
+    than one position: the WY form, matrix products over chunks of 64),
+    ``kernel`` (one position, the kernels on, rows in groups of 8 and the
+    heads' lanes in whole 128-lane groups: ONE Pallas kernel that reads and
+    writes the state once, in place), ``step`` (one position otherwise:
+    plain ``jax.numpy``). The program's notes count each, and the bytes of
+    state the single positions take in."""
+    from ..parallel import gated_delta as rule
+
+    q, k, v, g, beta = inputs[:5]
+    state_in = inputs[5] if len(inputs) > 5 else None
+    rows, s, h, dk = q.shape
+    dv = v.shape[-1]
+    if tuple(k.shape) != tuple(q.shape) or tuple(v.shape[:3]) != (rows, s, h) \
+            or tuple(g.shape) != (rows, s, h) or tuple(beta.shape) != g.shape \
+            or (state_in is not None
+                and tuple(state_in.shape) != (rows, dk, h * dv)):
+        raise ValueError(
+            f"GatedDeltaRule: q {list(q.shape)}, k {list(k.shape)}, v "
+            f"{list(v.shape)}, g {list(g.shape)}, beta {list(beta.shape)}, "
+            f"state_in {state_in is not None and list(state_in.shape)}: "
+            f"[rows, S, H, dk] twice, [rows, S, H, dv], [rows, S, H] twice, "
+            f"[rows, dk, H x dv]")
+    if s > 1:
+        form = "chunked"
+    else:
+        if state_in is not None:
+            _note(ctx, "recurrent_state_bytes", rows * dk * h * dv * 4)
+        takes = _kernels_on() and rule.kernel_takes(rows, h, dk, dv)
+        form = "kernel" if takes else "step"
+    _note(ctx, "gated_delta_" + form)
+    out, state = getattr(rule, form + "_form")(q, k, v, g, beta, state_in)
+    return (out, state) if ctx["n_outputs"] > 1 else out

@@ -229,3 +229,55 @@ def test_selective_scan_compiles_for_a_v5e(rows, length, entering, one_chip,
     out, state = jax.eval_shape(selective_scan, *operands)
     assert out.shape == (rows, length, d) and out.dtype == jnp.bfloat16
     assert state.shape == (rows, n, d) and state.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("rows,length,entering", [
+    (128, 1, True),       # a decode pass of olmo_hybrid_7b.s256_gen128
+    (128, 256, False),    # its prompt pass: the chunked form, no kernel
+])
+def test_gated_delta_rule_compiles_for_a_v5e(rows, length, entering,
+                                             one_chip, monkeypatch):
+    """A delta rule layer's ``GatedDeltaRule`` of ``olmo_hybrid_7b`` (30
+    heads of 96 / 192; bfloat16 ``q``, ``k``, ``v``, float32 ``g``, ``beta``
+    and state) through ``ops._gated_delta_rule`` with the kernels on: a
+    single position is ONE custom call that writes the state where it read
+    it (aliased, no copy of it), in the unpadded ``[rows, 96, 5760]``; a
+    prompt is the chunked form, no custom call."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.onnx import ops
+
+    h, dk, dv = 30, 96, 192
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+    notes = {}
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def rule(*inputs):
+        return ops._gated_delta_rule(list(inputs), {},
+                                     {"n_outputs": 2, "notes": notes})
+
+    qk = shape((rows, length, h, dk), jnp.bfloat16)
+    operands = [qk, qk, shape((rows, length, h, dv), jnp.bfloat16),
+                shape((rows, length, h)), shape((rows, length, h))] \
+        + [shape((rows, dk, h * dv))] * entering
+    compiled = jax.jit(rule, donate_argnums=(5,) if entering else ()).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    state = f"f32[{rows},{dk},{h * dv}]"
+    if length == 1:
+        assert notes == {"gated_delta_kernel": 1,
+                         "recurrent_state_bytes": rows * dk * h * dv * 4}
+        assert text.count("tpu_custom_call") == 1
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            == rows * dk * h * dv * 4
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and state in line.split(" copy(")[0]]
+    else:
+        assert notes == {"gated_delta_chunked": 1}
+        assert "tpu_custom_call" not in text
+    out, last = jax.eval_shape(rule, *operands)
+    assert out.shape == (rows, length, h, dv) and out.dtype == jnp.bfloat16
+    assert last.shape == (rows, dk, h * dv) and last.dtype == jnp.float32
