@@ -1461,7 +1461,7 @@ def _attention(inputs, attrs, ctx):
     (``[1, kv]``, ``[batch, 1, 1, kv]`` and what broadcasts from them), the
     kernels are on, queries, keys and values have ONE head size that is a
     multiple of 128 and a key-value head serves at most 128 query rows (``q``
-    x the query heads that share it, whole sublane tiles of them),
+    x the query heads that share it: one query on a head of its own too),
     ``parallel.flash.cached_attention`` reads the cache once and as it lies
     and keeps the scores in VMEM: ``attention_cached``. Every other case (a
     mask a head or a query has to itself, latent attention's one head of

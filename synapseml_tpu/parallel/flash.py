@@ -625,8 +625,9 @@ def cached_attention_takes(q_shape, k_shape, v_shape, mask_shape,
     D) layout) and is the form for them: ONE head size, a multiple of the 128
     lanes, for queries, keys and values (a key-value head is then a column
     block of the cache as it lies); key-value heads that divide the query
-    heads; a few query rows a key-value head, whole sublane tiles of them; a
-    mask over key positions alone."""
+    heads; at most ``_CACHED_QUERY_ROWS`` query rows a key-value head, whole
+    sublane tiles of them or not (one query a head of 30 on 30 key-value
+    heads, 20 query heads on one); a mask over key positions alone."""
     import numpy as np
 
     b, s_q, h, d = q_shape
@@ -636,8 +637,7 @@ def cached_attention_takes(q_shape, k_shape, v_shape, mask_shape,
         return False
     if d % 128 or h % h_kv or s_k % sublanes:
         return False
-    n = s_q * (h // h_kv)
-    return n % sublanes == 0 and n <= _CACHED_QUERY_ROWS \
+    return s_q * (h // h_kv) <= _CACHED_QUERY_ROWS \
         and _key_mask_rows(mask_shape, b, s_k) is not None
 
 
@@ -645,13 +645,17 @@ def _cached_blocks(rows: int, n: int, s_k: int, d: int, itemsize: int):
     """Rows and key positions a grid step of the cached kernel takes, from
     the VMEM it may use. A (row, key position) pair costs its key and its
     value twice (the next block is fetched meanwhile) and three float32
-    numbers a query row (the score, its exponential, the rounded copy). The
-    whole key axis is one block where 8 rows of it fit (no running maximum,
-    no rescaling: at 320 positions key blocks of 128 take half as long
-    again), else blocks of the multiple of 128 keys that 8 rows fit; then as
-    many rows as fit, a divisor of ``rows`` (8 to 32 rows a step read within
-    2 % of each other). ``tools/cached_attention_forms.py`` has the table
-    (PERF.md section 6, PR 35)."""
+    numbers a query row (the score, its exponential, the rounded copy); the
+    ``n`` query rows count as the whole sublane tiles they fill in VMEM (one
+    query row of bfloat16 takes a tile of 16). The whole key axis is one
+    block where 8 rows of it fit (no running maximum, no rescaling: at 320
+    positions key blocks of 128 take half as long again), else blocks of the
+    multiple of 128 keys that 8 rows fit; then as many rows as fit, a
+    divisor of ``rows`` (8 to 32 rows a step read within 2 % of each other).
+    ``tools/cached_attention_forms.py`` has the table (PERF.md section 6, PR
+    35)."""
+    sublanes = 32 // itemsize
+    n = -(-n // sublanes) * sublanes
     fit = _CACHED_VMEM // (4 * d * itemsize + 12 * n)
     few = min(rows, _CACHED_ROWS)
     block_k = s_k if few * s_k <= fit else max(128, fit // few // 128 * 128)
@@ -676,7 +680,10 @@ def cached_attention(q, k, v, mask, scale: float = None,
       ``[B, S_k, H_kv x D]`` (the reshape below undoes the caller's and
       moves nothing), so no ``[B, S_k, H_kv, D]`` copy is made;
     - the ``S_q x H / H_kv`` query rows that share the head go against it in
-      one product, and the scores live and die in VMEM;
+      one product, and the scores live and die in VMEM; a block of ``(rows,
+      n, D)`` queries spans its array's ``n``, so it is a legal block at any
+      ``n`` from 1 on, whole sublane tiles or not: Mosaic pads it in VMEM,
+      and no padded copy of the queries or the result crosses HBM;
     - where the key axis is beyond what a step can hold it goes in blocks
       under the online softmax of the flash kernel (``m``, ``l``, ``acc``).
 

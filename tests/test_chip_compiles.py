@@ -145,25 +145,37 @@ def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(
                 if " copy(" in line or " transpose(" in line]
 
 
-@pytest.mark.parametrize("rows,length,whole", [
-    (128, 320, True),     # a generating pass of sdar_30b_a3b.gen64
-    (16, 4224, False),    # a cache beyond one key block: the online softmax
+@pytest.mark.parametrize("rows,length,whole,queries,heads,kv", [
+    # a generating pass of sdar_30b_a3b.gen64
+    pytest.param(128, 320, True, 4, 32, 4, id="128-320-True"),
+    # a cache beyond one key block: the online softmax
+    pytest.param(16, 4224, False, 4, 32, 4, id="16-4224-False"),
+    # a decode pass of olmo_hybrid_7b: ONE query row a key-value head
+    pytest.param(128, 384, True, 1, 30, 30, id="olmo-128-384"),
+    # a decode pass of jamba2_3b: 20 query heads on one key-value head
+    pytest.param(128, 256, True, 1, 20, 1, id="jamba-128-256"),
 ])
-def test_cached_attention_compiles_for_a_v5e(rows, length, whole, one_chip,
+def test_cached_attention_compiles_for_a_v5e(rows, length, whole, queries,
+                                             heads, kv, one_chip,
                                              monkeypatch):
-    """A layer's cached ``Attention`` of ``sdar_30b_a3b`` (4 queries a row,
-    32 / 4 heads of 128, bfloat16, the mask over key positions) through
-    ``ops._attention`` with the kernels on: Mosaic takes a key-value head as
-    a column block of the rank-3 cache, the batched products and the rows
-    and keys a step ``flash._cached_blocks`` gives, and the cache reaches
-    the kernel as it lies (no copy of it in the compiled program)."""
+    """A layer's cached ``Attention`` (bfloat16 heads of 128, the mask over
+    key positions) through ``ops._attention`` with the kernels on, at
+    ``sdar_30b_a3b``'s 4 queries on 32 / 4 heads and at one query on
+    ``olmo_hybrid_7b``'s 30 / 30 and ``jamba2_3b``'s 20 / 1 (query rows a
+    key-value head that are no whole sublane tile): Mosaic takes a key-value
+    head as a column block of the rank-3 cache, the batched products and the
+    rows and keys a step ``flash._cached_blocks`` gives, and the cache
+    reaches the kernel as it lies, in its own type: the compiled program
+    holds no copy of it and no float32 array over its rows and positions (a
+    converted cache, or scores written to HBM)."""
     import jax
     import jax.numpy as jnp
 
     from synapseml_tpu.onnx import ops
     from synapseml_tpu.parallel import flash
 
-    assert (flash._cached_blocks(rows, 32, length, 128, 2)[1]
+    n = queries * heads // kv
+    assert (flash._cached_blocks(rows, n, length, 128, 2)[1]
             == length) is whole
     monkeypatch.setattr(ops, "_kernels_on", lambda: True)
     notes = {}
@@ -173,18 +185,21 @@ def test_cached_attention_compiles_for_a_v5e(rows, length, whole, one_chip,
 
     def attention(*inputs):
         return ops._attention(list(inputs),
-                              dict(q_num_heads=32, kv_num_heads=4),
+                              dict(q_num_heads=heads, kv_num_heads=kv),
                               {"n_outputs": 1, "notes": notes})
 
     text = jax.jit(attention).lower(
-        shape((rows, 4, 4096)), shape((rows, length, 512)),
-        shape((rows, length, 512)), shape((1, length), jnp.bool_)
+        shape((rows, queries, heads * 128)), shape((rows, length, kv * 128)),
+        shape((rows, length, kv * 128)), shape((1, length), jnp.bool_)
     ).compile().as_text()
     assert notes["attention_cached"] == 1 and "attention_masked" not in notes
     assert text.count("tpu_custom_call") == 1
-    cache = f"bf16[{rows},{length},"
-    assert not [line for line in text.splitlines()
-                if " copy(" in line and cache in line.split(" copy(")[0]]
+    cache = f"[{rows},{length},"
+    made = [line.split(" = ", 1)[1] for line in text.splitlines()
+            if " = " in line]
+    assert not [r for r in made if r.startswith("bf16" + cache)
+                and " copy(" in r]
+    assert not [r for r in made if r.startswith("f32" + cache)]
 
 
 @pytest.mark.parametrize("rows,length,entering", [

@@ -462,6 +462,9 @@ _CACHED_CASES = {
     "longer_than_a_key_block": (4, 2, 4, 40, (1, 16), None, True),
     "a_fill_a_row_and_scale": (8, 2, 1, 32, (3, 16), 0.3, True),
     "scale": (4, 1, 4, 24, None, 0.21, False),
+    # query rows a key-value head that are no whole sublane tile
+    "one_query_a_head": (4, 4, 1, 24, None, None, False),
+    "twenty_rows_a_head": (20, 1, 1, 24, None, None, False),
 }
 
 
@@ -519,12 +522,14 @@ def test_the_cached_kernel_refuses_a_mask_a_query_has_to_itself():
 @pytest.mark.parametrize("mask_kind,lowering", [
     ("key_only", "cached"), ("key_only_a_row", "cached"),
     ("per_head", "masked"), ("per_query", "masked"),
-    ("latent_576_512_1", "masked"), ("size_64", "masked")])
+    ("latent_576_512_1", "masked"), ("size_64", "masked"),
+    ("one_query_a_head", "cached")])
 def test_attention_picks_the_cached_kernel_by_what_it_sees(
         mask_kind, lowering, monkeypatch):
     """With the kernels on, a run-time mask over key positions alone and one
-    head size of a multiple of 128 count ``cached`` and run the kernel; a
-    mask a head or a query has to itself, latent attention's absorbed form
+    head size of a multiple of 128 count ``cached`` and run the kernel (one
+    query on a key-value head of its own too); a mask a head or a query has
+    to itself, latent attention's absorbed form
     (576-wide keys, 512-wide values, one key-value head) and a head size of
     64 count ``masked`` and trace to ``masked_attention``."""
     import functools
@@ -541,6 +546,8 @@ def test_attention_picks_the_cached_kernel_by_what_it_sees(
         kv, d, dv = 1, 576, 512
     elif mask_kind == "size_64":
         d = dv = 64
+    elif mask_kind == "one_query_a_head":
+        sq, kv = 1, heads
     q = rng.standard_normal((b, sq, heads, d), dtype=np.float32)
     k = rng.standard_normal((b, sk, kv, d), dtype=np.float32)
     v = k[..., :dv] if dv != d else \
@@ -586,8 +593,10 @@ def test_attention_picks_the_cached_kernel_by_what_it_sees(
 def test_the_cached_kernel_takes_whole_tiles_and_fits_its_memory():
     """What the dispatch asks of the shapes, and the rows and keys a step
     that follow from the VMEM: the cell's (128 rows, 32 query rows a head, a
-    cache of 320: the whole key axis, a divisor of the rows) and a cache
-    beyond one block (blocks of a multiple of 128 keys)."""
+    cache of 320: the whole key axis, a divisor of the rows), a cache beyond
+    one block (blocks of a multiple of 128 keys), and query rows that are no
+    whole sublane tile (``olmo_hybrid_7b``'s one a head against 384 keys:
+    the whole key axis, 16 rows a step)."""
     import jax.numpy as jnp
 
     from synapseml_tpu.parallel import flash
@@ -603,10 +612,17 @@ def test_the_cached_kernel_takes_whole_tiles_and_fits_its_memory():
     assert not takes((16, 1, 32, 576), (16, 320, 1, 576),
                      (16, 320, 1, 512))                   # latent, absorbed
     assert not takes((128, 4, 32, 64), (128, 320, 4, 64))
-    assert not takes((128, 1, 4, 128), (128, 320, 4, 128))    # 1 query row
+    assert takes((128, 1, 4, 128), (128, 320, 4, 128))        # 1 query row
     assert not takes((128, 64, 32, 128), (128, 320, 4, 128))  # 512 of them
     assert takes((128, 1, 32, 128), (128, 320, 4, 128), dtype=jnp.float32)
-    assert not takes((128, 1, 32, 128), (128, 320, 4, 128))   # half a tile
+    assert takes((128, 1, 32, 128), (128, 320, 4, 128))       # half a tile
+    assert takes((128, 1, 30, 128), (128, 384, 30, 128), mask=(1, 384))
+    assert takes((128, 1, 20, 128), (128, 256, 1, 128), mask=(1, 256))
+    # one query row fills a bfloat16 tile of 16 in VMEM, and is counted so
+    rows, keys = flash._cached_blocks(128, 1, 384, 128, 2)
+    assert (rows, keys) == flash._cached_blocks(128, 16, 384, 128, 2) \
+        == (16, 384)
+    assert rows * keys * (4 * 128 * 2 + 12 * 16) <= flash._CACHED_VMEM
     rows, keys = flash._cached_blocks(128, 32, 320, 128, 2)
     assert keys == 320 and 128 % rows == 0 and rows >= 8
     rows, keys = flash._cached_blocks(16, 32, 4224, 128, 2)
@@ -629,34 +645,40 @@ def test_the_cached_attention_tool_rehearses_on_the_cpu(capsys):
         os.path.join(ROOT, "tools", "cached_attention_forms.py"))
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
-    assert tool.main(["--rehearse-on-cpu", "--loads", "cell,long"]) == 0
+    assert tool.main(["--rehearse-on-cpu", "--loads",
+                      "cell,long,olmo,jamba"]) == 0
     head, *lines = [json.loads(line)
                     for line in capsys.readouterr().out.splitlines()]
     assert head["rehearsal"] and head["device"]["platform"] == "cpu"
     assert [(line["load"], line["form"]) for line in lines] == [
-        (load, form) for load in ("cell", "long")
+        (load, form) for load in ("cell", "long", "olmo", "jamba")
         for form in tool.TOY_FORMS[load].split(",")]
     for line in lines:
         assert line["finite"] and line["same_bits_twice"]
         assert line["max_abs_from_dense"] < 0.02 and "ms_a_layer_and_pass" \
             not in line
         assert line["lowering"] == {
-            "masked" if line["form"] == "dense" else "cached": head["layers"]}
+            "masked" if line["form"] == "dense" else "cached": line["layers"]}
     assert [line["rows_keys"] for line in lines[1:5]] == [
         [2, 48], [4, 48], [2, 16], [4, 32]]
-    # the reader of a compiled loop body: one copy of a cache, one of less
+    # the readers of a compiled loop body: one copy of a cache, one of less;
+    # one float32 convert of a cache, one to bfloat16, one outside the loop
     text = """
 %body.1 (p: (s32[], bf16[4,48,256])) -> (s32[], bf16[4,48,256]) {
   %copy.3 = bf16[4,48,2,128]{3,2,1,0} copy(%x)
   %copy.4 = bf16[4,4,256]{2,1,0} copy(%y)
   %fusion.2 = bf16[4,48,256]{2,1,0} fusion(%z), kind=kLoop
+  %convert.5 = f32[4,48,256]{1,2,0} convert(%fusion.2)
+  %convert_fusion.6 = bf16[4,48,256]{2,1,0} fusion(%convert.5), kind=kLoop
 }
 ENTRY %main (a: bf16[4,48,256]) -> bf16[4,48,256] {
   %copy.9 = bf16[4,48,256]{2,1,0} copy(%a)
+  %convert.10 = f32[4,48,256]{2,1,0} convert(%a)
   %while.1 = (s32[], bf16[4,48,256]) while(%t), condition=%cond.1, body=%body.1
 }
 """
     assert tool.cache_copies_in_loop(text, 4 * 48 * 256) == 1
+    assert tool.cache_converts_in_loop(text, 4 * 48 * 256) == 1
 
 
 @pytest.mark.parametrize("block", [2, 4, 8])

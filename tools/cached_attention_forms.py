@@ -1,12 +1,11 @@
 #!/usr/bin/env python3
 """What a few queries against a key-value cache cost in each form, timed on
-the chip -- ``python tools/cached_attention_forms.py`` (PERF.md section 6,
-PR 35; ``synapseml_tpu/parallel/flash.py`` ``_cached_blocks`` cites the
-table).
+the chip -- ``python tools/cached_attention_forms.py`` (PERF.md section 6;
+``synapseml_tpu/parallel/flash.py`` ``_cached_blocks`` cites the table).
 
-The cached ``Attention`` of six layers as a generating pass of
-``sdar_30b_a3b.gen64`` runs it, inside a ``lax.fori_loop`` that carries the
-caches the way an ONNX ``Loop`` does (six layers' keys and values: half a
+The cached ``Attention`` of a load's layers as a generating pass runs it,
+inside a ``lax.fori_loop`` that carries the caches the way an ONNX ``Loop``
+does (``sdar_30b_a3b.gen64``'s six layers' keys and values: half a
 gigabyte, so that they stream from HBM as the cell's do; one layer's would
 sit in the chip's faster memory): a pass writes its block's keys and values
 into each cache (``ops._tensor_scatter``, in place), computes the mask over
@@ -26,9 +25,13 @@ form. A form is
   picks the rows and the keys).
 
 The loads: ``cell`` (128 rows, 4 queries, a cache of 320, 32 / 4 heads of
-128: the cell's), ``q16`` (16 queries: 128 query rows a key-value head, the
-most the dispatch gives the kernel) and ``long`` (16 rows against 4,224
-positions: a cache beyond one block).
+128: ``sdar_30b_a3b.gen64``'s), ``q16`` (16 queries: 128 query rows a
+key-value head, the most the dispatch gives the kernel), ``long`` (16 rows
+against 4,224 positions: a cache beyond one block), ``olmo`` (128 rows, one
+query, 30 / 30 heads of 128, a cache of 384, the two attention layers of
+``olmo_hybrid_7b.s256_gen128``: ONE query row a key-value head) and
+``jamba`` (128 rows, one query, 20 / 1 heads, a cache of 256, the two of
+``jamba2_3b.s128_gen128``: 20 query rows on one key-value head).
 
 A line holds the milliseconds of a layer's ``Attention`` in a pass (a call
 of ``passes`` passes through ``layers`` layers, the median of three sets of
@@ -39,8 +42,9 @@ a hundredth), a layer's cache streamed once at the chip's bandwidth
 one traced call that took longest, how ``_attention`` lowered the node, how
 many instructions of the compiled loop body make a copy of a whole cache
 (``cache_copies_in_loop``: a ``copy``, ``reshape`` or ``transpose`` whose
-result has the cache's elements), the largest difference from the dense
-form's answer and whether two calls gave the same bits. A form the chip's
+result has the cache's elements) and a float32 convert of one
+(``cache_converts_in_loop``), the largest difference from the dense form's
+answer and whether two calls gave the same bits. A form the chip's
 compiler refuses gives its error in place of a time. ``--rehearse-on-cpu``
 runs the same code at toy sizes through the Pallas interpreter and prints
 no time.
@@ -64,17 +68,24 @@ for path in (REPO, os.path.join(REPO, "tools")):
 
 # load -> rows, queries a row, cache positions, query heads, key-value heads
 LOADS = {"cell": (128, 4, 320, 32, 4), "q16": (128, 16, 320, 32, 4),
-         "long": (16, 4, 4224, 32, 4)}
+         "long": (16, 4, 4224, 32, 4), "olmo": (128, 1, 384, 30, 30),
+         "jamba": (128, 1, 256, 20, 1)}
 TOY = {"cell": (4, 4, 48, 8, 2), "q16": (4, 8, 48, 8, 2),
-       "long": (2, 4, 96, 8, 2)}
+       "long": (2, 4, 96, 8, 2), "olmo": (4, 1, 48, 4, 4),
+       "jamba": (4, 1, 32, 20, 1)}
+# layers whose caches a pass carries, where the cell has other than six
+LAYERS = {"olmo": 2, "jamba": 2}
 SIZE = 128
 FORMS = {"cell": "dense,4:whole,8:whole,16:whole,32:whole,8:128,16:128,"
                  "shipped",
          "q16": "dense,4:whole,8:whole,8:128,shipped",
-         "long": "dense,4:512,8:512,8:640,4:1024,2:2048,shipped"}
+         "long": "dense,4:512,8:512,8:640,4:1024,2:2048,shipped",
+         "olmo": "dense,4:whole,8:whole,shipped",
+         "jamba": "dense,4:whole,8:whole,shipped"}
 TOY_FORMS = {"cell": "dense,2:whole,4:whole,2:16,4:32,shipped",
              "q16": "dense,2:whole,2:16,shipped",
-             "long": "dense,1:32,2:16,shipped"}
+             "long": "dense,1:32,2:16,shipped",
+             "olmo": "dense,2:16,shipped", "jamba": "dense,2:16,shipped"}
 
 
 @contextlib.contextmanager
@@ -140,24 +151,37 @@ def passes_of_the_layers(passes: int, heads: int, kv_heads: int, prompt: int,
     return run
 
 
-def cache_copies_in_loop(text: str, cache_elements: int) -> int:
-    """Instructions of a compiled program's loop bodies that lay a whole
-    cache out again: a ``copy``, ``reshape`` or ``transpose`` (fused or not)
-    whose result has the cache's element count."""
+def _loop_body_results(text: str):
+    """``(name, type, element count)`` of every instruction of a compiled
+    program's loop bodies (fusions included, not what they call)."""
     bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
-    found, inside = 0, False
+    inside = False
     for line in text.splitlines():
         head = re.match(r"\s*(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
         if head:
             inside = head.group(1) in bodies
         elif inside:
-            made = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]",
-                            line)
-            if made and re.search(r"copy|reshape|transpose", made.group(1)):
-                found += math.prod(
-                    int(d) for d in made.group(2).split(",") if d
-                ) == cache_elements
-    return found
+            made = re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]", line)
+            if made:
+                yield made.group(1), made.group(2), math.prod(
+                    int(d) for d in made.group(3).split(",") if d)
+
+
+def cache_copies_in_loop(text: str, cache_elements: int) -> int:
+    """Instructions of a compiled program's loop bodies that lay a whole
+    cache out again: a ``copy``, ``reshape`` or ``transpose`` (fused or not)
+    whose result has the cache's element count."""
+    return sum(size == cache_elements
+               and bool(re.search(r"copy|reshape|transpose", name))
+               for name, _, size in _loop_body_results(text))
+
+
+def cache_converts_in_loop(text: str, cache_elements: int) -> int:
+    """Instructions of a compiled program's loop bodies that convert a whole
+    cache to float32 (fused or not)."""
+    return sum(size == cache_elements and kind == "f32" and "convert" in name
+               for name, kind, size in _loop_body_results(text))
 
 
 def main(argv=None):
@@ -187,14 +211,15 @@ def main(argv=None):
         return 3
     with open(os.path.join(REPO, "benchmark", "peaks.json")) as f:
         peaks = json.load(f).get(device.device_kind)
-    passes, layers = (48, 6) if on_chip else (3, 2)
+    passes = 48 if on_chip else 3
     print(json.dumps({"device": {"platform": device.platform,
                                  "kind": device.device_kind},
-                      "size": SIZE, "passes": passes, "layers": layers,
+                      "size": SIZE, "passes": passes,
                       "rehearsal": not on_chip}), flush=True)
 
     for load in args.loads.split(","):
         rows, s_q, length, heads, kv_heads = (LOADS if on_chip else TOY)[load]
+        layers = LAYERS.get(load, 6) if on_chip else 2
         keys = jax.random.split(jax.random.PRNGKey(args.seed),
                                 3 + 2 * layers)
 
@@ -210,7 +235,8 @@ def main(argv=None):
         forms = args.forms or (FORMS if on_chip else TOY_FORMS)[load]
         for form in forms.split(","):
             line = {"load": load, "form": form,
-                    "shape": [rows, s_q, length, heads, kv_heads]}
+                    "shape": [rows, s_q, length, heads, kv_heads],
+                    "layers": layers}
             notes = {}
             try:
                 with form_of(form, interpret=not on_chip):
@@ -230,8 +256,11 @@ def main(argv=None):
             line["lowering"] = {
                 key[len("attention_"):]: count for key, count in notes.items()
                 if key in ("attention_cached", "attention_masked")}
+            text = fn.as_text()
             line["cache_copies_in_loop"] = cache_copies_in_loop(
-                fn.as_text(), cache.size)
+                text, cache.size)
+            line["cache_converts_in_loop"] = cache_converts_in_loop(
+                text, cache.size)
             answer = np.asarray(fn(*given).astype(jnp.float32))
             line["same_bits_twice"] = bool(
                 (np.asarray(fn(*given).astype(jnp.float32)) == answer).all())
