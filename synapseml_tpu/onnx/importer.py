@@ -652,9 +652,12 @@ class OnnxFunction:
             ("fn", "form"))
         delta = reg.counter(
             "smt_onnx_gated_delta_lowering_total",
-            "GatedDeltaRule nodes of a traced program by lowering: chunked "
-            "(more than one position: the WY form, matrix products over "
-            "chunks), kernel (one position: the Pallas kernel reads and "
+            "GatedDeltaRule nodes of a traced program by lowering: "
+            "chunked_kernel (more than one position: the WY form as a "
+            "Pallas kernel, a row's state and a chunk's products in VMEM), "
+            "chunked (more than one position: the WY form as XLA's matrix "
+            "products over chunks: not a TPU, or shapes the kernel does not "
+            "take), kernel (one position: the Pallas kernel reads and "
             "writes the state once, in place) or step (one position, plain "
             "jax.numpy: not a TPU, or shapes the kernel does not take)",
             ("fn", "form"))

@@ -1826,12 +1826,15 @@ def _gated_delta_rule(inputs, attrs, ctx):
     the last position: ONE operator is a prompt pass's rule and a generating
     loop's single step.
 
-    Three lowerings, from shapes and the backend alone: ``chunked`` (more
-    than one position: the WY form, matrix products over chunks of 64),
-    ``kernel`` (one position, the kernels on, rows in groups of 8 and the
-    heads' lanes in whole 128-lane groups: ONE Pallas kernel that reads and
-    writes the state once, in place), ``step`` (one position otherwise:
-    plain ``jax.numpy``). The program's notes count each, and the bytes of
+    Four lowerings, from shapes and the backend alone: ``chunked_kernel``
+    (more than one position, the kernels on and the heads' lanes in whole
+    128-lane groups: the WY form over chunks of 64 as ONE Pallas kernel that
+    keeps a row's state and a chunk's float32 products in VMEM),
+    ``chunked`` (more than one position otherwise: the same form as XLA's
+    matrix products), ``kernel`` (one position, the kernels on, rows in
+    groups of 8 and the heads' lanes in whole 128-lane groups: ONE Pallas
+    kernel that reads and writes the state once, in place), ``step`` (one
+    position otherwise: plain ``jax.numpy``). The program's notes count each, and the bytes of
     state the single positions take in."""
     from ..parallel import gated_delta as rule
 
@@ -1850,7 +1853,9 @@ def _gated_delta_rule(inputs, attrs, ctx):
             f"[rows, S, H, dk] twice, [rows, S, H, dv], [rows, S, H] twice, "
             f"[rows, dk, H x dv]")
     if s > 1:
-        form = "chunked"
+        takes = _kernels_on() and rule.chunked_kernel_takes(rows, s, h, dk,
+                                                            dv)
+        form = "chunked_kernel" if takes else "chunked"
     else:
         if state_in is not None:
             _note(ctx, "recurrent_state_bytes", rows * dk * h * dv * 4)

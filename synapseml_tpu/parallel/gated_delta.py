@@ -2,7 +2,7 @@
 arXiv:2412.06464) as the published ``chunk_gated_delta_rule(q, k, v, g,
 beta, initial_state=..., output_final_state=True,
 use_qk_l2norm_in_kernel=True)`` computes it at its default ``scale``, in
-three forms of one arithmetic.
+four forms of one arithmetic.
 
 For a row and a head, over positions ``t`` in order (``q``, ``k`` of ``dk``
 numbers, ``v`` of ``dv``, ``g`` and ``beta`` scalars)::
@@ -32,6 +32,9 @@ whole tiles.
   state leaving is ``G_C S0 + (K * G_C / G)^T U``: matrix products, and the
   state crosses HBM once a chunk. Padded positions carry ``g`` 0 and
   ``beta`` 0 and leave the state as it was.
+- :func:`chunked_kernel_form`: the same form as ONE Pallas kernel: a grid
+  step is a row and a chunk, the row's state and the chunk's float32
+  products stay in VMEM, and the state crosses HBM once a row.
 - :func:`step_form`: one position in plain ``jax.numpy`` over the state as it
   lies: a head's ``k`` and ``q`` are spread over its ``dv`` lanes.
 - :func:`kernel_form`: one position as ONE Pallas kernel: a grid step owns a
@@ -44,7 +47,8 @@ from __future__ import annotations
 
 import math
 
-__all__ = ["chunked_form", "step_form", "kernel_form", "kernel_takes"]
+__all__ = ["chunked_form", "chunked_kernel_form", "chunked_kernel_takes",
+           "step_form", "kernel_form", "kernel_takes"]
 
 # positions of a chunk of the WY form (the published kernels' 64)
 _CHUNK = 64
@@ -53,6 +57,9 @@ _CHUNK = 64
 _ROWS = 8
 # the published ``l2norm``'s epsilon
 _EPS = 1e-6
+# VMEM the chunked kernel may hold (v5e has 128 MiB): its double-buffered
+# blocks and a chunk's products
+_VMEM_LIMIT = 64 * 2 ** 20
 
 
 def _normed(q, k):
@@ -123,6 +130,204 @@ def chunked_form(q, k, v, g, beta, state_in=None, chunk: int = _CHUNK):
         rows, n * c, h, dv)[:, :s]
     return o.astype(v.dtype), \
         jnp.swapaxes(state, 1, 2).reshape(rows, dk, h * dv)
+
+
+def _chunked_vmem_bytes(h: int, dk: int, dv: int) -> int:
+    """VMEM of :func:`chunked_kernel_form`'s blocks, each held twice by the
+    pipeline, at chunks of ``_CHUNK`` and float32 operands (the most they
+    can be): ``q`` and ``k`` (``dk`` padded to a lane block), ``v`` and the
+    output, the entering and leaving state, ``g`` and ``beta``."""
+    lanes = -(-dk // 128) * 128
+    qk = 2 * h * _CHUNK * lanes * 4
+    by_lanes = 2 * _CHUNK * h * dv * 4
+    state = 2 * dk * h * dv * 4
+    per_head = 2 * _CHUNK * -(-h // 128) * 128 * 4
+    return 2 * (qk + by_lanes + state + per_head)
+
+
+def chunked_kernel_takes(rows: int, s: int, h: int, dk: int, dv: int) -> bool:
+    """Whether :func:`chunked_kernel_form` compiles for these shapes: more
+    than one position (any number of rows, any length: the last chunk is
+    padded), a head's ``dk`` in whole sublane tiles, the heads' lanes in
+    whole groups (:func:`_group`), and a row's blocks in half the VMEM the
+    kernel asks for."""
+    del rows
+    return s > 1 and dk % 8 == 0 and (h * dv) % _group(dv) == 0 \
+        and _chunked_vmem_bytes(h, dk, dv) <= _VMEM_LIMIT // 2
+
+
+def _unit_lower_inverse(a, c: int, per: int):
+    """``(I + A)^-1`` of ``per`` strictly lower triangular ``[C, C]`` side by
+    side (``a`` ``[C, per C]``) by forward substitution, a column at a time:
+    once the columns before ``j`` are taken out, row ``j`` is final, and
+    every later row takes out ``A[t, j]`` times it. Float32 on the vector
+    unit; the heads of a group share each step's lanes, and a step leaves
+    the sublane tiles above row ``j + 1`` as they are."""
+    import jax
+    import jax.numpy as jnp
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, per * c), 1)
+    t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    eye = jnp.where(t == jax.lax.broadcasted_iota(jnp.int32, (c, c), 1),
+                    1.0, 0.0).astype(jnp.float32)
+    x = jnp.concatenate([eye] * per, axis=1)
+    for j in range(c - 1):
+        col = a[:, j:j + 1]
+        for i in range(1, per):
+            col = jnp.where(lane >= i * c, a[:, i * c + j:i * c + j + 1], col)
+        below = (j + 1) // 8 * 8
+        taken = x[below:] - col[below:] * x[j:j + 1, :]
+        x = jnp.concatenate([x[:below], taken]) if below else taken
+    return x
+
+
+def chunked_kernel_form(q, k, v, g, beta, state_in=None, chunk: int = _CHUNK,
+                        interpret: bool = False):
+    """The WY form of :func:`chunked_form` as ONE Pallas kernel; -> ``(out
+    [rows, S, H, dv], state_out [rows, dk, H x dv] float32)``.
+
+    A grid step is a row and a chunk, the chunks in order: the row's state
+    ``[dk, H x dv]`` lives in the leaving state's VMEM block across them
+    (from ``state_in``, or zero, at the first) and goes to HBM once, after
+    the last, in the layout the decode step takes. Inside a step the heads go
+    in groups of :func:`_group` lanes (two heads of 192), a ``fori_loop``
+    over groups; for each head ``q`` and ``k`` normed as :func:`_normed`
+    does, ``G`` the cumulative ``g`` (every head at once, by doubling
+    shifts down the chunk), the strictly lower ``A``, ``(I + A)^-1`` by
+    forward substitution (:func:`_unit_lower_inverse`, the group's heads side
+    by side), then ``W``, ``U~``, ``U = U~ - W S``, ``O = diag(G) Q S + (Q
+    K^T * M) U`` and ``S <- G_C S + (K * G_C / G)^T U``: float32 throughout,
+    every product at ``HIGHEST``, as :func:`chunked_form` has them. ``O`` is
+    rounded once, to ``v``'s type, into ``[rows, S, H x dv]``. ``q`` and
+    ``k`` cross HBM once more, heads first ``[rows, H, S, dk]`` in their own
+    type, so that a head is a leading index. Positions past ``S`` are
+    padded with ``g`` 0 and ``beta`` 0 and leave the state as it was.
+    :func:`chunked_kernel_takes` says which shapes Mosaic compiles; the
+    interpreter takes the same."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32, hp = jnp.float32, jax.lax.Precision.HIGHEST
+    rows, s, h, dk = q.shape
+    dv = v.shape[-1]
+    if not chunked_kernel_takes(rows, s, h, dk, dv) or chunk % 8:
+        raise ValueError(f"chunked_kernel_form: {rows} rows x {s} positions "
+                         f"of {h} heads of {dk} / {dv} in chunks of {chunk}: "
+                         f"more than one position, dk of whole sublane "
+                         f"tiles, the heads' lanes in groups of {_group(dv)}, "
+                         f"chunks of whole sublane tiles")
+    c = chunk
+    pad = (-s) % c
+    n = (s + pad) // c
+    wide, width = h * dv, _group(dv)
+    per = width // dv
+
+    def padded(x):
+        return jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+
+    operands = [jnp.swapaxes(padded(q), 1, 2), jnp.swapaxes(padded(k), 1, 2),
+                padded(v).reshape(rows, n * c, wide),
+                padded(g.astype(f32)), padded(beta.astype(f32))]
+    if state_in is not None:
+        operands.append(jnp.asarray(state_in, f32))
+
+    def dot(a, b, dims=((1,), (0,))):
+        return jax.lax.dot_general(a, b, (dims, ((), ())), precision=hp,
+                                   preferred_element_type=f32)
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *refs):
+        out_ref, state_ref, g_rows_ref = refs[-3:]
+
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            state_ref[0] = refs[0][0] if state_in is not None \
+                else jnp.zeros((dk, wide), f32)
+
+        t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+        src = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+        at = jax.lax.broadcasted_iota(jnp.int32, (c, h), 0)
+        head_lane = jax.lax.broadcasted_iota(jnp.int32, (c, h), 1)
+        gam = g_ref[0]                                  # [C, H]
+        shift = 1
+        while shift < c:
+            gam = gam + jnp.where(at >= shift, pltpu.roll(gam, shift, 0), 0.0)
+            shift *= 2
+        g_rows_ref[...] = gam.T                         # [H, C]
+        betas = beta_ref[0]
+
+        def column(x, head):  # [C, H] -> the head's [C, 1]
+            return jnp.sum(jnp.where(head_lane == head, x, 0.0), axis=1,
+                           keepdims=True)
+
+        def group(i, carry):
+            lanes = pl.ds(pl.multiple_of(i * width, 128), width)
+            state = state_ref[0, :, lanes]
+            values = v_ref[0, :, lanes].astype(f32)
+            heads = []
+            for j in range(per):
+                head = i * per + j
+                qn, kn = _normed(q_ref[0, head], k_ref[0, head])
+                gcol, bcol = column(gam, head), column(betas, head)
+                # G_t / G_s for s <= t, 0 above (no exponent there)
+                decay = jnp.where(t >= src, jnp.exp(jnp.where(
+                    t >= src, gcol - g_rows_ref[pl.ds(head, 1), :], 0.0)),
+                    0.0)
+                # Q K^T and K K^T as one product: two chunks of rows
+                qk_kk = dot(jnp.concatenate([qn, kn]), kn, ((1,), (1,)))
+                a = jnp.where(t > src, qk_kk[c:] * decay * bcol, 0.0)
+                heads.append((qn, kn, gcol, bcol, qk_kk[:c] * decay, a))
+            inverse = _unit_lower_inverse(
+                jnp.concatenate([x[-1] for x in heads], axis=1), c, per)
+            outs, states = [], []
+            for j, (qn, kn, gcol, bcol, qk, _) in enumerate(heads):
+                inv = inverse[:, j * c:(j + 1) * c]
+                s_j = state[:, j * dv:(j + 1) * dv]
+                grown = jnp.exp(gcol)
+                # W S and diag(G) Q S as one product
+                ws_qs = dot(jnp.concatenate([dot(inv, kn * (bcol * grown)),
+                                             qn * grown]), s_j)
+                u = dot(inv, values[:, j * dv:(j + 1) * dv] * bcol) \
+                    - ws_qs[:c]
+                outs.append(ws_qs[c:] + dot(qk, u))
+                last = gcol[c - 1:c, :]
+                states.append(s_j * jnp.exp(last) + dot(
+                    kn * jnp.exp(last - gcol), u, ((0,), (0,))))
+            state_ref[0, :, lanes] = jnp.concatenate(states, axis=1)
+            out_ref[0, :, lanes] = jnp.concatenate(outs, axis=1).astype(
+                out_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, wide // width, group, 0)
+
+    def of_chunk(r, ci):
+        return r, ci, 0
+
+    def of_row(r, ci):
+        return r, 0, 0
+
+    heads_block = pl.BlockSpec((1, h, c, dk), lambda r, ci: (r, 0, ci, 0))
+    lanes_block = pl.BlockSpec((1, c, wide), of_chunk)
+    per_head_block = pl.BlockSpec((1, c, h), of_chunk)
+    state_block = pl.BlockSpec((1, dk, wide), of_row)
+    out, state = pl.pallas_call(
+        kernel,
+        grid=(rows, n),
+        in_specs=[heads_block, heads_block, lanes_block, per_head_block,
+                  per_head_block] + [state_block] * (state_in is not None),
+        out_specs=[lanes_block, state_block],
+        out_shape=[jax.ShapeDtypeStruct((rows, n * c, wide), v.dtype),
+                   jax.ShapeDtypeStruct((rows, dk, wide), f32)],
+        scratch_shapes=[pltpu.VMEM((h, c), f32)],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            # the state is carried from a row's chunk to the next
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="gated_delta_chunked",
+    )(*operands)
+    return out.reshape(rows, n * c, h, dv)[:, :s], state
 
 
 def _lanes(x, dv: int):

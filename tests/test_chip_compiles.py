@@ -248,7 +248,7 @@ def test_selective_scan_compiles_for_a_v5e(rows, length, entering, one_chip,
 
 @pytest.mark.parametrize("rows,length,entering", [
     (128, 1, True),       # a decode pass of olmo_hybrid_7b.s256_gen128
-    (128, 256, False),    # its prompt pass: the chunked form, no kernel
+    (128, 256, False),    # its prompt pass: the chunked kernel
 ])
 def test_gated_delta_rule_compiles_for_a_v5e(rows, length, entering,
                                              one_chip, monkeypatch):
@@ -257,7 +257,9 @@ def test_gated_delta_rule_compiles_for_a_v5e(rows, length, entering,
     and state) through ``ops._gated_delta_rule`` with the kernels on: a
     single position is ONE custom call that writes the state where it read
     it (aliased, no copy of it), in the unpadded ``[rows, 96, 5760]``; a
-    prompt is the chunked form, no custom call."""
+    prompt is ONE custom call too, the chunked kernel, and no float32
+    intermediate of the chunked form's ``[rows, H, chunks, 64, ...]`` is
+    left in the program."""
     import jax
     import jax.numpy as jnp
 
@@ -291,8 +293,9 @@ def test_gated_delta_rule_compiles_for_a_v5e(rows, length, entering,
         assert not [line for line in text.splitlines()
                     if " copy(" in line and state in line.split(" copy(")[0]]
     else:
-        assert notes == {"gated_delta_chunked": 1}
-        assert "tpu_custom_call" not in text
+        assert notes == {"gated_delta_chunked_kernel": 1}
+        assert text.count("tpu_custom_call") == 1
+        assert f"f32[{rows},{h},{length // 64},64," not in text
     out, last = jax.eval_shape(rule, *operands)
     assert out.shape == (rows, length, h, dv) and out.dtype == jnp.bfloat16
     assert last.shape == (rows, dk, h * dv) and last.dtype == jnp.float32

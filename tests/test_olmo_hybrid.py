@@ -192,10 +192,13 @@ def _loop_by_hand(q, k, v, g, beta, state_in=None):
 @pytest.mark.parametrize("form,s,options", [
     ("chunked", 37, {}), ("chunked", 37, {"chunk": 8}),
     ("chunked", 16, {"chunk": 16}), ("chunked", 1, {}),
-    ("step", 1, {}), ("kernel", 1, {"interpret": True})])
+    ("step", 1, {}), ("kernel", 1, {"interpret": True}),
+    ("chunked_kernel", 37, {"interpret": True}),
+    ("chunked_kernel", 37, {"chunk": 16, "interpret": True}),
+    ("chunked_kernel", 32, {"chunk": 16, "interpret": True})])
 def test_the_forms_agree_with_a_loop_by_hand(form, s, options, entering):
     """Every form, from a state and from none: whole chunks, a part of one,
-    one position; the kernel through the interpreter."""
+    one position; the kernels through the interpreter."""
     import jax.numpy as jnp
 
     from synapseml_tpu.parallel import gated_delta as rule
@@ -232,6 +235,53 @@ def test_the_kernel_takes_two_heads_of_192_a_group_of_lanes():
                                atol=2e-5)
 
 
+def test_the_chunked_kernel_takes_two_heads_of_192_a_group_of_lanes():
+    """``dv`` 192 (the cell's): a group of 384 lanes holds two heads, whose
+    inverses share the forward substitution's lanes; a length off the chunk
+    is padded."""
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import gated_delta as rule
+
+    assert rule.chunked_kernel_takes(128, 256, 30, 96, 192)
+    assert rule.chunked_kernel_takes(3, 5, 30, 96, 192)  # any rows, length
+    assert not rule.chunked_kernel_takes(8, 1, 30, 96, 192)   # one position
+    assert not rule.chunked_kernel_takes(8, 64, 3, 96, 192)   # 576 lanes
+    assert not rule.chunked_kernel_takes(8, 64, 4, 12, 192)   # dk off 8
+    assert not rule.chunked_kernel_takes(8, 64, 256, 96, 192)  # VMEM
+    case = _case(np.random.default_rng(4), 3, 21, h=4, dk=16, dv=192)
+    got, state = rule.chunked_kernel_form(
+        *(jnp.asarray(case[k]) for k in ("q", "k", "v", "g", "beta")),
+        case["state_in"], chunk=8, interpret=True)
+    want, want_state = _loop_by_hand(**case)
+    assert got.shape == (3, 21, 4, 192)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(state), want_state, rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_the_chunked_kernels_state_then_one_step_is_one_more_position():
+    """The chunked kernel's leaving state, then the decode kernel's single
+    step, is the chunked kernel over one more position: the prompt pass's
+    state is laid out as the decode loop takes it."""
+    import jax.numpy as jnp
+
+    from synapseml_tpu.parallel import gated_delta as rule
+
+    case = _case(np.random.default_rng(7), 8, 25, entering=False)
+    ops_ = [jnp.asarray(case[k]) for k in ("q", "k", "v", "g", "beta")]
+    whole, whole_state = rule.chunked_kernel_form(*ops_, chunk=8,
+                                                  interpret=True)
+    _, state = rule.chunked_kernel_form(*(x[:, :-1] for x in ops_), chunk=8,
+                                        interpret=True)
+    last, last_state = rule.kernel_form(*(x[:, -1:] for x in ops_), state,
+                                        interpret=True)
+    np.testing.assert_allclose(np.asarray(last), np.asarray(whole[:, -1:]),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(last_state),
+                               np.asarray(whole_state), rtol=2e-5, atol=2e-5)
+
+
 def test_one_step_from_a_state_is_the_next_position_of_a_longer_run():
     """The chunked form's leaving state, then one step, is the chunked form
     over one more position: a prompt pass hands a decode pass all it
@@ -254,13 +304,13 @@ def test_one_step_from_a_state_is_the_next_position_of_a_longer_run():
                                np.asarray(whole_state), rtol=1e-5, atol=1e-5)
 
 
-def _rule_node_model(rows, s, entering):
+def _rule_node_model(rows, s, entering, h=HEADS):
     from synapseml_tpu.onnx import builder as ob
 
     names = ["q", "k", "v", "g", "beta"] + ["state_in"] * entering
-    shapes = dict(q=(rows, s, HEADS, DK), k=(rows, s, HEADS, DK),
-                  v=(rows, s, HEADS, DV), g=(rows, s, HEADS),
-                  beta=(rows, s, HEADS), state_in=(rows, DK, HEADS * DV))
+    shapes = dict(q=(rows, s, h, DK), k=(rows, s, h, DK),
+                  v=(rows, s, h, DV), g=(rows, s, h),
+                  beta=(rows, s, h), state_in=(rows, DK, h * DV))
     return _model(
         [ob.node("GatedDeltaRule", names, ["out", "state_out"], name="gdn",
                  domain="synapseml_tpu")],
@@ -268,23 +318,26 @@ def _rule_node_model(rows, s, entering):
         ["out", "state_out"], domain="synapseml_tpu")
 
 
-@pytest.mark.parametrize("rows,s,kernels,form", [
-    (8, 16, True, "chunked"), (8, 16, False, "chunked"),
-    (8, 1, True, "kernel"), (8, 1, False, "step"), (4, 1, True, "step")])
+@pytest.mark.parametrize("rows,s,kernels,form,h", [
+    (8, 16, True, "chunked_kernel", HEADS), (8, 16, False, "chunked", HEADS),
+    (8, 16, True, "chunked", 3),   # 96 lanes: no whole group of heads
+    (8, 1, True, "kernel", HEADS), (8, 1, False, "step", HEADS),
+    (4, 1, True, "step", HEADS)])
 def test_the_operator_chooses_its_lowering_from_shapes_and_backend(
-        rows, s, kernels, form, monkeypatch):
+        rows, s, kernels, form, h, monkeypatch):
     """Through ``OnnxFunction``: the lowering, the note that counts it, the
     bytes of state a single position takes in, and the same answer
-    whichever ran (the kernel through the interpreter)."""
+    whichever ran (the kernels through the interpreter)."""
     from synapseml_tpu.onnx import ops
     from synapseml_tpu.parallel import gated_delta as rule
 
     _fresh_programs(monkeypatch)
     monkeypatch.setattr(ops, "_kernels_on", lambda: kernels)
-    monkeypatch.setattr(rule, "kernel_form", functools.partial(
-        rule.kernel_form, interpret=True))
-    case = _case(np.random.default_rng(8), rows, s)
-    fn = OnnxFunction(_rule_node_model(rows, s, True))
+    for name in ("kernel_form", "chunked_kernel_form"):
+        monkeypatch.setattr(rule, name, functools.partial(
+            getattr(rule, name), interpret=True))
+    case = _case(np.random.default_rng(8), rows, s, h=h)
+    fn = OnnxFunction(_rule_node_model(rows, s, True, h))
     family = "smt_onnx_gated_delta_lowering_total"
     before = _gauge(family, fn=fn._fn_name)
     got = fn(case)
@@ -366,10 +419,13 @@ def test_the_forms_tool_rehearses_on_the_cpu(capsys):
     lines = [json.loads(line) for line in
              capsys.readouterr().out.strip().splitlines()]
     assert [(line["load"], line["form"]) for line in lines] == [
-        ("decode", "step"), ("decode", "kernel"), ("prompt", "chunked")]
-    # no time from a CPU; the kernel's state is the plain step's
+        ("decode", "step"), ("decode", "kernel"), ("prompt", "chunked"),
+        ("prompt", "chunked_kernel")]
+    # no time from a CPU; the kernels' answers are the plain forms'
     assert not [k for line in lines for k in line if "ms" in k or "gb" in k]
     assert lines[1]["max_diff_from_step"] < 1e-5
+    assert lines[3]["max_diff_out_from_chunked"] < 2e-5
+    assert lines[3]["max_diff_state_from_chunked"] < 2e-5
 
 
 @pytest.mark.parametrize("config,kwargs", [

@@ -1,7 +1,7 @@
 """The gated delta rule's forms timed alone at ``olmo_hybrid_7b``'s widths
 (30 heads of 96 / 192) on the chip: what ``PERF.md`` cites for the decode
 step's kernel against plain ``jax.numpy``, and for the prompt pass's
-chunked form.
+chunked form as XLA's products against the Pallas kernel.
 
     python3 tools/gated_delta_forms.py [--rows 128] [--passes 32] [--prompt 256] [--rehearse-on-cpu]
 
@@ -9,10 +9,13 @@ chunked form.
 delta rule layer's state (6 of the cell's 8 layers), one form each:
 ``kernel`` (the Pallas kernel, the state written in place) and ``step``
 (``jax.numpy`` over the same layout); ms a pass, and GB/s of the state read
-and written. ``prompt``: one layer's chunked form over ``--prompt``
-positions a row from no state, ms a layer. Every form's answer is held to
-the ``step`` / float32 loop's (largest difference printed). One JSON line a
-load and form. ``--rehearse-on-cpu``: toy sizes, the kernel through the
+and written. ``prompt``: one layer's rule over ``--prompt`` positions a row
+from no state, ms a layer, in two forms: ``chunked`` (XLA's products) and
+``chunked_kernel`` (the Pallas kernel), timed on the cell's bfloat16
+operands and compared on the same numbers in float32 (largest difference
+of the output and of the leaving state from ``chunked``). A decode form's
+answer is held to the ``step`` form's. One JSON line a load and form.
+``--rehearse-on-cpu``: toy sizes, the kernel through the
 interpreter; no time means anything there.
 """
 
@@ -98,13 +101,25 @@ def main(argv=None) -> int:
     prompt = (draw(rows, s, h, dk), draw(rows, s, h, dk), draw(rows, s, h, dv),
               -jnp.abs(draw(rows, s, h, dtype=jnp.float32)),
               jax.nn.sigmoid(draw(rows, s, h, dtype=jnp.float32)) * 2)
-    chunked = jax.jit(rule.chunked_form)
-    seconds = _timed(chunked, *prompt)
-    line = {"load": "prompt", "form": "chunked", "rows": rows,
-            "positions": s, "device": device.device_kind}
-    if not args.rehearse_on_cpu:
-        line["ms_a_layer"] = seconds * 1e3
-    print(json.dumps(line), flush=True)
+    # the same numbers in float32, so that the output is compared unrounded
+    wide = [x.astype(jnp.float32) for x in prompt]
+    forms = {"chunked": jax.jit(rule.chunked_form),
+             "chunked_kernel": jax.jit(functools.partial(
+                 rule.chunked_kernel_form, interpret=args.rehearse_on_cpu))}
+    want = [np.asarray(x) for x in forms["chunked"](*wide)]
+    for name, form in forms.items():
+        seconds = _timed(form, *prompt)
+        got = [np.asarray(x) for x in form(*wide)]
+        line = {"load": "prompt", "form": name, "rows": rows,
+                "positions": s, "max_diff_out_from_chunked": float(
+                    np.abs(got[0] - want[0]).max()),
+                "max_diff_state_from_chunked": float(
+                    np.abs(got[1] - want[1]).max()),
+                "max_abs_out": float(np.abs(want[0]).max()),
+                "device": device.device_kind}
+        if not args.rehearse_on_cpu:
+            line["ms_a_layer"] = seconds * 1e3
+        print(json.dumps(line), flush=True)
     return 0
 
 
