@@ -11,7 +11,7 @@ own and are ``[q_nope (nope); q_rope (rope)]`` a head; the values are ``v``
 wide, which is not the queries' ``nope + rope``. The rotation turns
 NEIGHBOURING pairs of the ``rope`` numbers (``rope_interleave``). ``FFN_0``
 (the leading dense layer) is a gated feed-forward; every later ``FFN_i`` a
-sigmoid router over all experts (``nemotron_h._router``: the same
+sigmoid router over all experts (``decoder.router``: the same
 equations), gated routed experts of which THIS chip holds ``experts_held``
 from ``first_expert`` on, and a gated shared expert that every chip computes.
 
@@ -62,15 +62,16 @@ import numpy as np
 from ..onnx.builder import constant_node, make_graph, make_model, node, \
     value_info
 from ..onnx.wire import DataType, ModelProto
-from .nemotron_h import EXPERT_DOMAIN, _Weights, _router
-from .sdar_moe import _infos, _of_shape
+from .decoder import EXPERT_DOMAIN, KINDS, STATE, Weights, choose, \
+    decode_loop, decode_tail, first_token, gated_ffn, gated_weights, infos, \
+    router
 
 __all__ = ["joyai_flash"]
 
 _FLOAT = DataType.FLOAT
 
 
-def _attention_weights(w: _Weights, z: _Sizes, p: str) -> None:
+def _attention_weights(w: Weights, z: _Sizes, p: str) -> None:
     h, qk = z.hidden, z.nope + z.rope
     w.full(p + "_norm_in_w", (h,), 1.0)
     w.normal(p + "_dq_w", (h, z.q_rank), h ** -0.5)
@@ -84,32 +85,14 @@ def _attention_weights(w: _Weights, z: _Sizes, p: str) -> None:
     w.full(p + "_norm_post_w", (h,), 1.0)
 
 
-def _gated_weights(w: _Weights, p: str, hidden: int, width: int) -> None:
-    w.normal(p + "_gate_w", (hidden, width), hidden ** -0.5)
-    w.normal(p + "_up_w", (hidden, width), hidden ** -0.5)
-    w.normal(p + "_down_w", (width, hidden), width ** -0.5)
-
-
-def _expert_weights(w: _Weights, z: _Sizes, p: str) -> None:
+def _expert_weights(w: Weights, z: _Sizes, p: str) -> None:
     h, f = z.hidden, z.expert_width
     w.normal(p + "_router_w", (h, z.experts), h ** -0.5)
     w.normal(p + "_router_bias", (z.experts,), 0.01)
     w.normal(p + "_experts_gate", (z.experts_held, h, f), h ** -0.5)
     w.normal(p + "_experts_up", (z.experts_held, h, f), h ** -0.5)
     w.normal(p + "_experts_down", (z.experts_held, f, h), f ** -0.5)
-    _gated_weights(w, p + "_shared", h, z.shared_width)
-
-
-def _gated_ffn(add, p: str, wp: str, u: str) -> str:
-    """``(silu(u G) * (u U)) D`` with the weights ``wp_{gate,up,down}_w``."""
-    add(node("MatMul", [u, wp + "_gate_w"], [p + "_g"], name=p + "_gate"))
-    add(node("Sigmoid", [p + "_g"], [p + "_g_s"], name=p + "_silu_s"))
-    add(node("Mul", [p + "_g", p + "_g_s"], [p + "_g_a"], name=p + "_silu"))
-    add(node("MatMul", [u, wp + "_up_w"], [p + "_u"], name=p + "_up"))
-    add(node("Mul", [p + "_g_a", p + "_u"], [p + "_h"], name=p + "_gated"))
-    add(node("MatMul", [p + "_h", wp + "_down_w"], [p + "_out"],
-             name=p + "_down"))
-    return p + "_out"
+    gated_weights(w, p + "_shared", h, z.shared_width)
 
 
 def _queries_and_latents(add, z: _Sizes, p: str, wp: str, u: str,
@@ -213,7 +196,7 @@ def _absorbed_attention(add, z: _Sizes, p: str, wp: str, u: str, cache: str):
     return p + "_ctx", p + "_cache"
 
 
-def _block(nodes: List, w: _Weights, z: _Sizes, c: str, i: int, x: str,
+def _block(nodes: List, w: Weights, z: _Sizes, c: str, i: int, x: str,
            attend, dense: bool) -> str:
     """Block ``i`` in pass ``c`` (``p`` or ``d``) over ``x``; ``attend(p,
     wp, u)`` adds the pass's form of the attention and names its context."""
@@ -228,9 +211,9 @@ def _block(nodes: List, w: _Weights, z: _Sizes, c: str, i: int, x: str,
     add(node("RMSNormalization", [p + "_mid", wp + "_norm_post_w"],
              [p + "_u2"], name=p + "_norm_post", axis=-1, epsilon=z.eps))
     if dense:
-        ffn = _gated_ffn(add, p + "_ffn", wp + "_ffn", p + "_u2")
+        ffn = gated_ffn(add, p + "_ffn", wp + "_ffn", p + "_u2")
     else:
-        top_i, top_w = _router(nodes, w, p, p + "_u2", z.hidden, z.experts,
+        top_i, top_w = router(nodes, w, p, p + "_u2", z.hidden, z.experts,
                                z.top_k, z.routed_scaling, weights=wp)
         add(node("ExpertFFN",
                  [p + "_u2", top_i, top_w, wp + "_experts_up",
@@ -238,35 +221,12 @@ def _block(nodes: List, w: _Weights, z: _Sizes, c: str, i: int, x: str,
                  [p + "_routed"], name=p + "_moe_experts",
                  domain=EXPERT_DOMAIN, first_expert=z.first_expert,
                  num_experts=z.experts, activation="swiglu"))
-        shared = _gated_ffn(add, p + "_moe_shared", wp + "_shared", p + "_u2")
+        shared = gated_ffn(add, p + "_moe_shared", wp + "_shared", p + "_u2")
         add(node("Add", [p + "_routed", shared], [p + "_ffn_out"],
                  name=p + "_moe_sum_shared"))
         ffn = p + "_ffn_out"
     add(node("Add", [p + "_mid", ffn], [p + "_out"], name=p + "_res_ffn"))
     return p + "_out"
-
-
-def _choose(add, c: str, final: str):
-    """The head over ``final [N, 1, hidden]``, float32 logits, the greedy
-    choice: names the id ``[N, 1]`` and its log-softmax ``[N, 1]``."""
-    add(node("MatMul", [final, "lm_head"], [c + "_logits"], name=c + "_head"))
-    return _greedy(add, c)
-
-
-def _greedy(add, c: str):
-    """The greedy choice over the float32 widening of ``c_logits [N, 1,
-    vocab]``: names the id ``[N, 1]`` and its log-softmax ``[N, 1]``."""
-    add(node("Cast", [c + "_logits"], [c + "_logits_f"], name=c + "_logits_f",
-             to=_FLOAT))
-    add(node("ArgMax", [c + "_logits_f"], [c + "_id"], name=c + "_id",
-             axis=-1, keepdims=0))
-    add(node("ReduceMax", [c + "_logits_f", "axes_last"], [c + "_top"],
-             name=c + "_top", keepdims=0))
-    add(node("ReduceLogSumExp", [c + "_logits_f", "axes_last"], [c + "_lse"],
-             name=c + "_lse", keepdims=0))
-    add(node("Sub", [c + "_top", c + "_lse"], [c + "_logprob"],
-             name=c + "_logprob"))
-    return c + "_id", c + "_logprob"
 
 
 def _draft_input(add, z: _Sizes, c: str, next_ids: str, final: str) -> str:
@@ -311,12 +271,12 @@ def joyai_flash(layers: int = 9, hidden: int = 2048, vocab: int = 129280,
                first_expert=first_expert, experts_held=experts_held, eps=eps,
                scale=float((nope + rope) ** -0.5))
     n_blocks = layers + mtp  # the prediction module's block comes last
-    w = _Weights(seed)
+    w = Weights(seed)
     w.normal("tok_emb", (vocab, hidden), 1.0)
     for i in range(layers):
         _attention_weights(w, z, f"l{i}")
         if i == 0:
-            _gated_weights(w, "l0_ffn", hidden, dense_width)
+            gated_weights(w, "l0_ffn", hidden, dense_width)
         else:
             _expert_weights(w, z, f"l{i}")
     w.full("norm_f_w", (hidden,), 1.0)
@@ -409,26 +369,14 @@ def joyai_flash(layers: int = 9, hidden: int = 2048, vocab: int = 129280,
         add(node("Gather", [x, "last_1d"], ["p_last"], name="p_last", axis=1))
         add(node("RMSNormalization", ["p_last", "norm_f_w"], ["p_final"],
                  name="p_norm_f", axis=-1, epsilon=eps))
-    first_id, first_logprob = _choose(add, "p", "p_final")
-    state = ["last_id", "tokens", "chosen_logprob", "pooled_sum"]
-    kinds = [np.int64, np.int64, np.float32, np.float32]
-    add(_of_shape("row_zero", "n_1d", np.int64(0)))
-    add(_of_shape("tokens_zero", "n_generate_shape", np.int64(0)))
-    add(_of_shape("logprob_zero", "n_generate_shape", np.float32(0)))
-    add(node("TensorScatter", ["tokens_zero", first_id, "row_zero"],
-             ["tokens_start"], name="tokens_start", axis=1))
-    add(node("TensorScatter", ["logprob_zero", first_logprob, "row_zero"],
-             ["logprob_start"], name="logprob_start", axis=1))
-    add(node("Cast", ["p_final"], ["p_final_f"], name="p_final_f", to=_FLOAT))
-    add(node("Squeeze", ["p_final_f", "axes_1"], ["pooled_start"],
-             name="pooled_start"))
-    starts = {"last_id": first_id, "tokens": "tokens_start",
-              "chosen_logprob": "logprob_start", "pooled_sum": "pooled_start"}
+    state, kinds = list(STATE), list(KINDS)
+    starts = first_token(add, choose)
     if mtp:
         # position t's next id: the prompt shifted by one, then id 0
         add(node("Slice", ["input_ids", "index1", "huge_1d", "axes_1"],
                  ["p_ids_after"], name="p_ids_after"))
-        add(node("Concat", ["p_ids_after", first_id], ["p_next_ids"],
+        add(node("Concat", ["p_ids_after", starts[0]],  # id 0
+                 ["p_next_ids"],
                  name="p_next_ids", axis=1))
         x = _draft_input(add, z, "p", "p_next_ids", "p_final_all")
         x = _block(nodes, w, z, "p", layers, x, prompt_attend, dense=False)
@@ -436,7 +384,7 @@ def joyai_flash(layers: int = 9, hidden: int = 2048, vocab: int = 129280,
                  axis=1))
         add(node("RMSNormalization", ["p_mtp_last", "mtp_norm_s_w"],
                  ["p_mtp_final"], name="p_mtp_norm_s", axis=-1, epsilon=eps))
-        draft, draft_logprob = _choose(add, "p_mtp", "p_mtp_final")
+        draft, draft_logprob = choose(add, "p_mtp", "p_mtp_final")
         add(node("TensorScatter", ["tokens_zero", draft, "row_zero"],
                  ["draft_tokens_start"], name="draft_tokens_start", axis=1))
         add(node("TensorScatter", ["logprob_zero", draft_logprob,
@@ -444,8 +392,7 @@ def joyai_flash(layers: int = 9, hidden: int = 2048, vocab: int = 129280,
                  name="draft_logprob_start", axis=1))
         state += ["draft_tokens", "draft_logprob"]
         kinds += [np.int64, np.float32]
-        starts.update(draft_tokens="draft_tokens_start",
-                      draft_logprob="draft_logprob_start")
+        starts += ["draft_tokens_start", "draft_logprob_start"]
 
     # ---- the body of Loop "decode": one token a row, the absorbed form
     d_in = ["trip", "trip_cond"] + ["d_" + s for s in state] \
@@ -478,31 +425,14 @@ def joyai_flash(layers: int = 9, hidden: int = 2048, vocab: int = 129280,
     x = "d_tok"
     for i in range(layers):
         x = _block(d_nodes, w, z, "d", i, x, cached_attend(i), dense=i == 0)
-    d_add(node("RMSNormalization", [x, "norm_f_w"], ["d_final"],
-               name="d_norm_f", axis=-1, epsilon=eps))
-    new_id, new_logprob = _choose(d_add, "d", "d_final")
-    d_add(node("TensorScatter", ["d_tokens", new_id, "d_slot_1d"],
-               ["d_tokens_out"], name="d_tokens_out", axis=1))
-    d_add(node("TensorScatter", ["d_chosen_logprob", new_logprob,
-                                 "d_slot_1d"], ["d_chosen_logprob_out"],
-               name="d_chosen_logprob_out", axis=1))
-    d_add(node("Cast", ["d_final"], ["d_final_f"], name="d_final_f",
-               to=_FLOAT))
-    d_add(node("Squeeze", ["d_final_f", "axes_1"], ["d_final_row"],
-               name="d_final_row"))
-    d_add(node("Add", ["d_pooled_sum", "d_final_row"], ["d_pooled_sum_out"],
-               name="d_pooled_sum_out"))
-    d_add(node("Identity", ["trip_cond"], ["trip_cond_out"],
-               name="trip_cond_out"))
-    d_out = ["trip_cond_out", new_id, "d_tokens_out", "d_chosen_logprob_out",
-             "d_pooled_sum_out"]
+    d_out = decode_tail(d_add, choose, x, eps)
     if mtp:
-        x = _draft_input(d_add, z, "d", new_id, "d_final")
+        x = _draft_input(d_add, z, "d", d_out[1], "d_final")  # the new id
         x = _block(d_nodes, w, z, "d", layers, x, cached_attend(layers),
                    dense=False)
         d_add(node("RMSNormalization", [x, "mtp_norm_s_w"], ["d_mtp_final"],
                    name="d_mtp_norm_s", axis=-1, epsilon=eps))
-        draft, draft_logprob = _choose(d_add, "d_mtp", "d_mtp_final")
+        draft, draft_logprob = choose(d_add, "d_mtp", "d_mtp_final")
         d_add(node("TensorScatter", ["d_draft_tokens", draft, "d_slot_1d"],
                    ["d_draft_tokens_out"], name="d_draft_tokens_out", axis=1))
         d_add(node("TensorScatter", ["d_draft_logprob", draft_logprob,
@@ -511,24 +441,13 @@ def joyai_flash(layers: int = 9, hidden: int = 2048, vocab: int = 129280,
         d_out += ["d_draft_tokens_out", "d_draft_logprob_out"]
     body = make_graph(
         d_nodes, "decode_pass",
-        _infos(d_in, [np.int64, np.bool_] + kinds, n_blocks),
-        _infos(d_out + new_caches, [np.bool_] + kinds, n_blocks))
+        infos(d_in, [np.int64, np.bool_] + kinds, n_blocks),
+        infos(d_out + new_caches, [np.bool_] + kinds, n_blocks))
 
     # ---- the loop and the outputs
-    totals = [s + "_total" for s in state]
-    add(node("Loop", ["trips", ""] + [starts[s] for s in state] + caches,
-             totals + [f"final_cache{j}" for j in range(n_blocks)],
-             name="decode", body=body))
-    add(node("Identity", ["tokens_total"], ["tokens"], name="tokens"))
-    add(node("Identity", ["chosen_logprob_total"], ["chosen_logprob"],
-             name="chosen_logprob"))
-    add(node("Cast", ["generate_1d"], ["generate_f"], name="generate_f",
-             to=_FLOAT))
-    add(node("Div", ["pooled_sum_total", "generate_f"], ["pooled"],
-             name="pooled"))
-    outputs = [value_info("tokens", np.int64, ["N", generate]),
-               value_info("chosen_logprob", np.float32, ["N", generate]),
-               value_info("pooled", np.float32, ["N", hidden])]
+    outputs = decode_loop(
+        add, body, state, starts + caches,
+        [f"final_cache{j}" for j in range(n_blocks)], generate, hidden)
     if mtp:
         add(node("Identity", ["draft_tokens_total"], ["draft_tokens"],
                  name="draft_tokens"))

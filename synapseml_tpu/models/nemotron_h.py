@@ -33,104 +33,25 @@ rounded to BFLOAT16 initializers (the source is a bfloat16 checkpoint).
 
 from __future__ import annotations
 
-import os
-import threading
-from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Dict, List, Tuple
+from typing import List, Tuple
 
 import numpy as np
 
 from ..onnx.builder import make_graph, make_model, node, value_info
-from ..onnx.wire import DataType, ModelProto
+from ..onnx.wire import ModelProto
+from .decoder import EXPERT_DOMAIN, Weights, cast_float, router
 
-__all__ = ["nemotron_h", "EXPERT_DOMAIN"]
-
-EXPERT_DOMAIN = "synapseml_tpu"
-_FLOAT = DataType.FLOAT
+__all__ = ["nemotron_h"]
 
 
-def _round_to_bfloat16(a: np.ndarray, out: np.ndarray) -> None:
-    """float32 ``a`` (overwritten) -> the bits of the nearest bfloat16 into
-    ``out`` (ties away from zero), in two passes of plain integer arithmetic
-    (numpy releases the GIL for it; ``ml_dtypes``' cast does not promise to)."""
-    bits = a.reshape(-1).view(np.uint32)
-    bits += 0x8000
-    out.reshape(-1)[...] = bits.view(np.uint16)[1::2]  # the high halves
-
-
-# numbers a job draws at most: a thread's float32 scratch is this long and is
-# used again and again (fresh pages cost more than the draws on some hosts)
-_JOB_SIZE = 1 << 22
-
-
-class _Weights:
-    """Initializers by name. A tensor is drawn in slices of rows, each by its
-    own generator seeded by (``seed``, the tensor's ordinal, the slice's), so
-    a thread pool fills them in any order to the same bits."""
-
-    def __init__(self, seed: int):
-        import ml_dtypes
-
-        self.seed = seed
-        self.bfloat16 = np.dtype(ml_dtypes.bfloat16)
-        self.store: Dict[str, np.ndarray] = {}
-        self._jobs: List[Tuple[np.ndarray, Tuple[int, ...], Callable]] = []
-
-    def ints(self, name: str, values) -> str:
-        self.store[name] = np.asarray(values, dtype=np.int64)
-        return name
-
-    def draw(self, name: str, shape: Tuple[int, ...], fill: Callable) -> str:
-        """``fill(rng, scratch)`` writes float32 numbers into ``scratch``
-        (flat, as long as the slice it fills)."""
-        bits = np.empty(shape, np.uint16)
-        ordinal = len(self.store)
-        self.store[name] = bits.view(self.bfloat16)
-        flat = bits.reshape(-1)
-        self._jobs += [(flat[lo:lo + _JOB_SIZE], (self.seed, ordinal, i), fill)
-                       for i, lo in enumerate(range(0, flat.size, _JOB_SIZE))]
-        return name
-
-    def normal(self, name: str, shape: Tuple[int, ...], std: float) -> str:
-        def fill(rng, scratch):
-            rng.standard_normal(out=scratch, dtype=np.float32)
-            scratch *= np.float32(std)
-
-        return self.draw(name, shape, fill)
-
-    def full(self, name: str, shape: Tuple[int, ...], value: float) -> str:
-        return self.draw(name, shape,
-                         lambda rng, scratch: scratch.fill(value))
-
-    def fill_all(self) -> None:
-        local = threading.local()
-
-        def run(job):
-            bits, key, fill = job
-            if not hasattr(local, "scratch"):
-                local.scratch = np.empty(_JOB_SIZE, np.float32)
-            scratch = local.scratch[:bits.size]
-            fill(np.random.default_rng(key), scratch)
-            _round_to_bfloat16(scratch, bits)
-
-        with ThreadPoolExecutor(max_workers=os.cpu_count() or 1) as pool:
-            list(pool.map(run, self._jobs))
-        self._jobs = []
-
-
-def _cast(nodes, src: str, name: str) -> str:
-    nodes.append(node("Cast", [src], [name], name=name, to=_FLOAT))
-    return name
-
-
-def _rms_norm(nodes, w: _Weights, name: str, x: str, size: int, eps: float):
+def _rms_norm(nodes, w: Weights, name: str, x: str, size: int, eps: float):
     weight = w.full(name + "_w", (size,), 1.0)
     nodes.append(node("RMSNormalization", [x, weight], [name], name=name,
                       axis=-1, epsilon=eps))
     return name
 
 
-def _mamba(nodes, w: _Weights, p: str, u: str, hidden: int, heads: int,
+def _mamba(nodes, w: Weights, p: str, u: str, hidden: int, heads: int,
            head_dim: int, groups: int, state: int, conv_kernel: int,
            chunk: int, eps: float, dt_limits: Tuple[float, float, float]):
     """Mamba-2 mixer; ``p`` prefixes every name, ``u`` is the normed input
@@ -179,11 +100,11 @@ def _mamba(nodes, w: _Weights, p: str, u: str, hidden: int, heads: int,
     w.draw(p + "_dt_bias", (heads,), dt_bias)
     w.draw(p + "_a_log", (heads,), a_log)
     w.full(p + "_d", (heads,), 1.0)
-    add(node("Add", [_cast(nodes, p + "_dt_raw", p + "_dt_f"),
-                     _cast(nodes, p + "_dt_bias", p + "_dt_bias_f")],
+    add(node("Add", [cast_float(nodes, p + "_dt_raw", p + "_dt_f"),
+                     cast_float(nodes, p + "_dt_bias", p + "_dt_bias_f")],
              [p + "_dt_b"], name=p + "_dt_add"))
     add(node("Softplus", [p + "_dt_b"], [p + "_dt"], name=p + "_dt"))
-    add(node("Exp", [_cast(nodes, p + "_a_log", p + "_a_log_f")],
+    add(node("Exp", [cast_float(nodes, p + "_a_log", p + "_a_log_f")],
              [p + "_a_exp"], name=p + "_a_exp"))
     add(node("Neg", [p + "_a_exp"], [p + "_a"], name=p + "_a"))
     add(node("Mul", [p + "_dt", p + "_a"], [p + "_da"], name=p + "_da"))
@@ -192,11 +113,11 @@ def _mamba(nodes, w: _Weights, p: str, u: str, hidden: int, heads: int,
     x_shape = w.ints(p + "_x_shape", [0, -1, chunk, groups, per, head_dim])
     h_shape = w.ints(p + "_h_shape", [0, -1, chunk, groups, per])
     bc_shape = w.ints(p + "_bc_shape", [0, -1, chunk, groups, state])
-    add(node("Reshape", [_cast(nodes, p + "_x", p + "_x_f"), x_shape],
+    add(node("Reshape", [cast_float(nodes, p + "_x", p + "_x_f"), x_shape],
              [p + "_xc"], name=p + "_x_chunks"))
-    add(node("Reshape", [_cast(nodes, p + "_b", p + "_b_f"), bc_shape],
+    add(node("Reshape", [cast_float(nodes, p + "_b", p + "_b_f"), bc_shape],
              [p + "_bchunks"], name=p + "_b_chunks"))
-    add(node("Reshape", [_cast(nodes, p + "_c", p + "_c_f"), bc_shape],
+    add(node("Reshape", [cast_float(nodes, p + "_c", p + "_c_f"), bc_shape],
              [p + "_cchunks"], name=p + "_c_chunks"))
     add(node("Reshape", [p + "_dt", h_shape], [p + "_dtc"],
              name=p + "_dt_chunks"))
@@ -269,7 +190,7 @@ def _mamba(nodes, w: _Weights, p: str, u: str, hidden: int, heads: int,
     add(node("Add", [p + "_y_diag", p + "_y_off"], [p + "_y_ssd"],
              name=p + "_ssd_sum"))
     d_shape = w.ints(p + "_d_shape", [groups, per, 1])
-    add(node("Reshape", [_cast(nodes, p + "_d", p + "_d_f"), d_shape],
+    add(node("Reshape", [cast_float(nodes, p + "_d", p + "_d_f"), d_shape],
              [p + "_d_r"], name=p + "_d_r"))
     add(node("Mul", [p + "_xc", p + "_d_r"], [p + "_skip"], name=p + "_skip"))
     add(node("Add", [p + "_y_ssd", p + "_skip"], [p + "_yc"], name=p + "_y"))
@@ -301,14 +222,14 @@ def _mamba(nodes, w: _Weights, p: str, u: str, hidden: int, heads: int,
     return p + "_mix"
 
 
-def _unsqueeze(nodes, w: _Weights, src: str, axis: int) -> str:
+def _unsqueeze(nodes, w: Weights, src: str, axis: int) -> str:
     name = f"{src}_u{axis % 10}"
     axes = w.ints(f"axes_{axis % 10}", [axis])
     nodes.append(node("Unsqueeze", [src, axes], [name], name=name))
     return name
 
 
-def _attention(nodes, w: _Weights, p: str, u: str, hidden: int, heads: int,
+def _attention(nodes, w: Weights, p: str, u: str, hidden: int, heads: int,
                kv_heads: int, head_dim: int):
     std = hidden ** -0.5
     for proj, n in (("q", heads), ("k", kv_heads), ("v", kv_heads)):
@@ -326,7 +247,7 @@ def _attention(nodes, w: _Weights, p: str, u: str, hidden: int, heads: int,
     return p + "_mix"
 
 
-def _relu2_ffn(nodes, w: _Weights, p: str, u: str, hidden: int, width: int):
+def _relu2_ffn(nodes, w: Weights, p: str, u: str, hidden: int, width: int):
     nodes.append(node("MatMul", [u, w.normal(p + "_up_w", (hidden, width),
                                              hidden ** -0.5)],
                       [p + "_up"], name=p + "_up"))
@@ -340,49 +261,11 @@ def _relu2_ffn(nodes, w: _Weights, p: str, u: str, hidden: int, width: int):
     return p + "_down"
 
 
-def _router(nodes, w: _Weights, p: str, u: str, hidden: int, experts: int,
-            top_k: int, scaling: float, weights: str = None):
-    """The sigmoid router of this family and of ``joyai_flash``'s: scores
-    ``sigmoid(u W_r)`` in float32 over every expert, as wide as published
-    whatever is held here; the ``top_k`` largest of scores + bias are chosen,
-    the chosen SCORES renormalised and scaled. Names the picks and their
-    weights. ``weights`` prefixes the router's two tensors where they are
-    not the nodes' own ``p`` (a graph that runs one layer in several
-    passes); they are drawn on first use."""
-    add, wp = nodes.append, weights or p
-    if wp + "_router_w" not in w.store:
-        w.normal(wp + "_router_w", (hidden, experts), hidden ** -0.5)
-        w.normal(wp + "_router_bias", (experts,), 0.01)
-    add(node("MatMul", [_cast(nodes, u, p + "_u_f"),
-                        _cast(nodes, wp + "_router_w", p + "_router_w_f")],
-             [p + "_router"], name=p + "_moe_route"))
-    add(node("Sigmoid", [p + "_router"], [p + "_scores"],
-             name=p + "_moe_scores"))
-    add(node("Add", [p + "_scores",
-                     _cast(nodes, wp + "_router_bias", p + "_router_bias_f")],
-             [p + "_choice"], name=p + "_moe_choice"))
-    add(node("TopK", [p + "_choice", w.ints("top_k", [top_k])],
-             [p + "_top_v", p + "_top_i"], name=p + "_moe_topk", axis=-1))
-    add(node("GatherElements", [p + "_scores", p + "_top_i"], [p + "_top_s"],
-             name=p + "_moe_pick", axis=-1))
-    add(node("ReduceSum", [p + "_top_s", w.ints("axes_9", [-1])],
-             [p + "_top_sum"], name=p + "_moe_sum", keepdims=1))
-    w.store["tiny"] = np.asarray(1e-20, np.float32)
-    w.store["routed_scaling"] = np.asarray(scaling, np.float32)
-    add(node("Add", [p + "_top_sum", "tiny"], [p + "_top_den"],
-             name=p + "_moe_den"))
-    add(node("Div", [p + "_top_s", p + "_top_den"], [p + "_top_n"],
-             name=p + "_moe_norm"))
-    add(node("Mul", [p + "_top_n", "routed_scaling"], [p + "_top_w"],
-             name=p + "_moe_weight"))
-    return p + "_top_i", p + "_top_w"
-
-
-def _experts(nodes, w: _Weights, p: str, u: str, hidden: int, experts: int,
+def _experts(nodes, w: Weights, p: str, u: str, hidden: int, experts: int,
              top_k: int, width: int, shared_width: int, scaling: float,
              first_expert: int, experts_held: int):
     add = nodes.append
-    top_i, top_w = _router(nodes, w, p, u, hidden, experts, top_k, scaling)
+    top_i, top_w = router(nodes, w, p, u, hidden, experts, top_k, scaling)
     std_up, std_down = hidden ** -0.5, width ** -0.5
     add(node("ExpertFFN",
              [u, top_i, top_w,
@@ -419,7 +302,7 @@ def nemotron_h(pattern: str = "MEMEM*EME", hidden: int = 2688,
     unknown = set(pattern) - set("ME*")
     if unknown or not pattern:
         raise ValueError(f"pattern {pattern!r}: letters M, E and * only")
-    w = _Weights(seed)
+    w = Weights(seed)
     nodes: List = []
     nodes.append(node("Gather", [w.normal("tok_emb", (vocab, hidden), 1.0),
                                  "input_ids"], ["tok"], name="tok", axis=0))

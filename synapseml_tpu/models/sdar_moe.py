@@ -55,8 +55,8 @@ import numpy as np
 
 from ..onnx.builder import constant_node, make_graph, make_model, node, \
     value_info
-from ..onnx.wire import DataType, ModelProto, numpy_to_tensor
-from .nemotron_h import EXPERT_DOMAIN, _Weights
+from ..onnx.wire import DataType, ModelProto
+from .decoder import EXPERT_DOMAIN, Weights, infos, of_shape
 
 __all__ = ["sdar_moe", "MASK_ID"]
 
@@ -157,20 +157,6 @@ def _cached_pass(z: _Sizes, c: str, ids: str, caches: List[str]):
     return nodes, final, new
 
 
-def _infos(names: List[str], leading, n_caches: int) -> List:
-    """Value infos of a body's inputs or outputs: the ``leading`` types,
-    then the caches in the checkpoint's type."""
-    import ml_dtypes
-
-    types = list(leading) + [ml_dtypes.bfloat16] * n_caches
-    return [value_info(n, t) for n, t in zip(names, types)]
-
-
-def _of_shape(name: str, shape: str, value) -> object:
-    return node("ConstantOfShape", [shape], [name], name=name,
-                value=numpy_to_tensor(name + "_value", np.asarray([value])))
-
-
 def sdar_moe(layers: int = 6, hidden: int = 2048, vocab: int = 151936,
              heads: int = 32, kv_heads: int = 4, head_dim: int = 128,
              experts: int = 128, top_k: int = 8, expert_width: int = 768,
@@ -189,7 +175,7 @@ def sdar_moe(layers: int = 6, hidden: int = 2048, vocab: int = 151936,
         raise ValueError(f"mask_id {mask_id} is not among {vocab} ids")
     z = _Sizes(layers=layers, heads=heads, kv_heads=kv_heads, eps=eps,
                experts=experts)
-    w = _Weights(seed)
+    w = Weights(seed)
     w.normal("tok_emb", (vocab, hidden), 1.0)
     for i in range(layers):
         p, std = f"l{i}", hidden ** -0.5
@@ -338,8 +324,8 @@ def sdar_moe(layers: int = 6, hidden: int = 2048, vocab: int = 151936,
     kinds = [np.int64, np.bool_, np.int32, np.float32]  # of ``state``
     passes_body = make_graph(
         b_nodes, "denoising_pass",
-        _infos(b_in, [np.int64, np.bool_] + kinds, n_caches),
-        _infos(b_out, [np.bool_] + kinds, n_caches))
+        infos(b_in, [np.int64, np.bool_] + kinds, n_caches),
+        infos(b_out, [np.bool_] + kinds, n_caches))
 
     # ---- the body of Loop "blocks": a block's passes, then its commit pass
     o_state = ["tokens", "unmask_pass", "chosen_logprob", "pooled_sum"]
@@ -368,10 +354,10 @@ def sdar_moe(layers: int = 6, hidden: int = 2048, vocab: int = 151936,
                name="visible_1d"))
     o_add(node("Unsqueeze", ["visible_1d", "axes_0"], ["block_visible"],
                name="block_visible"))
-    o_add(_of_shape("ids_start", "n_block_shape", np.int64(mask_id)))
-    o_add(_of_shape("masked_start", "n_block_shape", np.bool_(True)))
-    o_add(_of_shape("fixed_at_start", "n_block_shape", np.int32(0)))
-    o_add(_of_shape("logprob_start", "n_block_shape", np.float32(0)))
+    o_add(of_shape("ids_start", "n_block_shape", np.int64(mask_id)))
+    o_add(of_shape("masked_start", "n_block_shape", np.bool_(True)))
+    o_add(of_shape("fixed_at_start", "n_block_shape", np.int32(0)))
+    o_add(of_shape("logprob_start", "n_block_shape", np.float32(0)))
     passes_out = ["block_ids", "block_masked", "block_fixed_at",
                   "block_logprob"] + [f"d_cache{j}" for j in range(n_caches)]
     o_add(node("Loop", ["passes", "", "ids_start", "masked_start",
@@ -397,18 +383,18 @@ def sdar_moe(layers: int = 6, hidden: int = 2048, vocab: int = 151936,
              "chosen_logprob_out", "pooled_sum_out"] + c_caches
     kinds = [np.int64, np.int32, np.float32, np.float32]  # of ``o_state``
     blocks_body = make_graph(
-        o_nodes, "block", _infos(o_in, [np.int64, np.bool_] + kinds, n_caches),
-        _infos(o_out, [np.bool_] + kinds, n_caches))
+        o_nodes, "block", infos(o_in, [np.int64, np.bool_] + kinds, n_caches),
+        infos(o_out, [np.bool_] + kinds, n_caches))
 
     # ---- the loop over blocks and the outputs
     add(node("Range", ["zero", "vocab", "one"], ["vocab_range"],
              name="vocab_range"))
     add(node("Equal", ["vocab_range", "mask_id"], ["is_mask_id"],
              name="is_mask_id"))
-    add(_of_shape("tokens_start", "n_generate_shape", np.int64(0)))
-    add(_of_shape("unmask_pass_start", "n_generate_shape", np.int32(0)))
-    add(_of_shape("chosen_logprob_start", "n_generate_shape", np.float32(0)))
-    add(_of_shape("pooled_start", "n_hidden_shape", np.float32(0)))
+    add(of_shape("tokens_start", "n_generate_shape", np.int64(0)))
+    add(of_shape("unmask_pass_start", "n_generate_shape", np.int32(0)))
+    add(of_shape("chosen_logprob_start", "n_generate_shape", np.float32(0)))
+    add(of_shape("pooled_start", "n_hidden_shape", np.float32(0)))
     add(node("Loop", ["blocks", "", "tokens_start", "unmask_pass_start",
                       "chosen_logprob_start", "pooled_start"] + caches,
              ["tokens", "unmask_pass", "chosen_logprob", "pooled_total"]

@@ -8,23 +8,12 @@ models — sufficient for throughput benchmarking, integration tests, and archit
 validation (weights are obviously not the pretrained ones; load real weights via
 ``weights`` overrides when available).
 
-Models: ResNet-18/50 (v1.5 bottleneck), a BERT-base-style encoder, ViT-B/16, and one
-chip's share of the hybrid Mamba-2 / sparse-expert / grouped-query decoder
-``nemotron_h`` (``models/nemotron_h.py``: weights as BFLOAT16 initializers), and a
-pipeline stage of the block-diffusion sparse-expert decoder ``sdar_moe``, generation
-included (``models/sdar_moe.py``: a ``Loop`` over blocks carrying a key-value cache),
-and one chip's share of the latent-attention sparse-expert decoder ``joyai_llm_flash``,
-generating greedily (``models/joyai_flash.py``: a prompt pass in the expanded form, then a
-``Loop`` of one token a row against a cache of latents in the absorbed form),
-and the hybrid Mamba-1 / attention decoder ``jamba``, whole and generating greedily
-(``models/jamba.py``: the selective scan as one ``synapseml_tpu::SelectiveScan`` node,
-a prompt pass, then a ``Loop`` that carries each Mamba layer's state and convolution
-rows beside the attention layers' key-value caches),
-and the hybrid Gated DeltaNet / attention decoder ``olmo_hybrid``, generating greedily
-(``models/olmo_hybrid.py``: the gated delta rule as one ``synapseml_tpu::GatedDeltaRule``
-node, a prompt pass, then a ``Loop`` that carries each delta rule layer's matrix state and
-convolution rows beside the full attention layers' key-value caches).
-All emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
+Models: ResNet-18/50/101 (v1.5 bottleneck), a BERT-base-style encoder, ViT-B/16,
+and five decoders with seeded BFLOAT16 weights, a module each: ``nemotron_h`` (one
+pass), and ``sdar_moe``, ``joyai_flash``, ``jamba`` and ``olmo_hybrid``, which generate
+inside the graph (an ONNX ``Loop`` carrying caches and states); ``models/decoder.py``
+holds what they share, ``jamba`` and ``olmo_hybrid`` whole but for their layers' parts.
+The encoders emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
 can "cut" the head exactly like the reference's ``cutOutputLayers``
 (``ImageFeaturizer.scala:40-197``).
 """
@@ -294,25 +283,6 @@ def vit(patch: int = 16, image_size: int = 224, layers: int = 12, hidden: int = 
     return make_model(g, opset=20)
 
 
-MODEL_BUILDERS = {
-    "ResNet18": lambda **kw: resnet(18, **kw),
-    "ResNet50": lambda **kw: resnet(50, **kw),
-    "ResNet101": lambda **kw: resnet(101, **kw),
-    "BERTBase": lambda **kw: bert_encoder(**kw),
-    "BERTTiny": lambda **kw: bert_encoder(layers=2, hidden=128, heads=2, vocab=1000, **kw),
-    "ViTB16": lambda **kw: vit(**kw),
-    "NemotronH": lambda **kw: _nemotron_h(**kw),
-    "NemotronHTiny": lambda **kw: _nemotron_h(**{**NEMOTRON_H_TINY, **kw}),
-    "SDARMoE": lambda **kw: _sdar_moe(**kw),
-    "SDARMoETiny": lambda **kw: _sdar_moe(**{**SDAR_MOE_TINY, **kw}),
-    "JoyAIFlash": lambda **kw: _joyai_flash(**kw),
-    "JoyAIFlashTiny": lambda **kw: _joyai_flash(**{**JOYAI_FLASH_TINY, **kw}),
-    "Jamba": lambda **kw: _jamba(**kw),
-    "JambaTiny": lambda **kw: _jamba(**{**JAMBA_TINY, **kw}),
-    "OlmoHybrid": lambda **kw: _olmo_hybrid(**kw),
-    "OlmoHybridTiny": lambda **kw: _olmo_hybrid(**{**OLMO_HYBRID_TINY, **kw}),
-}
-
 # widths of the CPU tests' nemotron_h: every mechanism of the full graph
 # (two key-value groups, a router wider than its top-k, several chunks)
 NEMOTRON_H_TINY = dict(
@@ -321,24 +291,12 @@ NEMOTRON_H_TINY = dict(
     top_k=2, expert_width=32, shared_width=64, experts_held=8)
 
 
-def _nemotron_h(**kw) -> ModelProto:
-    from .nemotron_h import nemotron_h
-
-    return nemotron_h(**kw)
-
-
 # widths of the CPU tests' sdar_moe: every mechanism of the full graph (two
 # key-value groups, a router wider than its top-k, two blocks, two passes)
 SDAR_MOE_TINY = dict(
     layers=2, hidden=64, vocab=256, heads=4, kv_heads=2, head_dim=16,
     experts=8, top_k=2, expert_width=32, generate=8, block=4, passes=2,
     mask_id=255)
-
-
-def _sdar_moe(**kw) -> ModelProto:
-    from .sdar_moe import sdar_moe
-
-    return sdar_moe(**kw)
 
 
 # widths of the CPU tests' joyai_flash: every mechanism of the full graph
@@ -350,12 +308,6 @@ JOYAI_FLASH_TINY = dict(
     expert_width=32, shared_width=32, experts_held=8, generate=8)
 
 
-def _joyai_flash(**kw) -> ModelProto:
-    from .joyai_flash import joyai_flash
-
-    return joyai_flash(**kw)
-
-
 # widths of the CPU tests' jamba: every mechanism of the full graph (a Mamba
 # layer before and after the attention layer, four query heads on one
 # key-value head, a step's rank under the state's width)
@@ -363,12 +315,6 @@ JAMBA_TINY = dict(
     layers=4, hidden=64, vocab=512, heads=4, kv_heads=1, head_dim=16,
     attn_period=4, attn_offset=2, expand=2, state=16, dt_rank=8, width=96,
     generate=8)
-
-
-def _jamba(**kw) -> ModelProto:
-    from .jamba import jamba
-
-    return jamba(**kw)
 
 
 # widths of the CPU tests' olmo_hybrid: every mechanism of the full graph (a
@@ -380,10 +326,36 @@ OLMO_HYBRID_TINY = dict(
     generate=8)
 
 
-def _olmo_hybrid(**kw) -> ModelProto:
-    from .olmo_hybrid import olmo_hybrid
+def _decoder(module: str, preset: Optional[dict] = None):
+    """The builder ``models/<module>.py`` names after itself, imported at
+    its first use, with ``preset`` under the caller's arguments."""
+    def build(**kw) -> ModelProto:
+        import importlib
 
-    return olmo_hybrid(**kw)
+        builder = getattr(importlib.import_module("." + module, __package__),
+                          module)
+        return builder(**{**(preset or {}), **kw})
+    return build
+
+
+MODEL_BUILDERS = {
+    "ResNet18": lambda **kw: resnet(18, **kw),
+    "ResNet50": lambda **kw: resnet(50, **kw),
+    "ResNet101": lambda **kw: resnet(101, **kw),
+    "BERTBase": lambda **kw: bert_encoder(**kw),
+    "BERTTiny": lambda **kw: bert_encoder(layers=2, hidden=128, heads=2, vocab=1000, **kw),
+    "ViTB16": lambda **kw: vit(**kw),
+    "NemotronH": _decoder("nemotron_h"),
+    "NemotronHTiny": _decoder("nemotron_h", NEMOTRON_H_TINY),
+    "SDARMoE": _decoder("sdar_moe"),
+    "SDARMoETiny": _decoder("sdar_moe", SDAR_MOE_TINY),
+    "JoyAIFlash": _decoder("joyai_flash"),
+    "JoyAIFlashTiny": _decoder("joyai_flash", JOYAI_FLASH_TINY),
+    "Jamba": _decoder("jamba"),
+    "JambaTiny": _decoder("jamba", JAMBA_TINY),
+    "OlmoHybrid": _decoder("olmo_hybrid"),
+    "OlmoHybridTiny": _decoder("olmo_hybrid", OLMO_HYBRID_TINY),
+}
 
 
 def build_model_bytes(name: str, **kw) -> bytes:

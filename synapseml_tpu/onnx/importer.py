@@ -40,7 +40,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .ops import OPS
+from .ops import NOTE_FAMILIES, OPS
 from .wire import (DataType, GraphProto, ModelProto, ValueInfo, parse_model,
                    tensor_to_numpy)
 
@@ -548,7 +548,7 @@ class OnnxFunction:
         # summed while the graph is traced (so once a compiled program)
         handoff = [0] if self.dtype_policy == "bfloat16" else None
         # what ops say of the program being traced (ops._note)
-        notes: Dict[str, int] = {}
+        notes: Dict[str, Any] = {}
         self._run_graph(self.graph, env, handoff=handoff, notes=notes)
         from ..observability.metrics import get_registry
 
@@ -567,156 +567,25 @@ class OnnxFunction:
             outs.append(jnp.asarray(v))
         return tuple(outs)
 
-    def _record_notes(self, notes: Dict[str, int]) -> None:
-        """Once a traced program: how its ``Attention`` and ``Gelu`` nodes
-        were lowered, the widths its ``Attention`` nodes saw, where the
-        flash kernel reads their operands, what its ``ExpertFFN`` nodes are
-        sized for, their form, their row tile and chunk and how they
-        combine, how often its ``Loop`` bodies run and what they carry, how
-        its ``SelectiveScan`` and ``GatedDeltaRule`` nodes were lowered and
-        the state their single steps take in."""
+    def _record_notes(self, notes: Dict[str, Any]) -> None:
+        """Once a traced program: what its ops noted of how they were
+        lowered and what they hold, into the metric of each of
+        ``ops.NOTE_FAMILIES``."""
         from ..observability.metrics import get_registry
 
         reg, fn = get_registry(), self._jit.name
-        lowering = reg.counter(
-            "smt_onnx_attention_lowering_total",
-            "Attention nodes of a traced program by lowering: flash (the "
-            "Pallas kernel, scores never written), dense (materialised "
-            "scores where the kernel could have served: not a TPU, or "
-            "lengths that do not tile), cached (a mask over key positions "
-            "that only the run knows, one head size of a multiple of 128: "
-            "the Pallas kernel for a few queries against a cache, which "
-            "reads it once as it lies and keeps the scores in VMEM) or "
-            "masked (any other mask only the run knows, or no TPU: the "
-            "grouped dense form is the lowering)",
-            ("fn", "kind"))
-        for kind in ("flash", "dense", "cached", "masked"):
-            if "attention_" + kind in notes:
-                lowering.labels(fn, kind).inc(notes["attention_" + kind])
-        gelu = reg.counter(
-            "smt_onnx_gelu_lowering_total",
-            "exact Gelu nodes of a traced program by form: erf_float32 (a "
-            "bfloat16 input: one-branch erf on the float32 upcast, rounded "
-            "once) or erfc (any wider input: jax.nn.gelu's two-branch form)",
-            ("fn", "form"))
-        for form in ("erf_float32", "erfc"):
-            if "gelu_" + form in notes:
-                gelu.labels(fn, form).inc(notes["gelu_" + form])
-        combine = reg.counter(
-            "smt_onnx_expert_combine_total",
-            "ExpertFFN nodes of a traced program by how their product rows "
-            "reach their tokens: held_first (a token's held picks first, "
-            "the first few gathered for every token, the few beyond those "
-            "added row by row)",
-            ("fn", "form"))
-        form = reg.counter(
-            "smt_onnx_expert_form_total",
-            "ExpertFFN nodes of a traced program by activation: relu2 (one "
-            "up-projection) or swiglu (a gate and an up-projection)",
-            ("fn", "form"))
-        tile = reg.counter(
-            "smt_onnx_expert_tile_total",
-            "ExpertFFN nodes of a traced program by the row tile of their "
-            "grouped products, which follows the pairs an expert is expected "
-            "to get (512 at 512 pairs an expert or more)",
-            ("fn", "rows"))
-        trips = reg.gauge(
-            "smt_onnx_loop_trips",
-            "times a call of the newest traced program runs the body of "
-            "each Loop node, outer loops multiplied in",
-            ("fn", "loop"), merge="max")
-        widths = reg.counter(
-            "smt_onnx_attention_widths_total",
-            "Attention nodes of a traced program by the width of a head's "
-            "queries and keys, of its values, and the key-value heads: which "
-            "form of attention ran (latent attention expanded has values "
-            "narrower than its keys; absorbed, one key-value head of latents)",
-            ("fn", "qk", "v", "kv_heads"))
-        flash_form = reg.counter(
-            "smt_onnx_attention_flash_form_total",
-            "Attention nodes of a traced program that run the flash kernel, "
-            "by where it reads its operands: in_place ([batch, seq, heads x "
-            "size] as the node got them, a head picked by the block index "
-            "map) or heads_first (copies transposed to [batch x heads, seq, "
-            "size] in HBM, and the result back: value heads that are no "
-            "whole 128-lane blocks)",
-            ("fn", "form"))
-        scan = reg.counter(
-            "smt_onnx_selective_scan_lowering_total",
-            "SelectiveScan nodes of a traced program by lowering: kernel "
-            "(the Pallas kernel: the state stays in VMEM across positions), "
-            "step (one position, a generating loop's body: plain jax.numpy "
-            "over the state) or scan (lax.scan over positions, the state "
-            "crossing HBM every position: not a TPU, or shapes that do not "
-            "tile)",
-            ("fn", "form"))
-        delta = reg.counter(
-            "smt_onnx_gated_delta_lowering_total",
-            "GatedDeltaRule nodes of a traced program by lowering: "
-            "chunked_kernel (more than one position: the WY form as a "
-            "Pallas kernel, a row's state and a chunk's products in VMEM), "
-            "chunked (more than one position: the WY form as XLA's matrix "
-            "products over chunks: not a TPU, or shapes the kernel does not "
-            "take), kernel (one position: the Pallas kernel reads and "
-            "writes the state once, in place) or step (one position, plain "
-            "jax.numpy: not a TPU, or shapes the kernel does not take)",
-            ("fn", "form"))
-        for key, count in notes.items():
-            if key.startswith("selective_scan_"):
-                scan.labels(fn, key[len("selective_scan_"):]).inc(count)
-            elif key.startswith("gated_delta_"):
-                delta.labels(fn, key[len("gated_delta_"):]).inc(count)
-            elif key.startswith("attention_widths."):
-                widths.labels(fn, *key.split(".")[1:]).inc(count)
-            elif key.startswith("attention_flash_form."):
-                flash_form.labels(fn, key.split(".")[1]).inc(count)
-            elif key.startswith("expert_combine_"):
-                combine.labels(fn, key[len("expert_combine_"):]).inc(count)
-            elif key.startswith("expert_form_"):
-                form.labels(fn, key[len("expert_form_"):]).inc(count)
-            elif key.startswith("expert_tile_"):
-                tile.labels(fn, key[len("expert_tile_"):]).inc(count)
-            elif key.startswith("loop_trips."):
-                trips.labels(fn, key[len("loop_trips."):]).set(count)
-        if "loop_state_bytes" in notes:
-            reg.gauge(
-                "smt_onnx_loop_state_bytes",
-                "bytes the outermost Loop nodes of the newest traced "
-                "program carry from trip to trip (a key-value cache)",
-                ("fn",), merge="max").labels(fn).set(
-                    notes["loop_state_bytes"])
-        if "recurrent_state_bytes" in notes:
-            reg.gauge(
-                "smt_onnx_recurrent_state_bytes",
-                "bytes of state the single-position SelectiveScan and "
-                "GatedDeltaRule nodes of the newest traced program take in "
-                "(and hand on as many): what a generating pass streams "
-                "beside the weights",
-                ("fn",), merge="max").labels(fn).set(
-                    notes["recurrent_state_bytes"])
-        if "expert_pairs" in notes:
-            reg.gauge(
-                "smt_onnx_expert_pairs",
-                "(token, pick) pairs one call of the newest traced program "
-                "presents to its ExpertFFN nodes: what their grouped "
-                "products are sized for",
-                ("fn",), merge="max").labels(fn).set(notes["expert_pairs"])
-            reg.gauge(
-                "smt_onnx_experts_held",
-                "experts held by the ExpertFFN nodes of the newest traced "
-                "program, summed over nodes",
-                ("fn",), merge="max").labels(fn).set(notes["experts_held"])
-            reg.gauge(
-                "smt_onnx_expert_chunk_rows",
-                "sorted (token, pick) pairs the ExpertFFN nodes of the newest "
-                "traced program gather and multiply at a time: the smallest "
-                "chunk of any node",
-                ("fn",), merge="max").labels(fn).set(
-                    notes["expert_chunk_rows"])
+        for family, declare in NOTE_FAMILIES.items():
+            metric = declare(reg)
+            for labels, amount in notes.get(family, {}).items():
+                series = metric.labels(fn, *labels)
+                if metric.type == "gauge":
+                    series.set(amount)
+                else:
+                    series.inc(amount)
 
     def _run_function(self, fdef, call, env: Dict[str, Any],
                       handoff: "List[int] | None" = None,
-                      notes: "Dict[str, int] | None" = None) -> None:
+                      notes: "Dict[str, Any] | None" = None) -> None:
         """Inline-expand a model-local function call: bind formal inputs,
         substitute ``ref_attr_name`` attributes from the call site (falling
         back to ``attribute_proto`` defaults, recursing into subgraph
@@ -776,7 +645,7 @@ class OnnxFunction:
     def _run_graph(self, graph: GraphProto, env: Dict[str, Any],
                    opset: "int | None" = None,
                    handoff: "List[int] | None" = None,
-                   notes: "Dict[str, int] | None" = None) -> None:
+                   notes: "Dict[str, Any] | None" = None) -> None:
         import jax
         import jax.numpy as jnp
 

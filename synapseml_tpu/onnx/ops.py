@@ -24,6 +24,7 @@ rounded to it, Softmax/LogSoftmax/LayerNormalization reduce in float32 inside.
 from __future__ import annotations
 
 import functools
+import operator
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -253,9 +254,9 @@ def _gelu(inputs, attrs, ctx):
     if attrs.get("approximate", "none") == "tanh":
         return jax.nn.gelu(x, approximate=True)
     if getattr(x, "dtype", None) == jnp.bfloat16:
-        _note(ctx, "gelu_erf_float32")
+        _note(ctx, "gelu", "erf_float32")
         return _gelu_erf_float32(inputs, attrs, ctx)
-    _note(ctx, "gelu_erfc")
+    _note(ctx, "gelu", "erfc")
     return jax.nn.gelu(x, approximate=False)
 
 
@@ -1187,10 +1188,10 @@ def _loop(inputs, attrs, ctx):
     if notes is None:
         notes = {}
     outer = notes.get("loop_factor", 1)
-    notes["loop_trips." + name] = outer * trips
+    _note(ctx, "loop_trips", name, amount=outer * trips)
     if outer == 1:  # an inner loop's state is part of its outer loop's
         _note(ctx, "loop_state_bytes",
-              sum(v.size * v.dtype.itemsize for v in state))
+              amount=sum(v.size * v.dtype.itemsize for v in state))
 
     def one_trip(i, state):
         outs = run(i, np.bool_(True), *state)
@@ -1329,12 +1330,132 @@ def _kernels_on() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _note(ctx, key: str, amount: int = 1) -> None:
-    """Add to what the executor says of the program it is tracing
-    (``OnnxFunction._run_positional`` turns the notes into metrics)."""
+# What a traced program's notes publish (``OnnxFunction._record_notes``):
+# by family, the metric a note feeds, declared in the registry it is given.
+# Every family is labelled by the program (``fn``) first, then by the values
+# ``_note`` names; a counter adds a trace's notes, a gauge is set to them.
+NOTE_FAMILIES: Dict[str, Callable] = {
+    "attention_lowering": lambda reg: reg.counter(
+        "smt_onnx_attention_lowering_total",
+        "Attention nodes of a traced program by lowering: flash (the "
+        "Pallas kernel, scores never written), dense (materialised "
+        "scores where the kernel could have served: not a TPU, or "
+        "lengths that do not tile), cached (a mask over key positions "
+        "that only the run knows, one head size of a multiple of 128: "
+        "the Pallas kernel for a few queries against a cache, which "
+        "reads it once as it lies and keeps the scores in VMEM) or "
+        "masked (any other mask only the run knows, or no TPU: the "
+        "grouped dense form is the lowering)",
+        ("fn", "kind")),
+    "gelu": lambda reg: reg.counter(
+        "smt_onnx_gelu_lowering_total",
+        "exact Gelu nodes of a traced program by form: erf_float32 (a "
+        "bfloat16 input: one-branch erf on the float32 upcast, rounded "
+        "once) or erfc (any wider input: jax.nn.gelu's two-branch form)",
+        ("fn", "form")),
+    "expert_combine": lambda reg: reg.counter(
+        "smt_onnx_expert_combine_total",
+        "ExpertFFN nodes of a traced program by how their product rows "
+        "reach their tokens: held_first (a token's held picks first, "
+        "the first few gathered for every token, the few beyond those "
+        "added row by row)",
+        ("fn", "form")),
+    "expert_form": lambda reg: reg.counter(
+        "smt_onnx_expert_form_total",
+        "ExpertFFN nodes of a traced program by activation: relu2 (one "
+        "up-projection) or swiglu (a gate and an up-projection)",
+        ("fn", "form")),
+    "expert_tile": lambda reg: reg.counter(
+        "smt_onnx_expert_tile_total",
+        "ExpertFFN nodes of a traced program by the row tile of their "
+        "grouped products, which follows the pairs an expert is expected "
+        "to get (512 at 512 pairs an expert or more)",
+        ("fn", "rows")),
+    "loop_trips": lambda reg: reg.gauge(
+        "smt_onnx_loop_trips",
+        "times a call of the newest traced program runs the body of "
+        "each Loop node, outer loops multiplied in",
+        ("fn", "loop"), merge="max"),
+    "attention_widths": lambda reg: reg.counter(
+        "smt_onnx_attention_widths_total",
+        "Attention nodes of a traced program by the width of a head's "
+        "queries and keys, of its values, and the key-value heads: which "
+        "form of attention ran (latent attention expanded has values "
+        "narrower than its keys; absorbed, one key-value head of latents)",
+        ("fn", "qk", "v", "kv_heads")),
+    "attention_flash_form": lambda reg: reg.counter(
+        "smt_onnx_attention_flash_form_total",
+        "Attention nodes of a traced program that run the flash kernel, "
+        "by where it reads its operands: in_place ([batch, seq, heads x "
+        "size] as the node got them, a head picked by the block index "
+        "map) or heads_first (copies transposed to [batch x heads, seq, "
+        "size] in HBM, and the result back: value heads that are no "
+        "whole 128-lane blocks)",
+        ("fn", "form")),
+    "selective_scan": lambda reg: reg.counter(
+        "smt_onnx_selective_scan_lowering_total",
+        "SelectiveScan nodes of a traced program by lowering: kernel "
+        "(the Pallas kernel: the state stays in VMEM across positions), "
+        "step (one position, a generating loop's body: plain jax.numpy "
+        "over the state) or scan (lax.scan over positions, the state "
+        "crossing HBM every position: not a TPU, or shapes that do not "
+        "tile)",
+        ("fn", "form")),
+    "gated_delta": lambda reg: reg.counter(
+        "smt_onnx_gated_delta_lowering_total",
+        "GatedDeltaRule nodes of a traced program by lowering: "
+        "chunked_kernel (more than one position: the WY form as a "
+        "Pallas kernel, a row's state and a chunk's products in VMEM), "
+        "chunked (more than one position: the WY form as XLA's matrix "
+        "products over chunks: not a TPU, or shapes the kernel does not "
+        "take), kernel (one position: the Pallas kernel reads and "
+        "writes the state once, in place) or step (one position, plain "
+        "jax.numpy: not a TPU, or shapes the kernel does not take)",
+        ("fn", "form")),
+    "loop_state_bytes": lambda reg: reg.gauge(
+        "smt_onnx_loop_state_bytes",
+        "bytes the outermost Loop nodes of the newest traced "
+        "program carry from trip to trip (a key-value cache)",
+        ("fn",), merge="max"),
+    "recurrent_state_bytes": lambda reg: reg.gauge(
+        "smt_onnx_recurrent_state_bytes",
+        "bytes of state the single-position SelectiveScan and "
+        "GatedDeltaRule nodes of the newest traced program take in "
+        "(and hand on as many): what a generating pass streams "
+        "beside the weights",
+        ("fn",), merge="max"),
+    "expert_pairs": lambda reg: reg.gauge(
+        "smt_onnx_expert_pairs",
+        "(token, pick) pairs one call of the newest traced program "
+        "presents to its ExpertFFN nodes: what their grouped "
+        "products are sized for",
+        ("fn",), merge="max"),
+    "experts_held": lambda reg: reg.gauge(
+        "smt_onnx_experts_held",
+        "experts held by the ExpertFFN nodes of the newest traced "
+        "program, summed over nodes",
+        ("fn",), merge="max"),
+    "expert_chunk_rows": lambda reg: reg.gauge(
+        "smt_onnx_expert_chunk_rows",
+        "sorted (token, pick) pairs the ExpertFFN nodes of the newest "
+        "traced program gather and multiply at a time: the smallest "
+        "chunk of any node",
+        ("fn",), merge="max"),
+}
+# how two notes of one trace combine where they do not add up
+_NOTE_FOLDS: Dict[str, Callable[[int, int], int]] = {
+    "loop_trips": lambda was, now: now, "expert_chunk_rows": min}
+
+
+def _note(ctx, family: str, *labels, amount: int = 1) -> None:
+    """Note ``amount`` under ``family``'s ``labels`` in what the executor
+    says of the program it is tracing (``NOTE_FAMILIES``)."""
     notes = ctx.get("notes")
     if notes is not None:
-        notes[key] = notes.get(key, 0) + amount
+        noted = notes.setdefault(family, {})
+        fold = _NOTE_FOLDS.get(family, operator.add)
+        noted[labels] = fold(noted[labels], amount) if labels in noted \
+            else amount
 
 
 @op("RotaryEmbedding")
@@ -1492,7 +1613,7 @@ def _attention(inputs, attrs, ctx):
     b, s_q, h, d = q.shape
     s_k, h_kv, d_v = k.shape[1], k.shape[2], v.shape[3]
     scale = attrs.get("scale")  # None: 1 / sqrt(d), on the float32 scores
-    _note(ctx, f"attention_widths.{d}.{d_v}.{h_kv}")
+    _note(ctx, "attention_widths", d, d_v, h_kv)
     causal, block = bool(attrs.get("is_causal", 0)), 1
     if isinstance(mask, np.ndarray) and not causal:
         # a constant mask may be one of the kernel's own
@@ -1502,23 +1623,23 @@ def _attention(inputs, attrs, ctx):
     if mask is not None:
         if not causal and _kernels_on() and flash.cached_attention_takes(
                 q.shape, k.shape, v.shape, mask.shape, q.dtype):
-            _note(ctx, "attention_cached")
+            _note(ctx, "attention_lowering", "cached")
             out = flash.cached_attention(q, k, v, mask, scale=scale)
         else:
-            _note(ctx, "attention_masked")
+            _note(ctx, "attention_lowering", "masked")
             out = flash.masked_attention(q, k, v, mask, causal=causal,
                                          scale=scale)
     elif _kernels_on() and flash.auto_blocks_tile(b * h, s_q, s_k, block):
-        _note(ctx, "attention_flash")
+        _note(ctx, "attention_lowering", "flash")
         # where the kernel reads its operands: as they lie here, or from
         # copies laid out heads first (widths that are no blocks of lanes)
-        _note(ctx, "attention_flash_form." + (
+        _note(ctx, "attention_flash_form", (
             "in_place" if flash.reads_in_place(d, d_v, q.dtype.itemsize)
             else "heads_first"))
         out = flash.flash_attention(q, k, v, causal=causal,
                                     causal_block=block, scale=scale)
     else:
-        _note(ctx, "attention_dense")
+        _note(ctx, "attention_lowering", "dense")
         if h != h_kv:
             k, v = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
         out = flash.dense_attention(q, k, v, causal=causal,
@@ -1663,17 +1784,14 @@ def _expert_ffn(inputs, attrs, ctx):
                     axis=0, dtype=jnp.int32)
     ends = jnp.cumsum(sizes)
     # a node in a Loop body presents its pairs once a trip
-    _note(ctx, "expert_pairs",
-          n_pairs * (ctx.get("notes") or {}).get("loop_factor", 1))
-    _note(ctx, "experts_held", held)
-    _note(ctx, "expert_combine_held_first")
-    _note(ctx, "expert_form_" + activation)
+    _note(ctx, "expert_pairs", amount=n_pairs * (
+        ctx.get("notes") or {}).get("loop_factor", 1))
+    _note(ctx, "experts_held", amount=held)
+    _note(ctx, "expert_combine", "held_first")
+    _note(ctx, "expert_form", activation)
     tile, chunk = _expert_tiling(n_pairs, int(attrs["num_experts"]))
-    _note(ctx, f"expert_tile_{tile}")
-    notes = ctx.get("notes")
-    if notes is not None:
-        notes["expert_chunk_rows"] = min(
-            notes.get("expert_chunk_rows", chunk), chunk)
+    _note(ctx, "expert_tile", tile)
+    _note(ctx, "expert_chunk_rows", amount=chunk)
 
     # the sorted pairs a chunk at a time, for as many chunks as hold a held
     # expert's pair: the work follows the load (a quarter of the pairs where
@@ -1796,12 +1914,12 @@ def _selective_scan(inputs, attrs, ctx):
     if s == 1:
         form = "step"
         if state_in is not None:
-            _note(ctx, "recurrent_state_bytes", rows * n * d * 4)
+            _note(ctx, "recurrent_state_bytes", amount=rows * n * d * 4)
     elif _kernels_on() and scan.kernel_takes(s, d):
         form = "kernel"
     else:
         form = "scan"
-    _note(ctx, "selective_scan_" + form)
+    _note(ctx, "selective_scan", form)
     out, state = getattr(scan, form + "_form")(
         u, delta, a, b, c, skip, z, bias, state_in,
         delta_softplus=bool(attrs.get("delta_softplus", 1)))
@@ -1858,9 +1976,10 @@ def _gated_delta_rule(inputs, attrs, ctx):
         form = "chunked_kernel" if takes else "chunked"
     else:
         if state_in is not None:
-            _note(ctx, "recurrent_state_bytes", rows * dk * h * dv * 4)
+            _note(ctx, "recurrent_state_bytes",
+                  amount=rows * dk * h * dv * 4)
         takes = _kernels_on() and rule.kernel_takes(rows, h, dk, dv)
         form = "kernel" if takes else "step"
-    _note(ctx, "gated_delta_" + form)
+    _note(ctx, "gated_delta", form)
     out, state = getattr(rule, form + "_form")(q, k, v, g, beta, state_in)
     return (out, state) if ctx["n_outputs"] > 1 else out

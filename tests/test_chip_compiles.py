@@ -138,8 +138,8 @@ def test_flash_kernel_compiles_for_a_v5e_at_192_and_128(
             shape(rows, length, heads * d), shape(rows, length, kv * d),
             shape(rows, length, kv * 128))
     text = lowered.compile().as_text()
-    assert notes["attention_flash"] == 1 \
-        and notes["attention_flash_form.in_place"] == 1
+    assert notes["attention_lowering"][("flash",)] == 1 \
+        and notes["attention_flash_form"][("in_place",)] == 1
     assert text.count("tpu_custom_call") == 1
     assert not [line for line in text.splitlines()
                 if " copy(" in line or " transpose(" in line]
@@ -192,7 +192,8 @@ def test_cached_attention_compiles_for_a_v5e(rows, length, whole, queries,
         shape((rows, queries, heads * 128)), shape((rows, length, kv * 128)),
         shape((rows, length, kv * 128)), shape((1, length), jnp.bool_)
     ).compile().as_text()
-    assert notes["attention_cached"] == 1 and "attention_masked" not in notes
+    assert notes["attention_lowering"][("cached",)] == 1 \
+        and ("masked",) not in notes["attention_lowering"]
     assert text.count("tpu_custom_call") == 1
     cache = f"[{rows},{length},"
     made = [line.split(" = ", 1)[1] for line in text.splitlines()
@@ -238,7 +239,7 @@ def test_selective_scan_compiles_for_a_v5e(rows, length, entering, one_chip,
     operands = [wide, wide, shape((d, n)), narrow, narrow, shape((d,)), wide,
                 shape((d,))] + [shape((rows, n, d))] * entering
     text = jax.jit(selective_scan).lower(*operands).compile().as_text()
-    assert notes == {"selective_scan_kernel": 1}
+    assert notes == {"selective_scan": {("kernel",): 1}}
     assert text.count("tpu_custom_call") == 1
     assert f"[{rows},{d},{n}]" not in text
     out, state = jax.eval_shape(selective_scan, *operands)
@@ -285,15 +286,15 @@ def test_gated_delta_rule_compiles_for_a_v5e(rows, length, entering,
     text = compiled.as_text()
     state = f"f32[{rows},{dk},{h * dv}]"
     if length == 1:
-        assert notes == {"gated_delta_kernel": 1,
-                         "recurrent_state_bytes": rows * dk * h * dv * 4}
+        assert notes == {"gated_delta": {("kernel",): 1},
+                         "recurrent_state_bytes": {(): rows * dk * h * dv * 4}}
         assert text.count("tpu_custom_call") == 1
         assert compiled.memory_analysis().alias_size_in_bytes \
             == rows * dk * h * dv * 4
         assert not [line for line in text.splitlines()
                     if " copy(" in line and state in line.split(" copy(")[0]]
     else:
-        assert notes == {"gated_delta_chunked_kernel": 1}
+        assert notes == {"gated_delta": {("chunked_kernel",): 1}}
         assert text.count("tpu_custom_call") == 1
         assert f"f32[{rows},{h},{length // 64},64," not in text
     out, last = jax.eval_shape(rule, *operands)

@@ -162,16 +162,16 @@ def _share_model(first, held):
     over every expert, ``held`` routed experts from ``first`` on, and the
     shared expert as an output of its own."""
     from synapseml_tpu.models import joyai_flash as jf
-    from synapseml_tpu.models.nemotron_h import EXPERT_DOMAIN, _Weights, \
-        _router
+    from synapseml_tpu.models.decoder import EXPERT_DOMAIN, Weights, \
+        gated_ffn, router
 
     z = jf._Sizes(hidden=32, experts=8, experts_held=8, expert_width=24,
                   shared_width=24)
-    w = _Weights(21)
+    w = Weights(21)
     jf._expert_weights(w, z, "l1")
     nodes = []
-    top_i, top_w = _router(nodes, w, "s", "u", z.hidden, z.experts, 3, 2.5,
-                           weights="l1")
+    top_i, top_w = router(nodes, w, "s", "u", z.hidden, z.experts, 3, 2.5,
+                          weights="l1")
     w.fill_all()
     for name in ("experts_up", "experts_down", "experts_gate"):
         w.store["l1_" + name] = w.store["l1_" + name][first:first + held]
@@ -180,7 +180,7 @@ def _share_model(first, held):
                       "l1_experts_gate"], ["routed"], name="s_moe_experts",
         domain=EXPERT_DOMAIN, first_expert=first, num_experts=z.experts,
         activation="swiglu"))
-    shared = jf._gated_ffn(nodes.append, "s_moe_shared", "l1_shared", "u")
+    shared = gated_ffn(nodes.append, "s_moe_shared", "l1_shared", "u")
     graph = ob.make_graph(
         nodes, "share", [ob.value_info("u", np.float32, [3, 10, 32])],
         [ob.value_info("routed", np.float32, None),

@@ -254,8 +254,9 @@ def main(argv=None):
                 print(json.dumps(line), flush=True)
                 continue
             line["lowering"] = {
-                key[len("attention_"):]: count for key, count in notes.items()
-                if key in ("attention_cached", "attention_masked")}
+                kind: count for (kind,), count in notes.get(
+                    "attention_lowering", {}).items()
+                if kind in ("cached", "masked")}
             text = fn.as_text()
             line["cache_copies_in_loop"] = cache_copies_in_loop(
                 text, cache.size)

@@ -194,9 +194,8 @@ def main(argv=None):
                 line["error"] = f"{type(error).__name__}: {error}"[:300]
                 print(json.dumps(line), flush=True)
                 continue
-            line["lowering"] = {k[len("selective_scan_"):]: v
-                                for k, v in notes.items()
-                                if k.startswith("selective_scan_")}
+            line["lowering"] = {form: v for (form,), v in notes.get(
+                "selective_scan", {}).items()}
             out, states = fn(*given)
             again = fn(*given)[0]
             line["same_bits_twice"] = bool((np.asarray(
