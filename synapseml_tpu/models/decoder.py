@@ -1,25 +1,28 @@
-"""Parts the zoo's decoder builders share, and the one generating hybrid
-decoder that ``jamba`` and ``olmo_hybrid`` both are.
+"""Parts the zoo's decoder builders share, and the one generating decoder
+skeleton that ``jamba``, ``olmo_hybrid`` and ``keye_vl2`` all are.
 
 - ``Weights``: the seeded weight store every decoder's bits come from. A
   tensor is seeded by (``seed``, its ordinal in the store, a slice), so the
   order in which a builder adds weights and integer constants is part of
   the weights' bits.
 - Graph parts: ``gated_ffn`` (and its ``gated_weights``), the sigmoid
-  ``router``, the greedy choice (``greedy``, ``choose``), ``of_shape``, a
-  loop body's ``infos``, and a recurrent mixer's ``causal_conv`` (and its
-  ``step_bias``).
+  ``router``, the Qwen3-MoE family's ``softmax_experts``, rotary angles
+  (``rotary_tables``) and a per-head norm before the rotation
+  (``head_norm_rotary``), the greedy choice (``greedy``, ``choose``),
+  ``of_shape``, a loop body's ``infos``, and a recurrent mixer's
+  ``causal_conv`` (and its ``step_bias``).
 - The runs a generating graph repeats: ``first_token`` (id 0 into the
   outputs), ``decode_tail`` (the body's norm, head and writes) and
   ``decode_loop`` (the ``Loop`` and the graph's outputs).
 - ``hybrid_decoder``: a prompt pass over every layer, then ``Loop`` ``decode``
   of one position a row. A layer is the model's recurrent mixer or causal
   attention; the loop carries, for each layer, either (recurrent state
-  float32, convolution rows bfloat16), replaced every pass, or (keys,
-  values) bfloat16, written in place. The body reads the outer graph's
-  initializers: weights are named ``l#_...`` once and used by both passes.
-  The model supplies its mixer, its attention projections, its block and
-  its head.
+  float32, convolution rows bfloat16), replaced every pass, or the rows its
+  attention keeps (keys and values for ``grouped_attention``; whatever a
+  model's own ``attend`` keeps), bfloat16, written in place. The body reads
+  the outer graph's initializers: weights are named ``l#_...`` once and
+  used by both passes. The model supplies its mixer, its attention
+  projections and attention, its block and its head.
 """
 
 from __future__ import annotations
@@ -31,7 +34,8 @@ from typing import Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from ..onnx.builder import make_graph, make_model, node, value_info
+from ..onnx.builder import constant_node, make_graph, make_model, node, \
+    value_info
 from ..onnx.wire import DataType, ModelProto, numpy_to_tensor
 
 EXPERT_DOMAIN = "synapseml_tpu"
@@ -168,6 +172,71 @@ def router(nodes, w: Weights, p: str, u: str, hidden: int, experts: int,
     add(node("Mul", [p + "_top_n", "routed_scaling"], [p + "_top_w"],
              name=p + "_moe_weight"))
     return p + "_top_i", p + "_top_w"
+
+
+def softmax_experts(add, p: str, wp: str, u: str, experts: int) -> str:
+    """The Qwen3-MoE family's experts over ``u``: a softmax router in
+    float32 over every expert, the ``top_k`` largest renormalised to sum 1,
+    gated experts ``wp_experts_{gate,up,down}`` (all ``experts`` held);
+    names their sum ``p_moe``."""
+    # the router in float32, over every expert
+    add(node("Cast", [u], [p + "_v2_f"], name=p + "_v2_f", to=_FLOAT))
+    add(node("Cast", [wp + "_router_w"], [p + "_router_w_f"],
+             name=p + "_router_w_f", to=_FLOAT))
+    add(node("MatMul", [p + "_v2_f", p + "_router_w_f"], [p + "_router"],
+             name=p + "_moe_route"))
+    add(node("Softmax", [p + "_router"], [p + "_probs"],
+             name=p + "_moe_probs", axis=-1))
+    add(node("TopK", [p + "_probs", "top_k"], [p + "_top_p", p + "_top_i"],
+             name=p + "_moe_topk", axis=-1))
+    add(node("ReduceSum", [p + "_top_p", "axes_last"], [p + "_top_sum"],
+             name=p + "_moe_sum", keepdims=1))
+    add(node("Div", [p + "_top_p", p + "_top_sum"], [p + "_top_w"],
+             name=p + "_moe_weight"))
+    add(node("ExpertFFN",
+             [u, p + "_top_i", p + "_top_w", wp + "_experts_up",
+              wp + "_experts_down", wp + "_experts_gate"],
+             [p + "_moe"], name=p + "_moe_experts", domain=EXPERT_DOMAIN,
+             first_expert=0, num_experts=experts, activation="swiglu"))
+    return p + "_moe"
+
+
+def rotary_tables(add, theta: float, dim: int, prefix: str = "") -> None:
+    """``<prefix>rope_cos`` and ``<prefix>rope_sin`` ``[L, dim / 2]``:
+    position x ``theta^(-2j / dim)`` at every position of
+    ``all_positions``, in float32 from integers and one float32 constant
+    that no policy narrows."""
+    inv_freq = (float(theta) ** (-np.arange(dim // 2, dtype=np.float64)
+                                 * 2.0 / dim)).astype(np.float32)
+    add(constant_node(prefix + "rope_inv_freq", inv_freq))
+    add(node("Cast", ["all_positions"], [prefix + "positions_f"],
+             name=prefix + "positions_f", to=_FLOAT))
+    add(node("Unsqueeze", [prefix + "positions_f", "axes_1"],
+             [prefix + "positions_col"], name=prefix + "positions_col"))
+    add(node("Mul", [prefix + "positions_col", prefix + "rope_inv_freq"],
+             [prefix + "rope_angles"], name=prefix + "rope_angles"))
+    for part in ("cos", "sin"):
+        add(node(part.capitalize(), [prefix + "rope_angles"],
+                 [f"{prefix}rope_{part}"], name=f"{prefix}rope_{part}"))
+
+
+def head_norm_rotary(add, p: str, wp: str, proj: str, heads: int,
+                     positions: str, eps: float) -> str:
+    """``p_<proj> [N, s, heads x size]`` through an RMSNorm over a head's
+    size (weight ``wp_<proj>_norm_w``), then rotate-half rotary at
+    ``positions`` from ``rope_cos`` / ``rope_sin``; names ``p_<proj>_r``.
+    Reads ``heads_<proj>_shape`` and ``flat_shape``."""
+    add(node("Reshape", [f"{p}_{proj}", f"heads_{proj}_shape"],
+             [f"{p}_{proj}_h"], name=f"{p}_{proj}_heads"))
+    add(node("RMSNormalization", [f"{p}_{proj}_h", f"{wp}_{proj}_norm_w"],
+             [f"{p}_{proj}_n"], name=f"{p}_{proj}_norm", axis=-1,
+             epsilon=eps))
+    add(node("Reshape", [f"{p}_{proj}_n", "flat_shape"], [f"{p}_{proj}_f"],
+             name=f"{p}_{proj}_flat"))
+    add(node("RotaryEmbedding", [f"{p}_{proj}_f", "rope_cos", "rope_sin",
+                                 positions], [f"{p}_{proj}_r"],
+             name=f"{p}_rope_{proj}", num_heads=heads))
+    return f"{p}_{proj}_r"
 
 
 def greedy(add, c: str):
@@ -337,11 +406,32 @@ def decode_loop(add, body, state: List[str], starts: List[str],
             value_info("pooled", np.float32, ["N", hidden])]
 
 
+def grouped_attention(add, z, p: str, wp: str, u: str, q: str,
+                      kept: Sequence[str], start, *, heads: int,
+                      kv_heads: int) -> None:
+    """The standard grouped-query ``Attention`` of ``q`` over ``kept`` (keys,
+    values), ``hybrid_decoder``'s ``attend`` for a model with no attention
+    of its own: causal in the prompt pass (``start`` None), held to
+    ``d_visible`` in a decode pass; names ``p_ctx``."""
+    if start is None:
+        add(node("Attention", [q, *kept], [p + "_ctx"], name=p + "_att",
+                 q_num_heads=heads, kv_num_heads=kv_heads, is_causal=1))
+    else:
+        add(node("Attention", [q, *kept, "d_visible"], [p + "_ctx"],
+                 name=p + "_att", q_num_heads=heads, kv_num_heads=kv_heads))
+
+
+def _nothing(*_) -> list:
+    return []
+
+
 def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
-                   attention: Sequence[bool], heads: int, kv_heads: int,
-                   hidden: int, generate: int, eps: float,
-                   ints: Sequence[Tuple[str, object]], mixer, projections,
-                   block, head) -> ModelProto:
+                   attention: Sequence[bool], hidden: int, generate: int,
+                   eps: float, ints: Sequence[Tuple[str, object]], mixer,
+                   projections, block, head, attend, setup=_nothing,
+                   prompt_outputs=_nothing,
+                   cache_pads: Sequence[str] = ("cache_pad", "cache_pad")
+                   ) -> ModelProto:
     """The generating hybrid decoder (module docstring) over the weights in
     ``w`` and the set-up ``nodes`` already made; layer ``i`` is causal
     attention where ``attention[i]``. ``ints`` are the model's integer
@@ -351,10 +441,22 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
     - ``mixer(add, z, p, wp, u[, conv_rows, state_in])``: the recurrent
       mixer over ``u [N, s, hidden]`` (a decode pass gives the carried
       values); names the mix, the state and the rows the next pass reads;
-    - ``projections(add, z, p, wp, u)``: names the queries, keys and values;
+    - ``projections(add, z, p, wp, u)``: names the queries, then the rows
+      an attention layer keeps (keys and values);
     - ``block(nodes, z, c, i, x, mixer)``: layer ``i`` in pass ``c`` around
       ``mixer(p, wp, u)``; names its output;
-    - ``head(add, c, final)``: names the id and its log-prob."""
+    - ``head(add, c, final)``: names the id and its log-prob;
+    - ``attend(add, z, p, wp, u, q, kept, start)``: the attention of a pass
+      (``kept`` the rows as ``projections`` named them in the prompt pass,
+      the written caches in a decode pass; ``start`` None, or the position
+      of the pass's query ``[N]``), naming ``p_ctx``: ``grouped_attention``
+      or the model's own, with ``cache_pads``, the ``Pad`` constant of each
+      kept row's cache;
+    - ``setup(add, c)``: the pass's own nodes over its sizes (``c`` ``p``:
+      after the outer graph's; ``d``: after the body's position);
+    - ``prompt_outputs(add, x)``: outputs of the model's own over the
+      prompt pass's last hidden states ``x [N, S, hidden]``; names their
+      value infos."""
     layers = len(attention)
     for const, values in (
             ("zero", 0), ("one", 1), ("index0", [0]), ("index1", [1]),
@@ -378,6 +480,7 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
              name="all_positions"))
     add(node("Concat", ["n_1d", "generate_1d"], ["n_generate_shape"],
              name="n_generate_shape", axis=0))
+    setup(add, "p")
 
     # ---- the prompt pass: every position; the states and caches it leaves
     carried = {}  # a layer's two carried values, as the prompt pass names them
@@ -387,12 +490,11 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
             if not attention[i]:
                 out, *carried[i] = mixer(add, z, p, wp, u)
                 return out
-            q, k, v = projections(add, z, p, wp, u)
-            add(node("Attention", [q, k, v], [p + "_ctx"], name=p + "_att",
-                     q_num_heads=heads, kv_num_heads=kv_heads, is_causal=1))
+            q, *kept = projections(add, z, p, wp, u)
+            attend(add, z, p, wp, u, q, kept, None)
             carried[i] = []
-            for rows in (k, v):
-                add(node("Pad", [rows, "cache_pad"], [rows + "_cache"],
+            for rows, pad in zip(kept, cache_pads):
+                add(node("Pad", [rows, pad], [rows + "_cache"],
                          name=rows + "_cache", mode="constant"))
                 carried[i].append(rows + "_cache")
             add(node("MatMul", [p + "_ctx", wp + "_o_w"], [p + "_mix"],
@@ -405,6 +507,7 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
     x = "p_tok"
     for i in range(layers):
         x = block(nodes, z, "p", i, x, prompt_mixer(i))
+    own_outputs = prompt_outputs(add, x)
     add(node("Gather", [x, "last_1d"], ["p_last"], name="p_last", axis=1))
     add(node("RMSNormalization", ["p_last", "norm_f_w"], ["p_final"],
              name="p_norm_f", axis=-1, epsilon=eps))
@@ -414,9 +517,10 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
     # a recurrent layer carries (state, convolution rows), replaced every
     # pass; an attention layer (keys, values), written in place
     of_layers = [t for i in range(layers) for t in (
-        [w.bfloat16] * 2 if attention[i] else [np.float32, w.bfloat16])]
-    d_carried = {i: [f"d_carried{i}_{j}" for j in range(2)]
-                 for i in range(layers)}
+        [w.bfloat16] * len(cache_pads) if attention[i]
+        else [np.float32, w.bfloat16])]
+    d_carried = {i: [f"d_carried{i}_{j}" for j in range(
+        len(cache_pads) if attention[i] else 2)] for i in range(layers)}
     d_nodes: List = []
     d_add = d_nodes.append
     d_add(node("Add", ["trip", "prompt_len"], ["d_position"],
@@ -429,6 +533,7 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
                name="d_visible"))
     d_add(node("Add", ["trip", "one"], ["d_slot"], name="d_slot"))
     d_add(node("Expand", ["d_slot", "n_1d"], ["d_slot_1d"], name="d_slot_1d"))
+    setup(d_add, "d")
     d_left = {}
 
     def decode_mixer(i):
@@ -438,15 +543,13 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
                 out, *d_left[i] = mixer(d_add, z, p, wp, u, conv_rows,
                                         state_in)
                 return out
-            q, k, v = projections(d_add, z, p, wp, u)
+            q, *kept = projections(d_add, z, p, wp, u)
             d_left[i] = []
-            for rows, cache in zip((k, v), d_carried[i]):
+            for rows, cache in zip(kept, d_carried[i]):
                 d_add(node("TensorScatter", [cache, rows, "d_position_1d"],
                            [rows + "_cache"], name=rows + "_cache", axis=1))
                 d_left[i].append(rows + "_cache")
-            d_add(node("Attention", [q, *d_left[i], "d_visible"],
-                       [p + "_ctx"], name=p + "_att", q_num_heads=heads,
-                       kv_num_heads=kv_heads))
+            attend(d_add, z, p, wp, u, q, d_left[i], "d_position_1d")
             d_add(node("MatMul", [p + "_ctx", wp + "_o_w"], [p + "_mix"],
                        name=p + "_att_o"))
             return p + "_mix"
@@ -469,8 +572,9 @@ def hybrid_decoder(w: Weights, nodes: List, z, *, name: str,
     outputs = decode_loop(
         add, body, STATE,
         starts + [n for i in range(layers) for n in carried[i]],
-        [f"final_carried{i}_{j}" for i in range(layers) for j in range(2)],
-        generate, hidden)
+        [f"final_carried{i}_{j}" for i in range(layers)
+         for j in range(len(d_carried[i]))],
+        generate, hidden) + own_outputs
     w.fill_all()
     graph = make_graph(nodes, name,
                        [value_info("input_ids", np.int64, ["N", "S"])],

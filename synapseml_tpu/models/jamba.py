@@ -49,6 +49,7 @@ exactly, since the checkpoint is one.
 
 from __future__ import annotations
 
+from functools import partial
 from types import SimpleNamespace as _Sizes
 from typing import List
 
@@ -57,7 +58,8 @@ import numpy as np
 from ..onnx.builder import node
 from ..onnx.wire import DataType, ModelProto
 from .decoder import EXPERT_DOMAIN, Weights, cast_float, causal_conv, \
-    gated_ffn, gated_weights, greedy, hybrid_decoder, step_bias
+    gated_ffn, gated_weights, greedy, grouped_attention, hybrid_decoder, \
+    step_bias
 
 __all__ = ["jamba"]
 
@@ -211,9 +213,9 @@ def jamba(layers: int = 28, hidden: int = 2560, vocab: int = 65536,
         cast_float(nodes, p + "_conv_b", p + "_conv_b_f")
     return hybrid_decoder(
         w, nodes, z, name=f"jamba_{layers}l_h{hidden}_g{generate}",
-        attention=attention, heads=heads, kv_heads=kv_heads, hidden=hidden,
-        generate=generate, eps=eps,
+        attention=attention, hidden=hidden, generate=generate, eps=eps,
         ints=(("huge_1d", [np.iinfo(np.int64).max]),
               ("conv_keep_from", [-(conv_kernel - 1)]),
               ("dt_b_c", [dt_rank, state, state])),
-        mixer=_mamba, projections=_projections, block=_block, head=_tied_head)
+        mixer=_mamba, projections=_projections, block=_block, head=_tied_head,
+        attend=partial(grouped_attention, heads=heads, kv_heads=kv_heads))

@@ -59,12 +59,11 @@ from typing import List
 
 import numpy as np
 
-from ..onnx.builder import constant_node, make_graph, make_model, node, \
-    value_info
+from ..onnx.builder import make_graph, make_model, node, value_info
 from ..onnx.wire import DataType, ModelProto
 from .decoder import EXPERT_DOMAIN, KINDS, STATE, Weights, choose, \
     decode_loop, decode_tail, first_token, gated_ffn, gated_weights, infos, \
-    router
+    rotary_tables, router
 
 __all__ = ["joyai_flash"]
 
@@ -327,19 +326,7 @@ def joyai_flash(layers: int = 9, hidden: int = 2048, vocab: int = 129280,
              name="n_one_shape", axis=0))
     add(node("Concat", ["n_1d", "generate_1d"], ["n_generate_shape"],
              name="n_generate_shape", axis=0))
-    # rotary angles in float32: position x theta^(-2j/rope), from integers
-    # and one float32 constant that no policy narrows
-    inv_freq = (float(rope_theta) ** (-np.arange(rope // 2, dtype=np.float64)
-                                      * 2.0 / rope)).astype(np.float32)
-    add(constant_node("rope_inv_freq", inv_freq))
-    add(node("Cast", ["all_positions"], ["positions_f"], name="positions_f",
-             to=_FLOAT))
-    add(node("Unsqueeze", ["positions_f", "axes_1"], ["positions_col"],
-             name="positions_col"))
-    add(node("Mul", ["positions_col", "rope_inv_freq"], ["rope_angles"],
-             name="rope_angles"))
-    add(node("Cos", ["rope_angles"], ["rope_cos"], name="rope_cos"))
-    add(node("Sin", ["rope_angles"], ["rope_sin"], name="rope_sin"))
+    rotary_tables(add, rope_theta, rope)
     add(node("Unsqueeze", ["prompt_range", "axes_0"], ["prompt_row"],
              name="prompt_row"))
     add(node("Expand", ["prompt_row", "ids_shape"], ["prompt_positions"],

@@ -51,6 +51,7 @@ FLOAT initializer of numbers a bfloat16 holds, since the checkpoint is one.
 
 from __future__ import annotations
 
+from functools import partial
 from types import SimpleNamespace as _Sizes
 from typing import List
 
@@ -59,7 +60,8 @@ import numpy as np
 from ..onnx.builder import node
 from ..onnx.wire import DataType, ModelProto
 from .decoder import EXPERT_DOMAIN, Weights, cast_float, causal_conv, \
-    choose, gated_ffn, gated_weights, hybrid_decoder, step_bias
+    choose, gated_ffn, gated_weights, grouped_attention, hybrid_decoder, \
+    step_bias
 
 __all__ = ["olmo_hybrid"]
 
@@ -229,11 +231,11 @@ def olmo_hybrid(layers: int = 32, hidden: int = 3840, vocab: int = 100352,
     lh, dk, dv = linear_heads, key_dim, value_dim
     return hybrid_decoder(
         w, nodes, z, name=f"olmo_hybrid_{layers}l_h{hidden}_g{generate}",
-        attention=attention, heads=heads, kv_heads=heads, hidden=hidden,
-        generate=generate, eps=eps,
+        attention=attention, hidden=hidden, generate=generate, eps=eps,
         ints=(("huge_1d", [np.iinfo(np.int64).max]),
               ("conv_keep_from", [-(conv_kernel - 1)]),
               ("qkv_split", [lh * dk, lh * dk, lh * dv]),
               ("ab_split", [lh, lh]), ("qk_heads", [0, 0, lh, dk]),
               ("v_heads", [0, 0, lh, dv]), ("v_merged", [0, 0, lh * dv])),
-        mixer=_delta, projections=_projections, block=_block, head=choose)
+        mixer=_delta, projections=_projections, block=_block, head=choose,
+        attend=partial(grouped_attention, heads=heads, kv_heads=heads))

@@ -56,7 +56,8 @@ import numpy as np
 from ..onnx.builder import constant_node, make_graph, make_model, node, \
     value_info
 from ..onnx.wire import DataType, ModelProto
-from .decoder import EXPERT_DOMAIN, Weights, infos, of_shape
+from .decoder import EXPERT_DOMAIN, Weights, head_norm_rotary, infos, \
+    of_shape, softmax_experts
 
 __all__ = ["sdar_moe", "MASK_ID"]
 
@@ -79,43 +80,15 @@ def _layer(add, z: _Sizes, c: str, i: int, x: str, positions: str,
                  name=f"{p}_att_{proj}"))
     for proj, heads in wide.items():
         # the norm over a head's size, then rotary at the absolute position
-        add(node("Reshape", [f"{p}_{proj}", f"heads_{proj}_shape"],
-                 [f"{p}_{proj}_h"], name=f"{p}_{proj}_heads"))
-        add(node("RMSNormalization", [f"{p}_{proj}_h", f"{w}_{proj}_norm_w"],
-                 [f"{p}_{proj}_n"], name=f"{p}_{proj}_norm", axis=-1,
-                 epsilon=z.eps))
-        add(node("Reshape", [f"{p}_{proj}_n", "flat_shape"],
-                 [f"{p}_{proj}_f"], name=f"{p}_{proj}_flat"))
-        add(node("RotaryEmbedding", [f"{p}_{proj}_f", "rope_cos", "rope_sin",
-                                     positions], [f"{p}_{proj}_r"],
-                 name=f"{p}_rope_{proj}", num_heads=heads))
+        head_norm_rotary(add, p, w, proj, heads, positions, z.eps)
     ctx = attend(p, p + "_q_r", p + "_k_r", p + "_v")
     add(node("MatMul", [ctx, w + "_o_w"], [p + "_att"], name=p + "_att_o"))
     add(node("Add", [x, p + "_att"], [p + "_mid"], name=p + "_res_att"))
 
     add(node("RMSNormalization", [p + "_mid", w + "_norm_post_w"],
              [p + "_v2"], name=p + "_norm_post", axis=-1, epsilon=z.eps))
-    # the router in float32, over every expert
-    add(node("Cast", [p + "_v2"], [p + "_v2_f"], name=p + "_v2_f", to=_FLOAT))
-    add(node("Cast", [w + "_router_w"], [p + "_router_w_f"],
-             name=p + "_router_w_f", to=_FLOAT))
-    add(node("MatMul", [p + "_v2_f", p + "_router_w_f"], [p + "_router"],
-             name=p + "_moe_route"))
-    add(node("Softmax", [p + "_router"], [p + "_probs"],
-             name=p + "_moe_probs", axis=-1))
-    add(node("TopK", [p + "_probs", "top_k"], [p + "_top_p", p + "_top_i"],
-             name=p + "_moe_topk", axis=-1))
-    add(node("ReduceSum", [p + "_top_p", "axes_last"], [p + "_top_sum"],
-             name=p + "_moe_sum", keepdims=1))
-    add(node("Div", [p + "_top_p", p + "_top_sum"], [p + "_top_w"],
-             name=p + "_moe_weight"))
-    add(node("ExpertFFN",
-             [p + "_v2", p + "_top_i", p + "_top_w", w + "_experts_up",
-              w + "_experts_down", w + "_experts_gate"],
-             [p + "_moe"], name=p + "_moe_experts", domain=EXPERT_DOMAIN,
-             first_expert=0, num_experts=z.experts, activation="swiglu"))
-    add(node("Add", [p + "_mid", p + "_moe"], [p + "_out"],
-             name=p + "_res_moe"))
+    moe = softmax_experts(add, p, w, p + "_v2", z.experts)
+    add(node("Add", [p + "_mid", moe], [p + "_out"], name=p + "_res_moe"))
     return p + "_out"
 
 

@@ -9,10 +9,11 @@ validation (weights are obviously not the pretrained ones; load real weights via
 ``weights`` overrides when available).
 
 Models: ResNet-18/50/101 (v1.5 bottleneck), a BERT-base-style encoder, ViT-B/16,
-and five decoders with seeded BFLOAT16 weights, a module each: ``nemotron_h`` (one
-pass), and ``sdar_moe``, ``joyai_flash``, ``jamba`` and ``olmo_hybrid``, which generate
-inside the graph (an ONNX ``Loop`` carrying caches and states); ``models/decoder.py``
-holds what they share, ``jamba`` and ``olmo_hybrid`` whole but for their layers' parts.
+and six decoders with seeded BFLOAT16 weights, a module each: ``nemotron_h`` (one
+pass), and ``sdar_moe``, ``joyai_flash``, ``jamba``, ``olmo_hybrid`` and ``keye_vl2``,
+which generate inside the graph (an ONNX ``Loop`` carrying caches and states);
+``models/decoder.py`` holds what they share, ``jamba``, ``olmo_hybrid`` and
+``keye_vl2`` whole but for their layers' parts.
 The encoders emit both a logits output and a penultimate feature output, so ``ImageFeaturizer``
 can "cut" the head exactly like the reference's ``cutOutputLayers``
 (``ImageFeaturizer.scala:40-197``).
@@ -326,6 +327,15 @@ OLMO_HYBRID_TINY = dict(
     generate=8)
 
 
+# widths of the CPU tests' keye_vl2: every mechanism of the full graph (two
+# key-value groups, index keys chosen out of more than topk once the prompt
+# is past topk, a router wider than its top-k)
+KEYE_VL2_TINY = dict(
+    layers=2, hidden=64, vocab=256, heads=4, kv_heads=2, head_dim=16,
+    experts=8, top_k=2, expert_width=32, index_heads=4, index_dim=8, topk=8,
+    generate=8)
+
+
 def _decoder(module: str, preset: Optional[dict] = None):
     """The builder ``models/<module>.py`` names after itself, imported at
     its first use, with ``preset`` under the caller's arguments."""
@@ -355,6 +365,8 @@ MODEL_BUILDERS = {
     "JambaTiny": _decoder("jamba", JAMBA_TINY),
     "OlmoHybrid": _decoder("olmo_hybrid"),
     "OlmoHybridTiny": _decoder("olmo_hybrid", OLMO_HYBRID_TINY),
+    "KeyeVL2": _decoder("keye_vl2"),
+    "KeyeVL2Tiny": _decoder("keye_vl2", KEYE_VL2_TINY),
 }
 
 

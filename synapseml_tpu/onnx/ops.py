@@ -1441,6 +1441,24 @@ NOTE_FAMILIES: Dict[str, Callable] = {
         "traced program gather and multiply at a time: the smallest "
         "chunk of any node",
         ("fn",), merge="max"),
+    "sparse_attention": lambda reg: reg.counter(
+        "smt_onnx_sparse_attention_lowering_total",
+        "SparseAttention nodes of a traced program by lowering: "
+        "prompt_kernel (queries against their own keys: the indexer's "
+        "Pallas kernel picks each query's keys by bisection, a blockwise "
+        "Pallas kernel attends to them; no [S, S] array), decode_kernel "
+        "(one query a row against the caches: the indexer's kernel, the "
+        "chosen positions in order, a Pallas kernel that DMAs those rows "
+        "alone) or plain (jax.numpy with lax.top_k: not a TPU, or shapes "
+        "the kernels do not take)",
+        ("fn", "form")),
+    "sparse_attention_keys": lambda reg: reg.counter(
+        "smt_onnx_sparse_attention_keys_total",
+        "(query, key) pairs the SparseAttention nodes of a traced program "
+        "score with the indexer (scored: the causal pairs of a prompt "
+        "pass, every cached position of a decode pass) and attend to "
+        "(selected: at most topk a query), a call of each node",
+        ("fn", "what")),
 }
 # how two notes of one trace combine where they do not add up
 _NOTE_FOLDS: Dict[str, Callable[[int, int], int]] = {
@@ -1983,3 +2001,87 @@ def _gated_delta_rule(inputs, attrs, ctx):
     _note(ctx, "gated_delta", form)
     out, state = getattr(rule, form + "_form")(q, k, v, g, beta, state_in)
     return (out, state) if ctx["n_outputs"] > 1 else out
+
+
+@op("SparseAttention")
+def _sparse_attention(inputs, attrs, ctx):
+    """``synapseml_tpu::SparseAttention(q, k, v, q_index, k_index,
+    index_weights[, query_start]) -> out``: learned sparse attention, the
+    selection of DeepSeek Sparse Attention (``parallel/sparse_attention.py``
+    has the equations). A learned indexer scores every key a query sees,
+    ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``; the ``topk`` keys of
+    largest ``I`` are kept, ties to the lower position (every visible key
+    where fewer are visible), and softmax attention of every query head runs
+    over those alone, one set for every head.
+
+    ``q [N, s, H x d]``; ``k``, ``v`` ``[N, L, H_kv, d]`` or ``[N, L, H_kv
+    x d]``; ``q_index [N, s, H_I x dI]``; ``k_index [N, L, dI]`` (one index
+    key head); ``index_weights [N, s, H_I]``; ``query_start [N]`` the
+    position of a row's first query (absent: 0, the keys being the queries'
+    own). Query ``i`` sees keys ``0 .. query_start + i``. Attributes
+    ``q_num_heads``, ``kv_num_heads``, ``topk`` and ``scale`` (default
+    ``1 / sqrt(d)``). ``I``, the scores and the softmax are float32, the
+    probabilities rounded to ``v``'s type before the second product; the
+    result is in ``v``'s type.
+
+    Three lowerings, from shapes and the backend alone: ``prompt_kernel``
+    (no ``query_start``, ``s == L``, a TPU, whole blocks of queries and
+    keys, one head size of a multiple of 128: the indexer's Pallas kernel
+    picks each query's set by bisection, without a sort, and a blockwise
+    Pallas kernel attends to it, skipping the tiles of keys no query of a
+    block chose from; no ``[S, S]`` array is written),
+    ``decode_kernel`` (one query a row against caches of whole blocks of
+    128 positions laid ``[N, L, H_kv, d]``, a TPU: the indexer's kernel,
+    the chosen positions in order, then a Pallas kernel that DMAs exactly
+    those rows of the caches) and ``plain`` (``jax.numpy``, the set by
+    ``lax.top_k``: everything else). The program's notes count each, and
+    the pairs scored and attended to."""
+    from ..parallel import sparse_attention as sparse
+
+    q, k, v, q_index, k_index, weights = inputs[:6]
+    start = inputs[6] if len(inputs) > 6 else None
+    heads, kv_heads = int(attrs["q_num_heads"]), int(attrs["kv_num_heads"])
+    topk, scale = int(attrs["topk"]), attrs.get("scale")
+    n, s, wide = q.shape
+    length, index_heads = k.shape[1], weights.shape[-1]
+    if q.ndim != 3 or k.ndim not in (3, 4) or tuple(v.shape) != tuple(
+            k.shape) or int(np.prod(k.shape[2:])) * heads != kv_heads * wide \
+            or heads % kv_heads or tuple(weights.shape[:2]) != (n, s) \
+            or tuple(q_index.shape[:2]) != (n, s) \
+            or q_index.shape[2] % index_heads \
+            or tuple(k_index.shape) != (n, length, q_index.shape[2]
+                                        // index_heads) \
+            or (start is None and s != length) or topk < 1:
+        raise ValueError(
+            f"SparseAttention: q {list(q.shape)}, k {list(k.shape)}, v "
+            f"{list(v.shape)}, q_index {list(q_index.shape)}, k_index "
+            f"{list(k_index.shape)}, index_weights {list(weights.shape)}, "
+            f"query_start {start is not None}, topk {topk}: [N, s, H x d], "
+            f"[N, L, H_kv(, | x) d] twice, [N, s, H_I x dI], [N, L, dI], "
+            f"[N, s, H_I]; without query_start the keys are the queries' own")
+    kw = dict(heads=heads, kv_heads=kv_heads, topk=topk, scale=scale)
+    if start is None and _kernels_on() and sparse.prompt_takes(
+            q.shape, k.shape, k_index.shape, heads=heads, kv_heads=kv_heads):
+        form = "prompt_kernel"
+        out = sparse.prompt_form(q, k, v, q_index, k_index, weights, **kw)
+    elif start is not None and _kernels_on() and sparse.decode_takes(
+            q.shape, k.shape, k_index.shape, heads=heads, kv_heads=kv_heads,
+            topk=topk):
+        form = "decode_kernel"
+        out = sparse.decode_form(q, k, v, q_index, k_index, weights,
+                                 jnp.asarray(start, jnp.int32), **kw)
+    else:
+        form = "plain"
+        out = sparse.plain_form(
+            q, k, v, q_index, k_index, weights,
+            None if start is None else jnp.asarray(start, jnp.int32), **kw)
+    _note(ctx, "sparse_attention", form)
+    if start is None:  # causal pairs; every query keeps min(t + 1, topk)
+        scored = s * (s + 1) // 2
+        kept = min(s, topk)
+        selected = kept * (kept + 1) // 2 + (s - kept) * topk
+    else:
+        scored, selected = s * length, s * min(topk, length)
+    _note(ctx, "sparse_attention_keys", "scored", amount=n * scored)
+    _note(ctx, "sparse_attention_keys", "selected", amount=n * selected)
+    return out

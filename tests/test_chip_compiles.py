@@ -300,3 +300,50 @@ def test_gated_delta_rule_compiles_for_a_v5e(rows, length, entering,
     out, last = jax.eval_shape(rule, *operands)
     assert out.shape == (rows, length, h, dv) and out.dtype == jnp.bfloat16
     assert last.shape == (rows, dk, h * dv) and last.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("rows,s,length", [
+    (1, 65536, 65536),   # keye_vl2_30b_a3b's prompt pass
+    (1, 1, 65664),       # a decode pass against its caches of S + 128
+    (3, 1, 65664),       # a decode pass of three rows
+])
+def test_sparse_attention_compiles_for_a_v5e(rows, s, length, one_chip,
+                                             monkeypatch):
+    """A layer's ``SparseAttention`` of ``keye_vl2_30b_a3b`` (32 / 4 heads of
+    128; the indexer's 16 heads of 64, 2,048 keys a query) through
+    ``ops._sparse_attention`` with the kernels on: the prompt pass is the
+    indexer's kernel and the blockwise kernel, and nothing ``[S, S]`` is in
+    the program; a decode pass is the indexer's kernel and the kernel that
+    DMAs the chosen rows of the caches as they lie, ``[1, L, 4, 128]``."""
+    import jax
+    import jax.numpy as jnp
+
+    from synapseml_tpu.onnx import ops
+
+    heads, kv_heads, d, index_heads, index_dim = 32, 4, 128, 16, 64
+    monkeypatch.setattr(ops, "_kernels_on", lambda: True)
+    notes = {}
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def sparse(*inputs):
+        return ops._sparse_attention(
+            list(inputs), {"q_num_heads": heads, "kv_num_heads": kv_heads,
+                           "topk": 2048}, {"n_outputs": 1, "notes": notes})
+
+    operands = [shape((rows, s, heads * d)),
+                shape((rows, length, kv_heads, d)),
+                shape((rows, length, kv_heads, d)),
+                shape((rows, s, index_heads * index_dim)),
+                shape((rows, length, index_dim)),
+                shape((rows, s, index_heads), jnp.float32)] \
+        + [shape((rows,), jnp.int32)] * (s == 1)
+    compiled = jax.jit(sparse).lower(*operands).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    form = "prompt_kernel" if s > 1 else "decode_kernel"
+    assert notes["sparse_attention"] == {(form,): 1}
+    assert f"{length},{length}]" not in text
+    out = jax.eval_shape(sparse, *operands)
+    assert out.shape == (rows, s, heads * d) and out.dtype == jnp.bfloat16

@@ -40,6 +40,7 @@ REACHES = {
     "smt_onnx_loop_state_bytes": "JambaTiny",
     "smt_onnx_gated_delta_lowering_total": "OlmoHybridTiny",
     "smt_onnx_recurrent_state_bytes": "OlmoHybridTiny",
+    "smt_onnx_sparse_attention_lowering_total": "KeyeVL2Tiny",
 }
 
 
